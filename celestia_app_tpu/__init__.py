@@ -38,6 +38,17 @@ __version__ = "0.1.0"
 # unless CELESTIA_RACE asks for it. See tools/analyze/racecheck.py.
 import os as _os
 
+# The persistent compile cache, placed before anything imports JAX (JAX
+# reads the variable once, at import) and WITHOUT importing it: a
+# host-engine process must stay off the accelerator runtime. An operator's
+# JAX_COMPILATION_CACHE_DIR wins; otherwise the cache sits at a fixed path
+# in the checkout — the path is part of the cache key, so it never moves.
+_os.environ.setdefault(
+    "JAX_COMPILATION_CACHE_DIR",
+    _os.path.join(_os.path.dirname(_os.path.dirname(
+        _os.path.abspath(__file__))), ".jax_cache"),
+)
+
 _race = _os.environ.get("CELESTIA_RACE", "").strip() == "1"
 _lockprof = _os.environ.get("CELESTIA_LOCKPROF", "").strip() == "1"
 if _race or _lockprof:

@@ -46,6 +46,8 @@ Telemetry (the counters the tier-1 no-re-verification test pins):
   admission.batch_verified       lanes that verified and were cached
   admission.batch_rejected       lanes that failed batch verification
   admission.prevalidate_below_batch  batches too small for the device
+  admission.prevalidate_host_engine  batches a host-engine app left to
+                                 the scalar path (it never touches JAX)
 
 Commitment counters (the tier-1 no-recompute test pins; FORMATS §20):
   commitment.cache_hits          a validation consumed a cached commitment
@@ -410,6 +412,13 @@ def prevalidate(app, raws, *, check_state: bool = False,
             telemetry.incr("admission.prevalidate_errors")
     cache = getattr(app, "sig_cache", None)
     if cache is None:
+        return 0
+    if getattr(app, "engine", "host") == "host":
+        # the signature batch is a JAX dispatch like the extend: a
+        # host-engine process must not initialise an accelerator backend
+        # it does not own (N validator processes cannot share one chip),
+        # so its signatures keep to the ante's scalar path
+        telemetry.incr("admission.prevalidate_host_engine")
         return 0
     from celestia_app_tpu.ops import secp256k1 as fast
 

@@ -345,8 +345,8 @@ class ValidatorNode:
         self.name = name
         self.priv = priv
         self.address = priv.public_key().address()
-        # engine="host" stays the validator default (a validator process
-        # must not hang on a dead accelerator relay mid-consensus), but
+        # engine="host" stays the validator default (N validator
+        # processes on one machine cannot share one chip), but
         # device-engine validators are constructible now that the block
         # plane's EDS cache (da/edscache.py) is populated bit-identically
         # by both engines — a TPU proposer and a host follower land on

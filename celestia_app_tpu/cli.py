@@ -1154,7 +1154,9 @@ def cmd_validator_serve(args) -> int:
         # mesh plane: the consensus-critical k=256/512 square-cap
         # override — provisioned identically across the chain or absent
         max_square_size=home_cfg.get("max_square_size"),
-        # validators default to engine=host (the relay-hang policy —
+        # validators default to engine=host (N validator processes on
+        # one machine cannot share one chip, and a host-engine process
+        # must not initialise an accelerator backend it does not own —
         # _ensure_home_config writes "host"); a home explicitly
         # provisioned with "mesh"/"device"/"auto" opts in, which is how
         # a mesh validator (and its produce_batch prewarm) is deployed

@@ -61,8 +61,7 @@ def pipeline_fn(k: int):
         mins, maxs, vs = nmt.leaf_nodes(_axis_leaf_ns(eds, k), eds)
         # One 4k-tree reduction covers both orientations (rows first, then
         # the transposed grid as column trees): each level's SHA launch sees
-        # 2x the messages, which measured ~2 ms faster than two separate
-        # 2k-tree reductions on TPU (HW_NOTES_r4.md).
+        # 2x the messages than two separate 2k-tree reductions would.
         m4 = jnp.concatenate([mins, jnp.swapaxes(mins, 0, 1)], axis=0)
         x4 = jnp.concatenate([maxs, jnp.swapaxes(maxs, 0, 1)], axis=0)
         v4 = jnp.concatenate([vs, jnp.swapaxes(vs, 0, 1)], axis=0)

@@ -20,8 +20,8 @@ per engine in tests/test_read_plane.py.
 
 Engine gating is the edscache/commitment_device playbook:
 
-- "host" never imports (let alone dispatches) jax — a validator next to
-  a dead TPU relay must not hang resolving a read;
+- "host" never imports (let alone dispatches) jax — a host-engine
+  process must not initialise an accelerator backend it does not own;
 - "device"/"mesh" run the jitted search, but a dispatch failure here
   falls back to the host pass COUNTED (``blob.device_fallbacks``),
   never raised — reads are a serving surface, not a consensus phase;
@@ -156,7 +156,7 @@ _PAD_NS = b"\xff" * NS
 
 def _search_device(leaf_ns: np.ndarray, qs: np.ndarray):
     """One engine dispatch for the whole batch. May raise (jax missing,
-    relay down, OOM) — the caller degrades to the host pass, counted."""
+    backend error, OOM) — the caller degrades to the host pass, counted."""
     q = qs.shape[0]
     padded = 1 << max(0, (q - 1)).bit_length()
     if padded != q:
@@ -215,7 +215,7 @@ def get_namespace_data_batched(prover, namespaces,
             starts, ends, counts = _search_device(leaf_ns, qs)
             telemetry.incr("blob.device_batches")
         except Exception:
-            # reads are a serving surface: a dead relay or missing jax
+            # reads are a serving surface: a failed dispatch or missing jax
             # degrades to the host pass, loudly counted, never raised
             telemetry.incr("blob.device_fallbacks")
             starts = None

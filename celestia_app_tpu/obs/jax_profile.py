@@ -19,13 +19,13 @@ a backend to initialize:
 - `collect_gauges()` — a telemetry collector run at scrape time that
   exports device count, bytes-in-use, and live-buffer gauges. It reads
   ``sys.modules`` and only touches backends that ALREADY initialized:
-  a host-engine validator process (which must never import-and-dispatch
-  jax — the relay-down hang class, see service/server.py) serves
+  a host-engine validator process (which must never initialise an
+  accelerator backend it does not own, see service/server.py) serves
   /metrics without waking a backend.
 - `capture_profile(out_dir, seconds)` — the /debug/profile endpoint's
   worker: an on-demand ``jax.profiler`` trace capture to a directory
   (open with TensorBoard / xprof). Refuses when jax is not already
-  loaded in the process, for the same hang-class reason.
+  loaded in the process, for the same reason.
 """
 
 from __future__ import annotations

@@ -34,7 +34,7 @@ Design:
 
 One `vmap`/`jit` dispatch verifies a whole batch and returns a bool lane
 mask. uint64 requires x64 — enabled through the THREAD-LOCAL
-`jax.experimental.enable_x64` scope around trace and dispatch, so the
+`jax.enable_x64(True)` scope around trace and dispatch, so the
 rest of the process keeps the default 32-bit world. Scalar host work per
 signature (pubkey decompression, r/s range checks, s^-1 mod n, window
 digits) stays in Python: it is microseconds against the milliseconds of
@@ -461,48 +461,14 @@ def _kernel_fns():
 def jitted_verify(n: int):
     """Compiled batch verifier for one padded lane count (bucketed so the
     jit cache stays bounded). Instrumented like every jitted factory
-    (obs/jax_profile): the cache miss counts one ``jax.compilations``.
-
-    On the CPU backend the program is AOT-compiled with the thunk
-    runtime disabled — measured ~25% faster on this kernel's long
-    elementwise chains — as a PER-PROGRAM compiler option, so the
-    process-wide XLA flags (and the tuned RS/NMT pipelines) are
-    untouched. Any failure falls back to the plain jitted path."""
+    (obs/jax_profile): the cache miss counts one ``jax.compilations``."""
     import jax
-    import jax.numpy as jnp
 
     from celestia_app_tpu.obs import jax_profile
 
     jax_profile.note_compile("secp256k1.verify", n)
-    fn = jax.jit(_kernel_fns())
-    try:
-        if jax.devices()[0].platform == "cpu":
-            u64 = jnp.uint64
-            i32 = jnp.int32
-            s = jax.ShapeDtypeStruct
-            shapes = (
-                s((n, N_LIMBS), u64), s((n, N_LIMBS), u64),
-                s((n,), jnp.bool_),
-                s((n, N_WINDOWS), i32), s((n, N_WINDOWS), i32),
-                s((n, N_G_WINDOWS), i32), s((n, N_G_WINDOWS), i32),
-                s((n,), i32), s((n,), i32),
-                s((n, N_LIMBS), u64), s((n, N_LIMBS), u64),
-                s((n,), jnp.bool_),
-            )
-            with jax.experimental.enable_x64():
-                fn = fn.lower(*shapes).compile(
-                    compiler_options={"xla_cpu_use_thunk_runtime": False}
-                )
-    except Exception as e:
-        from celestia_app_tpu import obs
-        from celestia_app_tpu.utils import telemetry
-
-        telemetry.incr("secp256k1.aot_compile_fallbacks")
-        obs.get_logger("ops.secp256k1").warning(
-            "AOT compile with scoped compiler options failed; "
-            "using the default jit path", err=e,
-        )
-    return jax_profile.instrument(f"secp256k1.verify[{n}]", fn)
+    return jax_profile.instrument(f"secp256k1.verify[{n}]",
+                                  jax.jit(_kernel_fns()))
 
 
 from celestia_app_tpu.obs import jax_profile as _jax_profile  # noqa: E402
@@ -621,7 +587,7 @@ def _dispatch(preps) -> np.ndarray:
         if r + _N < _P:
             r2_l[i] = _to_limbs(r + _N)
             has_r2[i] = True
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         mask = np.asarray(
             jitted_verify(b)(qx, qy, ydiff, kq1d, kq2d, kg1d, kg2d,
                              sg1, sg2, r_l, r2_l, has_r2)

@@ -2,7 +2,7 @@
 
 `parallel/sharded_eds.py` proved the program — row-sharded RS extension
 with all-to-all column transposes over a (data, seq) ICI mesh, pinned
-bit-identical to the single-device pipeline — but only bench/MULTICHIP
+bit-identical to the single-device pipeline — but only bench
 harnesses ever called it. This module is the production dispatch:
 
 - **Engine selection.** ``edscache.compute_entry(engine="mesh")`` routes

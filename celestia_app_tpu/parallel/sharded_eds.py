@@ -18,11 +18,13 @@ collectives on ICI):
   4. column NMT roots — each device hashes the column trees it owns,
   5. all_to_all   — transpose back to row-sharding,
   6. row NMT roots — each device hashes its row trees,
-  7. data root    — computed outside the shard_map on the gathered 4k axis
-                    roots (tiny tree; XLA inserts the all-gather).
+  7. data root    — every device all-gathers the 4k axis roots (90 bytes
+                    each) and folds the tiny tree itself, still inside the
+                    shard_map: the TPU compiler cannot partition a Pallas
+                    (Mosaic) kernel that sits outside one.
 
 Collectives used: 2 × all_to_all over ``seq`` (the expensive transposes ride
-ICI), plus the implicit all-gather of 90-byte roots. Nothing crosses DCN.
+ICI), plus 2 × all_gather of 90-byte roots. Nothing crosses DCN.
 
 Reference parity: same codewords and roots as rsmt2d + nmt
 (pkg/da/data_availability_header.go:65-108) — asserted bit-identical against
@@ -163,7 +165,17 @@ def _local_pipeline(k: int, n_seq: int):
         row_ns = _leaf_ns_local(eds_rows, k, row_start)
         row_roots_local = _roots_from_leaves_local(row_ns, row_ns, row_vs)
 
-        return eds_rows, row_roots_local, col_roots_local
+        # 7. Data root: gather the 4k axis roots over ``seq`` (90 bytes
+        #    each) and fold them on every device. Inside the shard_map on
+        #    purpose: at 4k >= 1024 leaves the hash is the Pallas kernel,
+        #    and the TPU compiler refuses to partition a Mosaic kernel
+        #    that sits outside one.
+        axis_roots = jnp.concatenate([
+            lax.all_gather(row_roots_local, SEQ_AXIS, axis=1, tiled=True),
+            lax.all_gather(col_roots_local, SEQ_AXIS, axis=1, tiled=True),
+        ], axis=1)  # (B_l, 4k, 90)
+        data_roots = jax.vmap(merkle.merkle_root_pow2)(axis_roots)
+        return eds_rows, row_roots_local, col_roots_local, data_roots
 
     return run
 
@@ -180,35 +192,21 @@ def sharded_pipeline_fn(mesh: Mesh, k: int):
     if k % n_seq != 0:
         raise ValueError(f"seq axis {n_seq} must divide square size {k}")
 
-    local = _local_pipeline(k, n_seq)
-    specs = dict(
+    # check_vma=False: the SHA-256 fori_loop carries mix replicated init
+    # state (H0) with device-varying data; skip VMA inference rather than
+    # thread pvary through every op (outputs are all explicitly sharded).
+    return jax.shard_map(
+        _local_pipeline(k, n_seq),
         mesh=mesh,
         in_specs=P(DATA_AXIS, SEQ_AXIS, None, None),
         out_specs=(
             P(DATA_AXIS, SEQ_AXIS, None, None),
             P(DATA_AXIS, SEQ_AXIS, None),
             P(DATA_AXIS, SEQ_AXIS, None),
+            P(DATA_AXIS, None),  # data roots: replicated over ``seq``
         ),
+        check_vma=False,
     )
-    # The SHA-256 fori_loop carries mix replicated init state (H0) with
-    # device-varying data; skip VMA inference rather than thread pvary
-    # through every op (outputs are all explicitly sharded anyway).
-    # jax < 0.5 ships shard_map under jax.experimental with the older
-    # check_rep spelling of the same knob.
-    if hasattr(jax, "shard_map"):
-        shard = jax.shard_map(local, check_vma=False, **specs)
-    else:
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-        shard = _shard_map(local, check_rep=False, **specs)
-
-    def run(ods_batch: jax.Array):
-        eds, row_roots, col_roots = shard(ods_batch)
-        axis_roots = jnp.concatenate([row_roots, col_roots], axis=1)  # (B, 4k, 90)
-        data_roots = jax.vmap(merkle.merkle_root_pow2)(axis_roots)
-        return eds, row_roots, col_roots, data_roots
-
-    return run
 
 
 def input_sharding(mesh: Mesh) -> NamedSharding:

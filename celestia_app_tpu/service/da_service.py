@@ -46,8 +46,8 @@ class DACore:
     """Engine-gated extend/commit/prove with a bounded square cache.
 
     engine="host": pure NumPy/SIMD path — safe in any process (never
-    imports-and-dispatches jax; a validator next to a dead TPU relay
-    must not hang). engine="device": one jitted dispatch per square
+    imports-and-dispatches jax, so a host-engine validator never
+    initialises an accelerator backend it does not own). engine="device": one jitted dispatch per square
     (da/dah.new_dah_from_ods). Proof construction is host-side either
     way (tree traversal, not FLOPs)."""
 

@@ -52,7 +52,7 @@ class NodeService:
         # the stateless DA-core shim surface (§7.1.7): /da/extend_commit
         # + /da/prove_shares for foreign callers. Host engine unless this
         # node itself runs on device — a host-engine validator process
-        # must never import-and-dispatch jax (relay-down hang class).
+        # must never initialise an accelerator backend it does not own.
         from celestia_app_tpu.service.da_service import DACore
 
         self.da_core = DACore(

@@ -12,19 +12,16 @@ import tempfile
 
 import numpy as np
 
-REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-NATIVE_DIR = os.path.join(REPO, "native")
-BINARY = os.path.join(NATIVE_DIR, "baseline_pipeline")
+from celestia_app_tpu.utils import native_build
+
+BINARY = os.path.join(native_build.NATIVE_DIR, "baseline_pipeline")
 
 
 def build(timeout: int = 180) -> bool:
     """(Re)build via make — the Makefile's dependency tracking means a stale
     binary is rebuilt whenever the source changed. False if no toolchain."""
     try:
-        subprocess.run(
-            ["make", "-C", NATIVE_DIR], check=True, capture_output=True,
-            timeout=timeout,
-        )
+        native_build.make("baseline_pipeline", timeout=timeout)
         return os.path.exists(BINARY)
     except Exception as e:
         # no toolchain in this container: the caller falls back to the

@@ -8,18 +8,19 @@ reference parity notes (tm-db/IAVL + celestia-core block store,
 app/app.go:427-435).
 
 ``load()`` builds the .so via the native Makefile on first use (cheap,
-dependency-tracked) and raises RuntimeError when no toolchain is available
-— callers fall back to the pure-Python file backend.
+dependency-tracked, safe when several processes do so at once — see
+utils/native_build.py) and raises RuntimeError when no toolchain is
+available — callers fall back to the pure-Python file backend.
 """
 
 from __future__ import annotations
 
 import ctypes
 import os
-import subprocess
 
-REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-NATIVE_DIR = os.path.join(REPO, "native")
+from celestia_app_tpu.utils import native_build
+
+NATIVE_DIR = native_build.NATIVE_DIR
 LIB = os.path.join(NATIVE_DIR, "libchaindb.so")
 
 _lib = None
@@ -33,10 +34,8 @@ def load() -> ctypes.CDLL:
     # keeps a stale .so from silently serving an outdated engine after
     # chaindb.cc changes. Only a missing .so makes a failed build fatal.
     try:
-        subprocess.run(
-            ["make", "-C", NATIVE_DIR, "libchaindb.so"],
-            check=True, capture_output=True, timeout=120,
-        )
+        native_build.make("libchaindb.so", native_dir=NATIVE_DIR,
+                          timeout=120)
     except Exception as e:
         if not os.path.exists(LIB):
             raise RuntimeError(f"cannot build libchaindb.so: {e}")

@@ -148,7 +148,8 @@ def _fresh_node(n_accounts: int = 8, chain: str = "admission-test"):
     privs = [PrivateKey.from_seed(b"adm-acct-%d" % i)
              for i in range(n_accounts)]
     addrs = [p.public_key().address() for p in privs]
-    app = App(chain_id=chain, engine="host")
+    # a device-class engine: host-engine apps keep to the scalar path
+    app = App(chain_id=chain, engine="auto")
     app.init_chain({
         "time_unix": 1_700_000_000.0,
         "accounts": [{"address": a.hex(), "balance": 10**12}
@@ -251,7 +252,7 @@ def test_wal_replay_prevalidates_in_batch(monkeypatch):
         db.close()
 
         node2 = cons.ValidatorNode("val0", priv, genesis, chain,
-                                   data_dir=data_dir)
+                                   data_dir=data_dir, engine="auto")
         node2.app.load()
         assert node2.app.height == committed - 2
         scalar0 = _counter("admission.sig_scalar_verified")

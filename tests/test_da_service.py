@@ -273,18 +273,17 @@ def test_native_da_client_end_to_end():
     GF(2^8)/NMT/Merkle implementation, submits the ODS over the wire, and
     requires the returned DAH BYTE-IDENTICAL — then fetches and verifies
     a share proof, all without Python in the loop."""
-    import os
     import subprocess
 
-    native_dir = os.path.join(os.path.dirname(__file__), "..", "native")
-    binary = os.path.join(native_dir, "da_client")
+    from celestia_app_tpu.utils import native_build
+
     # make is the up-to-date check: the binary is NOT in version control
     # (ADVICE r5 #2), so build it from source here; skip only when the
     # environment has no C++ toolchain
-    r = subprocess.run(["make", "-C", native_dir, "da_client"],
-                       capture_output=True, text=True)
-    if r.returncode != 0 or not os.path.exists(binary):
-        pytest.skip(f"cannot build native/da_client: {r.stderr[-300:]}")
+    try:
+        binary = native_build.make("da_client")
+    except (subprocess.SubprocessError, OSError) as e:
+        pytest.skip(f"cannot build native/da_client: {e}")
     svc = DAService(DACore(engine="host"), port=0).serve_background()
     try:
         out = subprocess.run(

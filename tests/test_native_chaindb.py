@@ -22,9 +22,51 @@ from celestia_app_tpu.chain import storage
 from celestia_app_tpu.chain.state import KVStore
 from celestia_app_tpu.utils import native_chaindb
 
-pytestmark = pytest.mark.skipif(
-    not native_chaindb.available(), reason="no native toolchain"
-)
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _native_engine():
+    # decided when the first test runs, never at import: every xdist
+    # worker imports every test file, and collection must compile nothing
+    if not native_chaindb.available():
+        pytest.skip("no native toolchain")
+
+
+def test_concurrent_cold_load_builds_once_and_never_tears(tmp_path):
+    """Regression pin for the cold-checkout build race: six processes
+    call load() at once on a native/ that holds sources but no .so (what
+    `pytest -n 6` does on a fresh clone). All must load a whole library —
+    none may dlopen a half-linked file, none may report 'no toolchain'."""
+    import shutil
+    import subprocess
+    import sys
+
+    native = tmp_path / "native"
+    native.mkdir()
+    for name in ("Makefile", "chaindb.cc"):
+        shutil.copy(os.path.join(native_chaindb.NATIVE_DIR, name), native)
+    script = (
+        "import sys\n"
+        "from celestia_app_tpu.utils import native_chaindb as n\n"
+        "n.NATIVE_DIR = sys.argv[1]\n"
+        "n.LIB = sys.argv[1] + '/libchaindb.so'\n"
+        "lib = n.load()\n"
+        "assert n.available() and lib.cdb_open is not None\n"
+        "print('loaded')\n"
+    )
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    procs = [
+        subprocess.Popen([sys.executable, "-c", script, str(native)],
+                         cwd=repo, stdout=subprocess.PIPE,
+                         stderr=subprocess.PIPE, text=True)
+        for _ in range(6)
+    ]
+    for p in procs:
+        out, err = p.communicate(timeout=180)
+        assert p.returncode == 0 and "loaded" in out, err[-2000:]
+    left = sorted(os.listdir(native))
+    assert left == [".build.lock", "Makefile", "chaindb.cc",
+                    "libchaindb.so"], left
 
 
 def _log(tmp_path, name="db", **kw):

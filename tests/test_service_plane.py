@@ -432,16 +432,15 @@ def test_native_cpp_verify_client(tmp_path):
     service and INDEPENDENTLY verifies a share-inclusion proof chain
     (NMT semantics + RFC-6962 + SHA-256 all reimplemented in C++). Also
     self-checks that a tampered share fails its verifier."""
-    import os
     import subprocess
 
-    native_dir = os.path.join(os.path.dirname(__file__), "..", "native")
-    binary = os.path.join(native_dir, "verify_client")
+    from celestia_app_tpu.utils import native_build
+
     # make is the up-to-date check: edits to verify_client.cc must rebuild
-    r = subprocess.run(["make", "-C", native_dir, "verify_client"],
-                       capture_output=True, text=True)
-    if r.returncode != 0 or not os.path.exists(binary):
-        pytest.skip(f"no C++ toolchain: {r.stderr[-200:]}")
+    try:
+        binary = native_build.make("verify_client")
+    except (subprocess.SubprocessError, OSError) as e:
+        pytest.skip(f"no C++ toolchain: {e}")
 
     from celestia_app_tpu.service.server import NodeService
 

@@ -1,0 +1,173 @@
+"""The main path's device programs, compiled for a TPU v5e that is
+described and not attached (the TPU compiler ships with the installed
+jaxlib; nothing runs, so this proves lowering and memory fit, never
+results or speed).
+
+Interpret-mode Pallas tests cannot see what the chip's compiler refuses:
+a slice off the tiling, too much fast memory, an unsupported cast, a
+kernel it cannot partition across a mesh. These cases ask it directly, at
+the shapes chip_smoke.py runs: the k=64 / k=128 block pipelines and the
+prover's level stack with the Pallas SHA-256 kernel, the fused Pallas RS
+pass, the batched secp256k1 verifier, the namespace search, the blob
+commitment batch, and the four-chip sharded k=256 program.
+
+Rules this file keeps (they are what lets it run under `pytest -n 6`):
+the topology is described ONLY inside the module fixture (one process at
+a time may load libtpu; a worker that is not handed this file must never
+try), everything compiles in the test's own process, every program is a
+fresh closure (a jit cache shared with the production factories would
+hand a Pallas-lowered trace to a later CPU test), and the persistent
+compile cache is off (an entry compiled for a described chip cannot be
+read back without one).
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+V5E_HBM_BYTES = 16 * 1024**3
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _pallas_sha(monkeypatch):
+    # the process's backend is the CPU, so ops/sha256.use_pallas() would
+    # trace the jnp path and the compile would prove nothing
+    monkeypatch.setenv("CELESTIA_SHA256_IMPL", "pallas")
+
+
+def _compile(fn, *shapes, kernels: bool = True, **jit_kw):
+    """Lower+compile a fresh closure over `fn` for the described chip;
+    returns the compiled program after the checks every case shares."""
+    compiled = jax.jit(lambda *a: fn(*a), **jit_kw).lower(*shapes).compile()
+    mem = compiled.memory_analysis()
+    resident = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+                + mem.output_size_in_bytes + mem.generated_code_size_in_bytes)
+    assert resident < V5E_HBM_BYTES, f"{resident} bytes do not fit one v5e"
+    if kernels:
+        assert "tpu_custom_call" in compiled.as_text(), \
+            "no Pallas kernel in the compiled program"
+    return compiled
+
+
+def _u8(shape, sharding):
+    return jax.ShapeDtypeStruct(shape, jnp.uint8, sharding=sharding)
+
+
+def test_sha256_pallas_kernel(one_chip):
+    """The NMT leaf hash at k=64: 4096 messages of 1+29+512 bytes."""
+    from celestia_app_tpu.ops import sha256
+
+    _compile(sha256.sha256, _u8((4096, 542), one_chip))
+
+
+@pytest.mark.parametrize("k", [64, 128])
+def test_block_pipeline(one_chip, k):
+    """da/eds.pipeline_fn: RS extend + 4k NMT roots + data root, the
+    program behind compute_entry(ods, "device")."""
+    from celestia_app_tpu.da import eds
+
+    _compile(eds.pipeline_fn(k), _u8((k, k, 512), one_chip))
+
+
+def test_rs_pallas_pass_k128(one_chip):
+    from celestia_app_tpu.ops import rs_pallas
+
+    _compile(rs_pallas.extend_square_fn(128, interpret=False),
+             _u8((128, 128, 512), one_chip))
+
+
+@pytest.mark.parametrize("k", [64, 128])
+def test_prover_level_stack(one_chip, k):
+    """BlockProver's level pass (da/proof_device._jitted_row_levels)."""
+    from celestia_app_tpu.da import proof_device
+
+    levels = proof_device._jitted_row_levels.__wrapped__(k)
+    _compile(levels, _u8((2 * k, 2 * k, 512), one_chip))
+
+
+def test_namespace_search(one_chip):
+    """da/namespace_device: 16 queries against a 64x64 square's leaves."""
+    from celestia_app_tpu.da import namespace_device
+
+    search = namespace_device._jitted_search.__wrapped__(64 * 64, 16)
+    _compile(search, _u8((64 * 64, 29), one_chip), _u8((16, 29), one_chip),
+             kernels=False)
+
+
+def test_blob_commitment_batch(one_chip):
+    """da/commitment_device at chip_smoke's traffic: 64 blobs x 58 shares
+    decompose into width-1 subtrees, padded to 4096 trees per launch."""
+    from celestia_app_tpu.da import commitment
+    from celestia_app_tpu.ops import nmt
+
+    width = commitment.subtree_width(58, 64)
+    sizes = set(commitment.merkle_mountain_range_sizes(58, width))
+    assert sizes == {1}
+    trees = commitment.round_up_pow2(64 * 58)
+    _compile(nmt.nmt_roots, _u8((trees, 1, 29), one_chip),
+             _u8((trees, 1, 512), one_chip))
+
+
+def test_secp256k1_verify_batch(one_chip):
+    """One admission bucket (64 lanes). uint64 limbs are emulated on a
+    32-bit vector unit, which is why this is the slow compile."""
+    from celestia_app_tpu.ops import secp256k1 as k1
+
+    n = 64
+    with jax.enable_x64(True):
+        def s(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        u64, i32, b = jnp.uint64, jnp.int32, jnp.bool_
+        shapes = (
+            s((n, k1.N_LIMBS), u64), s((n, k1.N_LIMBS), u64), s((n,), b),
+            s((n, k1.N_WINDOWS), i32), s((n, k1.N_WINDOWS), i32),
+            s((n, k1.N_G_WINDOWS), i32), s((n, k1.N_G_WINDOWS), i32),
+            s((n,), i32), s((n,), i32),
+            s((n, k1.N_LIMBS), u64), s((n, k1.N_LIMBS), u64), s((n,), b),
+        )
+        _compile(k1._kernel_fns(), *shapes, kernels=False)
+
+
+def test_sharded_pipeline_k256_four_chips(topo):
+    """parallel/sharded_eds over the four described chips at the
+    big-block shape (GF(2^16)): partitions (every Pallas kernel inside
+    the shard_map), rides two all-to-alls, and fits each chip."""
+    from celestia_app_tpu.parallel import mesh as mesh_mod
+    from celestia_app_tpu.parallel import sharded_eds
+
+    k = 256
+    mesh = mesh_mod.make_mesh(4, k=k, devices=topo.devices)
+    assert dict(mesh.shape) == {"data": 1, "seq": 4}
+    placed = sharded_eds.input_sharding(mesh)
+    compiled = _compile(sharded_eds.sharded_pipeline_fn(mesh, k),
+                        _u8((1, k, k, 512), placed), in_shardings=placed)
+    text = compiled.as_text()
+    assert text.count("all-to-all") >= 2
+    assert "all-gather" in text
