@@ -213,7 +213,7 @@ class QueryRouter:
         bytes (blocks store the same BlobTx-envelope bytes clients hash) —
         the reference's /tx RPC that TxClient.ConfirmTx polls,
         pkg/user/tx_client.go:412. Per-height hash sets are cached so a
-        confirmation polling loop costs O(new heights), not a gzip reload
+        confirmation polling loop costs O(new heights), not a reload
         of the whole lookback window per poll."""
         import hashlib as _hashlib
 

@@ -22,9 +22,18 @@ engine-independent; engines only move bytes):
   batching, torn-tail recovery, rollback/prune tombstones, dead-segment GC
   and writer flocks. This is the tm-db analog and the engine a real
   validator runs on.
-- **files**: one gzip-JSON artifact per height under state/ delta/ blocks/
-  plus a LATEST pointer, each atomically renamed and fsynced. Zero native
+- **files**: one artifact per height under state/ delta/ blocks/ plus a
+  LATEST pointer, each atomically renamed and fsynced. Zero native
   dependencies; also the round-3 on-disk layout, which it still reads.
+
+What the artifacts hold is ChainDB's, the same under both engines: state
+snapshots and deltas are gzip-compressed JSON documents (a few KB a
+commit); a block is a **binary record** (FORMATS §23.2): magic, version,
+the header as its one JSON codec gives it, every tx as a length prefix and
+its raw bytes, a CRC-32 of all of it. Blob payloads are high-entropy and
+megabytes long; base64 + JSON + gzip of them cost nine tenths of a commit
+and saved nothing. Blocks an earlier version wrote as gzip-JSON are still
+read (told apart by the record's first bytes), never written.
 
 Selection: ``CELESTIA_CHAINDB`` env = ``native`` / ``files`` / ``auto``
 (default). Auto keeps whatever engine a home already uses (seg-*.log ⇒
@@ -41,6 +50,7 @@ from __future__ import annotations
 import gzip
 import json
 import os
+import zlib
 
 from celestia_app_tpu import faults
 from celestia_app_tpu.chain.block import Block
@@ -50,6 +60,91 @@ FULL_INTERVAL = 64  # full snapshot cadence (state-sync interval analog)
 
 # record streams (shared by both engines; the file engine maps them to dirs)
 STATE, DELTA, BLOCK, LATEST = 0, 1, 2, 3
+
+
+# -- the block record (FORMATS §23.2) -------------------------------------
+#
+#   magic "CBLK" | version u8 | hlen u32 | header JSON | ntx u32 |
+#   ntx x (len u32 | tx bytes) | crc32 u32 of everything before it
+#
+# Integers little-endian. The header rides as THE header codec's JSON
+# (chain/consensus.py: block store, WAL and socket wire agree on every
+# field, or a stored block re-hashes differently than the chain committed);
+# tx bytes ride raw. The CRC is the record's own corruption check: the file
+# engine frames nothing, and a flipped or torn record must fail in
+# load_block, not parse into another block.
+
+BLOCK_MAGIC = b"CBLK"
+BLOCK_VERSION = 1
+GZIP_MAGIC = b"\x1f\x8b"  # what every pre-record block starts with
+_U32 = 4  # bytes of every integer in the record
+
+
+def _u32(n: int) -> bytes:
+    return n.to_bytes(_U32, "little")  # OverflowError past 4 GiB: loud
+
+
+def _compact_json(doc: dict) -> bytes:
+    return json.dumps(doc, sort_keys=True, separators=(",", ":")).encode()
+
+
+class BlockRecordError(ValueError):
+    """A stored block record that is torn, corrupt or of an unknown kind."""
+
+
+def _encode_block(block: Block) -> bytes:
+    from celestia_app_tpu.chain.consensus import header_to_json
+
+    header = _compact_json(header_to_json(block.header))
+    parts = [
+        BLOCK_MAGIC + bytes([BLOCK_VERSION]) + _u32(len(header)),
+        header,
+        _u32(len(block.txs)),
+    ]
+    for tx in block.txs:
+        parts.append(_u32(len(tx)))
+        parts.append(tx)
+    crc = 0
+    for part in parts:
+        crc = zlib.crc32(part, crc)
+    parts.append(_u32(crc))
+    return b"".join(parts)  # the one copy of the payload
+
+
+def _decode_block(blob: bytes) -> Block:
+    from celestia_app_tpu.chain.consensus import header_from_json
+
+    view = memoryview(blob)
+    end = len(view) - _U32  # where the CRC starts
+    fixed = len(BLOCK_MAGIC) + 1
+    if end < fixed + 2 * _U32:
+        raise BlockRecordError(f"block record truncated at {len(view)} B")
+    if view[fixed - 1] != BLOCK_VERSION:
+        raise BlockRecordError(
+            f"block record version {view[fixed - 1]}, "
+            f"this code reads {BLOCK_VERSION}")
+    if zlib.crc32(view[:end]) != int.from_bytes(view[end:], "little"):
+        raise BlockRecordError("block record fails its CRC")
+    pos = fixed
+
+    def take(n: int) -> memoryview:
+        nonlocal pos
+        if n > end - pos:
+            raise BlockRecordError(
+                f"block record: length {n} at offset {pos} runs past "
+                f"its end ({end})")
+        pos += n
+        return view[pos - n:pos]
+
+    def u32() -> int:
+        return int.from_bytes(take(_U32), "little")
+
+    header = header_from_json(json.loads(bytes(take(u32()))))
+    txs = tuple(bytes(take(u32())) for _ in range(u32()))
+    if pos != end:
+        raise BlockRecordError(
+            f"block record: {end - pos} stray bytes after the last tx")
+    return Block(header=header, txs=txs)
 
 
 def _atomic_write(path: str, data: bytes) -> None:
@@ -75,7 +170,12 @@ def _atomic_write(path: str, data: bytes) -> None:
 
 
 class FileBackend:
-    """gzip-JSON-per-height files; every op is individually durable."""
+    """One file per height and stream; every op is individually durable.
+
+    The `.json.gz` suffix names the ENGINE's artifact, not its content:
+    state and delta files are gzip-JSON, a block file holds ChainDB's
+    binary block record (or, from an earlier version, gzip-JSON). Readers
+    sniff the first bytes; the names stay, so a home keeps opening."""
 
     DIRS = {STATE: "state", DELTA: "delta", BLOCK: "blocks"}
 
@@ -266,9 +366,7 @@ class ChainDB:
 
     @staticmethod
     def _encode(doc: dict) -> bytes:
-        return gzip.compress(
-            json.dumps(doc, sort_keys=True, separators=(",", ":")).encode()
-        )
+        return gzip.compress(_compact_json(doc))
 
     @staticmethod
     def _decode(blob: bytes) -> dict:
@@ -410,25 +508,36 @@ class ChainDB:
     # -- blocks ----------------------------------------------------------
 
     def save_block(self, block: Block) -> None:
-        # THE header codec (chain/consensus.py) — the block store, the WAL,
-        # and the socket wire must agree on every field, or a stored block
-        # re-hashes differently than the chain committed
         from celestia_app_tpu import obs
-        from celestia_app_tpu.chain.consensus import block_to_json
 
-        # encode (JSON + hex + compression of every tx byte), write, sync
-        with obs.span("storage.save_block", height=block.header.height):
-            doc = block_to_json(block)
-            self.backend.put(BLOCK, block.header.height, self._encode(doc))
-            self.backend.sync()
+        height = block.header.height
+        with obs.span("storage.save_block", height=height):
+            with obs.span("storage.block.encode"):
+                record = _encode_block(block)
+            # written AND synced on the caller's thread, before save_commit
+            # may move LATEST (the crash-safety contract above)
+            with obs.span("storage.block.put", bytes=len(record)):
+                self.backend.put(BLOCK, height, record)
+                self.backend.sync()
 
     def load_block(self, height: int) -> Block:
-        from celestia_app_tpu.chain.consensus import block_from_json
-
         blob = self.backend.get(BLOCK, height)
         if blob is None:
             raise FileNotFoundError(f"no block at height {height}")
-        return block_from_json(self._decode(blob))
+        # the bytes say which codec wrote them: no setting, no suffix
+        if blob.startswith(BLOCK_MAGIC):
+            return _decode_block(blob)
+        if blob.startswith(GZIP_MAGIC):
+            # a block an earlier version stored (gzip-JSON, txs base64):
+            # read-only compatibility, counted so a home shows how often
+            from celestia_app_tpu.chain.consensus import block_from_json
+            from celestia_app_tpu.utils import telemetry
+
+            telemetry.incr("storage.legacy_block_reads")
+            old = block_from_json(self._decode(blob))
+            return Block(header=old.header, txs=tuple(old.txs))
+        raise BlockRecordError(
+            f"block {height}: unknown record magic {blob[:4]!r}")
 
     def block_heights(self) -> list[int]:
         return self.backend.heights(BLOCK)

@@ -22,9 +22,12 @@ config 3) and is what ProcessProposal uses via blob_validation.batch_commitments
 
 from __future__ import annotations
 
+import hashlib
+
+from celestia_app_tpu import appconsts as c
 from celestia_app_tpu.da import shares as shares_mod
 from celestia_app_tpu.da.blob import Blob
-from celestia_app_tpu.utils import merkle_host, nmt_host
+from celestia_app_tpu.utils import merkle_host
 
 
 def round_up_pow2(n: int) -> int:
@@ -62,19 +65,57 @@ def merkle_mountain_range_sizes(total: int, max_tree_size: int) -> list[int]:
     return sizes
 
 
+def _leaf_digests(blob: Blob) -> list[bytes]:
+    """The NMT leaf hash, sha256(0x00 | ns | share), of every share
+    `shares.split_blob` gives for the blob, straight from the blob's bytes:
+    a share is a fixed header, a slice of the data and, in the last, zero
+    fill, so no share is built to be hashed."""
+    ns, data = blob.namespace.raw, memoryview(blob.data)
+    first_header, later_header = shares_mod.sparse_share_headers(
+        blob.namespace, len(data), blob.share_version)
+    take = c.FIRST_SPARSE_SHARE_CONTENT_SIZE
+    hasher = hashlib.sha256(b"\x00" + ns + first_header)
+    later = hashlib.sha256(b"\x00" + ns + later_header)
+    digests = []
+    pos = 0
+    while True:
+        chunk = data[pos:pos + take]
+        hasher.update(chunk)
+        if len(chunk) < take:
+            hasher.update(bytes(take - len(chunk)))
+        digests.append(hasher.digest())
+        pos += take
+        if pos >= len(data):
+            return digests
+        take = c.CONTINUATION_SPARSE_SHARE_CONTENT_SIZE
+        hasher = later.copy()
+
+
 def create_commitment(blob: Blob, subtree_root_threshold: int) -> bytes:
-    """32-byte share commitment of a blob."""
-    blob_shares = shares_mod.split_blob(blob.namespace, blob.data, blob.share_version)
-    width = subtree_width(len(blob_shares), subtree_root_threshold)
-    sizes = merkle_mountain_range_sizes(len(blob_shares), width)
+    """32-byte share commitment of a blob.
+
+    Every leaf of a blob's subtrees carries the blob's namespace, so every
+    node's (min, max) is (ns, ns) and only the digests differ; every MMR
+    size is a power of two, so each subtree folds level by level. Equal,
+    digest for digest, to NmtTree over split_blob (tests/test_commitment.py
+    keeps that as the reference): a wallet signs thousands of these."""
+    digests = _leaf_digests(blob)
+    width = subtree_width(len(digests), subtree_root_threshold)
+    span = blob.namespace.raw * 2
+    inner = hashlib.sha256(b"\x01" + span)
     subtree_roots: list[bytes] = []
     cursor = 0
-    for size in sizes:
-        tree = nmt_host.NmtTree()
-        for s in blob_shares[cursor : cursor + size]:
-            tree.push(blob.namespace.raw, s.raw)
-        subtree_roots.append(nmt_host.serialize(tree.root()))
+    for size in merkle_mountain_range_sizes(len(digests), width):
+        level = digests[cursor:cursor + size]
         cursor += size
+        while len(level) > 1:
+            folded = []
+            for i in range(0, len(level), 2):
+                node = inner.copy()
+                node.update(level[i] + span + level[i + 1])
+                folded.append(node.digest())
+            level = folded
+        subtree_roots.append(span + level[0])
     return merkle_host.hash_from_leaves(subtree_roots)
 
 

@@ -121,17 +121,30 @@ def sparse_shares_needed(blob_len: int) -> int:
     return 1 + -(-rest // c.CONTINUATION_SPARSE_SHARE_CONTENT_SIZE)
 
 
+def sparse_share_headers(ns: Namespace, blob_len: int,
+                         share_version: int = 0) -> tuple[bytes, bytes]:
+    """What precedes the data in a blob's first share (namespace, info
+    byte, sequence length) and in each later one (namespace, info byte)."""
+    return (
+        ns.raw + bytes([_info_byte(share_version, True)])
+        + blob_len.to_bytes(c.SEQUENCE_LEN_BYTES, "big"),
+        ns.raw + bytes([_info_byte(share_version, False)]),
+    )
+
+
 def split_blob(ns: Namespace, data: bytes, share_version: int = 0) -> list[Share]:
     """Share-split a blob (shares.md "Share Splitting")."""
+    first_header, later_header = sparse_share_headers(
+        ns, len(data), share_version)
     shares: list[Share] = []
     first = True
     pos = 0
     while first or pos < len(data):
         if first:
-            header = ns.raw + bytes([_info_byte(share_version, True)]) + len(data).to_bytes(4, "big")
+            header = first_header
             take = c.FIRST_SPARSE_SHARE_CONTENT_SIZE
         else:
-            header = ns.raw + bytes([_info_byte(share_version, False)])
+            header = later_header
             take = c.CONTINUATION_SPARSE_SHARE_CONTENT_SIZE
         chunk = data[pos : pos + take]
         pos += take

@@ -1,6 +1,7 @@
 """Share commitments: MMR decomposition, spec pins, size-independence."""
 
 import numpy as np
+import pytest
 
 from celestia_app_tpu.da import namespace as ns_mod
 from celestia_app_tpu.da import square as square_mod
@@ -75,3 +76,39 @@ def test_commitment_subtree_roots_are_row_tree_nodes():
             nmt_host.serialize(nmt_host.leaf_node(blob.namespace.raw, share.raw))
         )
     assert create_commitment(blob, 64) == merkle_host.hash_from_leaves(roots)
+
+
+def _reference_commitment(blob: Blob, subtree_root_threshold: int) -> bytes:
+    """The definition, slowly: split the blob into shares, push each chunk
+    of them into a namespaced Merkle tree, hash the serialized roots."""
+    from celestia_app_tpu.da import shares as shares_mod
+
+    shares = shares_mod.split_blob(
+        blob.namespace, blob.data, blob.share_version)
+    width = subtree_width(len(shares), subtree_root_threshold)
+    roots, cursor = [], 0
+    for size in merkle_mountain_range_sizes(len(shares), width):
+        tree = nmt_host.NmtTree()
+        for share in shares[cursor:cursor + size]:
+            tree.push(blob.namespace.raw, share.raw)
+        roots.append(nmt_host.serialize(tree.root()))
+        cursor += size
+    return merkle_host.hash_from_leaves(roots)
+
+
+# around the first share's 478 bytes and a later share's 482, MMR tails of
+# every shape (11 = 8+2+1 ...), and the benchmark's 50 / 200 KB blobs
+_BLOB_SIZES = [1, 477, 478, 479, 478 + 482, 478 + 483, 478 + 10 * 482 - 1,
+               999, 8_000, 50_000, 200_000, 1_200_000]
+
+
+@pytest.mark.parametrize("size", _BLOB_SIZES)
+def test_commitment_equals_the_tree_definition(size):
+    rng = np.random.default_rng(size)
+    data = rng.integers(0, 256, size, dtype=np.uint8).tobytes()
+    for ns in (ns_mod.Namespace.v0(b"fast"),
+               ns_mod.Namespace(ns_mod.PARITY_NS_RAW)):
+        blob = Blob(ns, data)
+        for threshold in (64, 8, 1):
+            assert create_commitment(blob, threshold) \
+                == _reference_commitment(blob, threshold), (size, threshold)

@@ -575,6 +575,8 @@ BLOCK_PATH = [
     ("finalize_block", "block.produce", 1),
     ("commit", "block.produce", 1),
     ("storage.save_block", "commit", 1),
+    ("storage.block.encode", "storage.save_block", 1),
+    ("storage.block.put", "storage.save_block", 1),
     ("storage.save_commit", "commit", 1),
     ("pool.recheck", "block.produce", 1),
     ("da.prover_warm", None, 1),
