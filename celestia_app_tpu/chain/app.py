@@ -75,7 +75,6 @@ from celestia_app_tpu.chain.tx import (
 )
 from celestia_app_tpu.da import blob as blob_mod
 from celestia_app_tpu.da import codec as dacodec
-from celestia_app_tpu.da import dah as dah_mod
 from celestia_app_tpu.da import edscache as edscache_mod
 from celestia_app_tpu.da import square as square_mod
 from celestia_app_tpu.da.square import PfbEntry
@@ -456,23 +455,8 @@ class App:
         server — hits the same entry. The commitments object is the
         scheme's (a DataAvailabilityHeader or CmtCommitments); its
         ``hash()`` is the data root either way."""
-        scheme = self.codec.name
-        # the square as one array + its content address, paid hit or miss
-        # (prepare AND process each walk the square to get here)
-        with obs.span("da.ods_key", k=square.size) as sp:
-            ods = dah_mod.shares_to_ods(square.share_bytes())
-            key = edscache_mod.cache_key(ods, scheme)
-            entry = self.eds_cache.get(key)
-            sp.set(hit=entry is not None)
-        if entry is None:
-            # upload, device run and download are priced inside
-            # compute_entry (xfer.*:edscache.compute_entry, da.extend.run)
-            with obs.span("da.extend_shares", k=square.size,
-                          engine=self.engine, scheme=scheme):
-                entry = self.eds_cache.put(
-                    key,
-                    edscache_mod.compute_entry(ods, self.engine, scheme),
-                )
+        entry = self.eds_cache.entry_for_square(
+            square, self.engine, self.codec.name)
         return entry.dah, entry.data_root
 
     # ------------------------------------------------------------------

@@ -65,15 +65,15 @@ def build_prover_entry(app, height: int):
     path for host-engine validators, which must not initialise an
     accelerator backend they do not own) otherwise."""
     block, square = rebuild_square(app, height)
-    ods = dah_mod.shares_to_ods(square.share_bytes())
     cache = getattr(app, "eds_cache", None)
     engine = getattr(app, "engine", "auto")
     codec = getattr(app, "codec", None)
     scheme = codec.name if codec is not None else "rs2d-nmt"
     if cache is not None:
-        entry = cache.get_or_compute(ods, engine, scheme)
+        entry = cache.entry_for_square(square, engine, scheme)
     else:  # bare apps (fixtures) still get the one-shot pipeline
-        entry = edscache_mod.compute_entry(ods, engine, scheme)
+        entry = edscache_mod.compute_entry(
+            dah_mod.shares_to_ods(square.share_bytes()), engine, scheme)
     if entry.data_root != block.header.data_hash:
         # a Byzantine (or corrupted-store) header can never be served
         # from the cache: the entry is a pure function of the ODS and the

@@ -521,6 +521,14 @@ class ChainDB:
                 self.backend.sync()
 
     def load_block(self, height: int) -> Block:
+        from celestia_app_tpu import obs
+
+        # read + CRC + split: the first thing a read of a stored height
+        # pays (chain/query.rebuild_square)
+        with obs.span("storage.load_block", height=height):
+            return self._load_block(height)
+
+    def _load_block(self, height: int) -> Block:
         blob = self.backend.get(BLOCK, height)
         if blob is None:
             raise FileNotFoundError(f"no block at height {height}")

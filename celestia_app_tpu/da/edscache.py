@@ -70,7 +70,11 @@ import weakref
 import numpy as np
 
 from celestia_app_tpu import obs
-from celestia_app_tpu.da.dah import DataAvailabilityHeader, ExtendedDataSquare
+from celestia_app_tpu.da.dah import (
+    DataAvailabilityHeader,
+    ExtendedDataSquare,
+    shares_to_ods,
+)
 from celestia_app_tpu.obs import xfer
 from celestia_app_tpu.utils import telemetry
 
@@ -614,6 +618,27 @@ class EdsCache:
         if entry is not None:
             return entry
         return self.put(key, compute_entry(ods, engine, scheme))
+
+    def entry_for_square(self, square, engine: str = "auto",
+                         scheme: str = "rs2d-nmt") -> EdsCacheEntry:
+        """`get_or_compute` from a laid-out Square, with the two phases
+        every caller of the lifecycle pays priced apart: ``da.ods_key``
+        (the square as one array + its content address + the lookup, hit
+        or miss) and, on a miss only, ``da.extend_shares`` (upload, device
+        run and download are priced inside compute_entry:
+        ``xfer.*:edscache.compute_entry``, ``da.extend.run``). The
+        proposer's phases (App._data_root) and a read of a height nobody
+        holds (chain/query.build_prover_entry) both come through here."""
+        with obs.span("da.ods_key", k=square.size) as sp:
+            ods = shares_to_ods(square.share_bytes())
+            key = cache_key(ods, scheme)
+            entry = self.get(key)
+            sp.set(hit=entry is not None)
+        if entry is None:
+            with obs.span("da.extend_shares", k=square.size,
+                          engine=engine, scheme=scheme):
+                entry = self.put(key, compute_entry(ods, engine, scheme))
+        return entry
 
     def clear(self) -> None:
         with self._lock:

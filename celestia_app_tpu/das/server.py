@@ -136,6 +136,17 @@ class _Entry:
         return self._col_prover_view
 
 
+class _Build:
+    """One height's build in progress: its waiters take `entry` once
+    `done` is set (None: the build failed, retry)."""
+
+    __slots__ = ("done", "entry")
+
+    def __init__(self):
+        self.done = threading.Event()
+        self.entry: _Entry | None = None
+
+
 def _b64(b: bytes) -> str:
     import base64
 
@@ -170,7 +181,7 @@ class SampleCore:
         self._lock = threading.Lock()
         # height -> build in progress (single-flight: concurrent samplers
         # of a fresh height pay ONE square build between them)
-        self._inflight: dict[int, threading.Event] = {}  # guarded-by: _lock
+        self._inflight: dict[int, _Build] = {}  # guarded-by: _lock
         # height -> serving record (exposed at /das/availability)
         self._availability: dict[int, dict] = {}
         self._withheld: dict[int, set[tuple[int, int]]] = {}
@@ -184,42 +195,47 @@ class SampleCore:
     def _entry(self, height: int) -> _Entry:
         """Cached serving entry for a height; misses are single-flight.
 
-        Two handler threads missing the same height used to both run the
-        full square rebuild under the app lock; now the first registers
-        an in-progress event and builds, later arrivals wait on it
-        (counted ``das.entry_coalesced``) and re-read the cache. A failed
-        build wakes the waiters, and whichever retries first becomes the
-        next builder — an error never wedges the height."""
+        The first thread to miss a height registers a build in progress
+        and builds; later arrivals wait on it (counted
+        ``das.entry_coalesced``, timed as ``das.entry_wait``) and take the
+        entry from the BUILD, not from the cache: under a sweep wider than
+        the cache the other builders' entries evict it before a waiter
+        wakes, and a waiter that re-read the cache would build the height
+        again. A failed build wakes the waiters with nothing, and
+        whichever retries first becomes the next builder — an error never
+        wedges the height."""
         while True:
             with self._lock:
                 hit = self._cache.get(height)
                 if hit is not None:
                     self._cache.move_to_end(height)
                     return hit
-                ev = self._inflight.get(height)
-                if ev is None:
-                    ev = threading.Event()
-                    self._inflight[height] = ev
+                build = self._inflight.get(height)
+                if build is None:
+                    build = self._inflight[height] = _Build()
                     break
+            from celestia_app_tpu import obs
+
             telemetry.incr("das.entry_coalesced")
-            ev.wait()
+            with obs.span("das.entry_wait", height=height):
+                build.done.wait()
+            if build.entry is not None:
+                return build.entry
         try:
-            return self._build_entry(height)
+            build.entry = self._build_entry(height)
+            return build.entry
         finally:
             with self._lock:
                 self._inflight.pop(height, None)
-            ev.set()
+            build.done.set()
 
     def _build_entry(self, height: int) -> _Entry:
-        import contextlib
-
         from celestia_app_tpu import obs
         from celestia_app_tpu.chain.query import QueryError, \
             build_prover_entry
 
         t0 = telemetry.start_timer()
-        guard = self.app_lock if self.app_lock is not None \
-            else contextlib.nullcontext()
+        lock = self.app_lock
         try:
             # the rebuild a read pays when it arrives before the commit
             # warmer has seeded the height (or after its eviction)
@@ -229,9 +245,18 @@ class SampleCore:
                 trace_id=obs.trace_id_for(
                     getattr(self.app, "chain_id", ""), height),
                 height=height,
-            ), guard:
-                _block, _square, cache_entry = \
-                    build_prover_entry(self.app, height)
+            ):
+                # the first phase of a miss, 0 in a process with no writer
+                # lock to share (then the span is all there is of it)
+                with obs.span("das.app_lock_wait"):
+                    if lock is not None:
+                        lock.acquire()
+                try:
+                    _block, _square, cache_entry = \
+                        build_prover_entry(self.app, height)
+                finally:
+                    if lock is not None:
+                        lock.release()
         except (QueryError, FileNotFoundError, KeyError, ValueError) as e:
             raise SampleError(f"no servable square at height {height}: {e}") \
                 from None
@@ -281,6 +306,7 @@ class SampleCore:
             self._cache.move_to_end(entry.height)
             while len(self._cache) > self._cache_heights:
                 self._cache.popitem(last=False)
+                telemetry.incr("das.entry_evictions")
 
     def _col_prover(self, entry: _Entry):
         """Column-axis prover (BEFP escalation serving) — owned by the

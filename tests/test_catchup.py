@@ -1,0 +1,502 @@
+"""Catch-up serving: readers that are behind, against caches smaller than
+what they sweep (ISSUE 28).
+
+Eight threads sweep 12 stored 8x8 heights against `cache_heights=2` and an
+EDS cache held to 2 entries (by count, and by bytes): every reply is held to
+the plain reference (benchmark/reference/plain_da.py: numpy + hashlib,
+nothing of the program) for that height — shares, proofs, roots, absence;
+evictions of both LRUs are counted; a concurrent miss of one height is built
+once even when the entry is evicted before its waiters wake; the phases of
+a miss close on `das.entry_build`; and the benchmark's metric files name the
+spans and counters the sweep really produced.
+"""
+
+import base64
+import importlib.util
+import json
+import os
+import sys
+import threading
+
+import numpy as np
+import pytest
+
+from celestia_app_tpu.da import edscache
+from celestia_app_tpu.utils import telemetry
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+K = 8
+HEIGHTS = 12
+CLIENTS = 8          # 6 light sweepers, 2 namespace followers
+_T0 = 1_700_000_000.0
+
+pytestmark = pytest.mark.backend
+
+
+def _load(name: str, *parts: str):
+    spec = importlib.util.spec_from_file_location(
+        name, os.path.join(REPO, "benchmark", *parts))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+da = _load("catchup_plain_da", "reference", "plain_da.py")
+
+
+def _counters() -> dict:
+    return dict(telemetry.snapshot()["counters"])
+
+
+def _delta(before: dict, after: dict) -> dict:
+    return {n: v - before.get(n, 0) for n, v in after.items()
+            if v != before.get(n, 0)}
+
+
+@pytest.fixture(scope="module")
+def chain(tmp_path_factory):
+    """12 committed PFB blocks at 8x8 on the device engine (the CPU
+    backend), their plain references, and the namespaces they carry."""
+    from obs_drive import pfb_rounds
+
+    from celestia_app_tpu.chain.app import App
+    from celestia_app_tpu.chain.node import Node
+
+    chain_id = "catchup28"
+    privs, rounds, namespaces = pfb_rounds(chain_id, HEIGHTS)
+    addrs = [p.public_key().address() for p in privs]
+    app = App(chain_id=chain_id, engine="device",
+              data_dir=str(tmp_path_factory.mktemp("catchup28") / "data"))
+    app.init_chain({
+        "time_unix": _T0,
+        "accounts": [{"address": a.hex(), "balance": 10**15} for a in addrs],
+        "validators": [{"operator": addrs[0].hex(), "power": 10}],
+        "gov_max_square_size": K,
+    })
+    node = Node(app)
+    refs = {}
+    for raws in rounds:
+        assert [r.code for r in node.broadcast_txs(raws)] == [0] * len(raws)
+        block, _results = node.produce_block(t=_T0 + app.height + 1)
+        assert block.header.square_size == K
+        ref = da.commit_block(list(block.txs), K)
+        assert ref["data_root"] == block.header.data_hash
+        refs[block.header.height] = ref
+    assert app.da_warmer.wait_idle(60)
+    yield {"app": app, "refs": refs,
+           "namespaces": [ns.raw for ns in namespaces],
+           "absent": bytes(19) + b"catchup-no"}
+    app.close()
+
+
+def _cores(app, **limits):
+    """A fresh serving core of 2 heights over an EDS cache held to
+    `limits`, both empty: every first read of a height is a miss."""
+    from celestia_app_tpu.das.blob_server import BlobCore
+    from celestia_app_tpu.das.server import SampleCore
+
+    app.eds_cache.clear()
+    app.eds_cache.max_entries = limits.get("max_entries",
+                                           edscache.DEFAULT_MAX_ENTRIES)
+    app.eds_cache.max_bytes = limits.get("max_bytes",
+                                         edscache.DEFAULT_MAX_BYTES)
+    core = SampleCore(app, cache_heights=2)
+    return core, BlobCore(core)
+
+
+# -- what a light node and a follower check of a reply ----------------------
+
+
+def _proof(doc: dict) -> dict:
+    return {**doc, "nodes": [base64.b64decode(n) for n in doc["nodes"]]}
+
+
+def check_light(header: dict, reply: dict, cells, ref: dict) -> list[str]:
+    wrong = []
+    if [bytes.fromhex(r) for r in header["row_roots"]] != ref["row_roots"] \
+            or [bytes.fromhex(c) for c in header["col_roots"]] \
+            != ref["col_roots"]:
+        wrong.append("header roots")
+    if bytes.fromhex(reply["data_root"]) != ref["data_root"]:
+        wrong.append("data root")
+    if [(s.get("row"), s.get("col")) for s in reply["samples"]] \
+            != list(cells):
+        wrong.append("cells")
+    for (row, col), s in zip(cells, reply["samples"]):
+        if "error" in s:
+            wrong.append(f"refused {row},{col}")
+            continue
+        share = base64.b64decode(s["share"])
+        if share != ref["eds"][row, col].tobytes():
+            wrong.append(f"share {row},{col}")
+        ns = share[:da.NS] if row < K and col < K else da.PARITY_NS
+        p = _proof(s["proof"])
+        if not (p["start"] == col and p["end"] == col + 1
+                and da.verify_range(ref["row_roots"][row], p["start"],
+                                    p["end"], p["total"],
+                                    [da.nmt_leaf(ns, share)], p["nodes"])):
+            wrong.append(f"proof {row},{col}")
+    return wrong
+
+
+def check_read(reply: dict, asked, ref: dict, absent: bytes) -> list[str]:
+    wrong = []
+    ods = ref["eds"][:K, :K]
+    if [bytes.fromhex(q.get("namespace", "")) for q in reply["queries"]] \
+            != list(asked):
+        return ["namespaces"]
+    for ns, doc in zip(asked, reply["queries"]):
+        if "error" in doc:
+            wrong.append("refused")
+            continue
+        want = da.namespace_shares(ods, ns)
+        shares = [base64.b64decode(s) for s in doc["shares"]]
+        if shares != want or bytes.fromhex(doc["data_root"]) \
+                != ref["data_root"]:
+            wrong.append("shares")
+        if doc["present"] != (ns != absent) or bool(want) != (ns != absent):
+            wrong.append("presence")
+        proof = doc["proof"]
+        if proof is None:
+            if doc["present"]:
+                wrong.append("no proof")
+            continue
+        data = [base64.b64decode(s) for s in proof["data"]]
+        if doc["present"] and data != shares:
+            wrong.append("proof shares")
+        pos = 0
+        for i, pr in enumerate(proof["share_proofs"]):
+            pr = _proof(pr)
+            n = pr["end"] - pr["start"]
+            leaves = [da.nmt_leaf(s[:da.NS], s) for s in data[pos:pos + n]]
+            pos += n
+            row = proof["row_proof"]["start_row"] + i
+            if not (row < K and pr["total"] == 2 * K and da.verify_range(
+                    ref["row_roots"][row], pr["start"], pr["end"],
+                    pr["total"], leaves, pr["nodes"])):
+                wrong.append(f"row proof {row}")
+    return wrong
+
+
+# -- the sweep --------------------------------------------------------------
+
+
+def _run_all(threads, timeout: float = 120.0) -> None:
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout)
+    assert not [t for t in threads if t.is_alive()]
+
+
+def _sweep(chain, limits: dict) -> dict:
+    """Every client one lap of the 12 heights from its own offset; the
+    replies as served, and the counters' growth."""
+    app = chain["app"]
+    core, blob = _cores(app, **limits)
+    names = chain["namespaces"]
+    heights = sorted(chain["refs"])
+    out = {"replies": [[] for _ in range(CLIENTS)], "errors": []}
+    start = threading.Barrier(CLIENTS)
+
+    def client(ci: int):
+        rng = np.random.default_rng([28, ci])
+        try:
+            start.wait()
+            for step in range(HEIGHTS):
+                h = heights[(ci * 3 // 2 + step) % HEIGHTS]
+                if ci < 6:
+                    cells = [(int(r), int(c)) for r, c in
+                             rng.integers(0, 2 * K, size=(4, 2))]
+                    header = core.header(h)
+                    reply = core.sample_many(h, cells)
+                    out["replies"][ci].append(("light", h, cells,
+                                               (header, reply)))
+                else:
+                    asked = [names[0] if ci == 6 else names[2]]
+                    if step % 2:
+                        asked.append(chain["absent"])
+                    reply = blob.namespaces_many(
+                        [{"height": h, "namespace": ns.hex()}
+                         for ns in asked])
+                    out["replies"][ci].append(("read", h, asked, reply))
+        except BaseException as e:  # surfaced by the tests below
+            out["errors"].append(e)
+
+    before = _counters()
+    threads = [threading.Thread(target=client, args=(ci,))
+               for ci in range(CLIENTS)]
+    # a switch every 0.1 ms: a lost update between the two LRUs, the
+    # in-flight table and the entries' locks gets its chance
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-4)
+    try:
+        _run_all(threads)
+    finally:
+        sys.setswitchinterval(interval)
+    out["delta"] = _delta(before, _counters())
+    out["resident"] = (len(core._cache), len(app.eds_cache))
+    return out
+
+
+LIMITS = {
+    # the default knob, CELESTIA_EDSCACHE_ENTRIES, held to 2
+    "entries": {"max_entries": 2},
+    # CELESTIA_EDSCACHE_BYTES binding first: room for two 8x8 entries
+    "bytes": {"max_entries": 64, "max_bytes": 2 * (2 * K) ** 2 * 512 * 2},
+}
+
+
+@pytest.fixture(scope="module", params=sorted(LIMITS))
+def sweep(request, chain):
+    return _sweep(chain, LIMITS[request.param])
+
+
+@pytest.mark.parametrize("ci", range(CLIENTS))
+def test_every_reply_of_the_sweep_equals_the_plain_reference(
+        chain, sweep, ci):
+    assert not sweep["errors"], sweep["errors"]
+    replies = sweep["replies"][ci]
+    assert len(replies) == HEIGHTS
+    assert sorted(h for _k, h, _a, _r in replies) == sorted(chain["refs"])
+    for kind, h, asked, reply in replies:
+        ref = chain["refs"][h]
+        wrong = (check_light(*reply, asked, ref) if kind == "light"
+                 else check_read(reply, asked, ref, chain["absent"]))
+        assert not wrong, (kind, h, wrong)
+    if ci >= 6:
+        assert any(chain["absent"] in asked for _k, _h, asked, _r in replies)
+
+
+@pytest.mark.parametrize("counter,builds", [
+    ("das.entry_evictions", "das.square_builds"),
+    ("edscache.evictions", "da.extend_runs"),
+])
+def test_evictions_of_each_lru_are_counted(sweep, counter, builds):
+    """Both caches start empty and hold 2: every insert beyond the second
+    evicts one, and the counter says so."""
+    delta = sweep["delta"]
+    assert delta[builds] >= HEIGHTS            # every height was a miss
+    assert delta[counter] > 0
+    # a racing insert of a key already resident evicts nothing
+    assert delta[builds] - 2 - CLIENTS <= delta[counter] <= delta[builds] - 2
+    assert max(sweep["resident"]) <= 2
+    assert delta.get("app.device_path_fallback", 0) == 0
+
+
+def test_the_checks_catch_a_reply_of_another_height(chain, sweep):
+    """The comparison is no tautology: a reply served from the neighbouring
+    height fails it."""
+    kind, h, cells, (header, reply) = sweep["replies"][0][0]
+    other = chain["refs"][h + 1 if h + 1 in chain["refs"] else h - 1]
+    assert check_light(header, reply, cells, other)
+    kind, h, asked, reply = sweep["replies"][6][0]
+    other = chain["refs"][h + 1 if h + 1 in chain["refs"] else h - 1]
+    assert check_read(reply, asked, other, chain["absent"])
+
+
+# -- one build per concurrent miss, whatever the cache does meanwhile --------
+
+
+def _wait_for_coalesced(before: dict, n: int, timeout: float = 30.0) -> None:
+    """Until `n` more readers wait on a build in progress (or the timeout:
+    the assertions after it then say what is missing)."""
+    tick = threading.Event()
+    while timeout > 0 and telemetry.snapshot()["counters"].get(
+            "das.entry_coalesced", 0) \
+            - before.get("das.entry_coalesced", 0) < n:
+        tick.wait(0.01)
+        timeout -= 0.01
+
+
+@pytest.mark.parametrize("waiters", [1, 3, 7])
+def test_a_concurrent_miss_of_one_height_is_built_once(chain, monkeypatch,
+                                                       waiters):
+    """The builder is held inside its build until every other reader waits
+    on it; the moment it remembers the entry, two other heights' entries
+    push it out of the cache of 2 (what seven other sweepers do to it at
+    the hard cap). The waiters are handed the BUILD's entry: one square
+    build, `das.entry_coalesced` and a `das.entry_wait` span per waiter."""
+    from celestia_app_tpu.chain import query
+
+    app = chain["app"]
+    core, _blob = _cores(app, max_entries=2)
+    others = [core._entry(h) for h in (1, 2)]
+    height = 7
+    gate = threading.Event()
+    real_build = query.build_prover_entry
+
+    def held(app_, h):
+        if h == height:
+            assert gate.wait(30)
+        return real_build(app_, h)
+
+    monkeypatch.setattr(query, "build_prover_entry", held)
+    real_remember = core._remember
+
+    def remember_then_evict(entry):
+        real_remember(entry)
+        if entry.height == height:
+            for o in others:
+                real_remember(o)
+            assert height not in core._cache
+
+    monkeypatch.setattr(core, "_remember", remember_then_evict)
+    got, errors = [], []
+
+    def reader():
+        try:
+            got.append(core._entry(height))
+        except BaseException as e:
+            errors.append(e)
+
+    before = _counters()
+    threads = [threading.Thread(target=reader) for _ in range(waiters + 1)]
+    for t in threads:
+        t.start()
+    _wait_for_coalesced(before, waiters)
+    gate.set()
+    for t in threads:
+        t.join(60)
+    assert not [t for t in threads if t.is_alive()]
+    delta = _delta(before, _counters())
+    assert not errors, errors
+    assert len(got) == waiters + 1 and len({id(e) for e in got}) == 1
+    assert got[0].height == height
+    assert got[0].root == chain["refs"][height]["data_root"]
+    assert delta["das.square_builds"] == 1
+    assert delta["das.entry_coalesced"] == waiters
+    assert delta['obs.span_n{name="das.entry_wait"}'] == waiters
+    assert not core._inflight
+
+
+def test_a_failed_build_wakes_its_waiters_and_the_next_reader_builds(
+        chain, monkeypatch):
+    from celestia_app_tpu.chain import query
+    from celestia_app_tpu.das.server import SampleError
+
+    core, _blob = _cores(chain["app"], max_entries=2)
+    gate = threading.Event()
+    real_build = query.build_prover_entry
+    calls = []
+
+    def fails_once(app_, h):
+        calls.append(h)
+        if len(calls) == 1:
+            assert gate.wait(30)
+            raise query.QueryError("store unreadable, once")
+        return real_build(app_, h)
+
+    monkeypatch.setattr(query, "build_prover_entry", fails_once)
+    outcomes = []
+
+    def reader():
+        try:
+            outcomes.append(core._entry(5))
+        except SampleError as e:
+            outcomes.append(e)
+
+    before = _counters()
+    threads = [threading.Thread(target=reader) for _ in range(3)]
+    for t in threads:
+        t.start()
+    _wait_for_coalesced(before, 2)
+    gate.set()
+    for t in threads:
+        t.join(60)
+    assert not [t for t in threads if t.is_alive()]
+    refused = [o for o in outcomes if isinstance(o, SampleError)]
+    served = [o for o in outcomes if not isinstance(o, SampleError)]
+    assert len(refused) == 1 and len(served) == 2
+    assert served[0] is served[1] and served[0].height == 5
+    assert len(calls) == 2 and not core._inflight
+
+
+# -- the phases of a miss close ---------------------------------------------
+
+
+def _rows_under(rows, root_name):
+    by_id = {r["span_id"]: r for r in rows}
+    root = [r for r in rows if r["name"] == root_name][-1]
+    return root, [r for r in rows if r["parent_id"] == root["span_id"]], by_id
+
+
+@pytest.mark.parametrize("held_ms", [0, 60])
+def test_the_phases_of_a_miss_sum_to_the_entry_build(chain, held_ms):
+    """`das.entry_build` = wait for the app lock + `query.rebuild_square`
+    (of it `storage.load_block`) + `da.ods_key` + `da.extend_shares` (→
+    `da.extend.run`), to within 5 %; the prover build is the next read's:
+    `das.build_provers` → `proof.levels.run`."""
+    from celestia_app_tpu.das.server import SampleCore
+
+    app = chain["app"]
+    app.eds_cache.clear()
+    lock = threading.Lock() if held_ms else None
+    core = SampleCore(app, cache_heights=2, app_lock=lock)
+    core._entry(3)                    # compiled and warm: time the next one
+    mark = len(app.traces.read("spans", 0, 100_000))
+    if lock is not None:
+        lock.acquire()
+        threading.Timer(held_ms / 1000.0, lock.release).start()
+    reply = core.sample_many(9, [(0, 0), (K + 1, 2)])
+    assert not [s for s in reply["samples"] if "error" in s]
+    rows = app.traces.read("spans", 0, 100_000)[mark:]
+    build, phases, by_id = _rows_under(rows, "das.entry_build")
+    assert [p["name"] for p in phases] == [
+        "das.app_lock_wait", "query.rebuild_square", "da.ods_key",
+        "da.extend_shares"]
+    by_name = {p["name"]: p for p in phases}
+    assert by_name["das.app_lock_wait"]["dur_ms"] >= 0.8 * held_ms
+    if not held_ms:
+        assert by_name["das.app_lock_wait"]["dur_ms"] < 1.0
+    assert by_name["da.ods_key"]["hit"] is False
+    total = sum(p["dur_ms"] for p in phases)
+    assert total <= build["dur_ms"] + 0.01
+    assert total >= 0.95 * build["dur_ms"] - 0.3
+    load = [r for r in rows if r["name"] == "storage.load_block"]
+    assert len(load) == 1 and by_id[load[0]["parent_id"]]["name"] \
+        == "query.rebuild_square"
+    run = [r for r in rows if r["name"] == "da.extend.run"]
+    assert len(run) == 1 and by_id[run[0]["parent_id"]]["name"] \
+        == "da.extend_shares"
+    provers, under, _ = _rows_under(rows, "das.build_provers")
+    assert by_id[provers["parent_id"]]["name"] == "das.serve_sample"
+    assert [r["name"] for r in under] == ["proof.levels.run"]
+    assert not lock or not lock.locked()
+
+
+# -- the benchmark's readers name what the sweep produced -------------------
+
+NEW_METRICS = ["miss_build_ms", "miss_lock_wait_ms", "miss_load_ms",
+               "miss_layout_ms", "miss_extend_ms", "miss_xfer_ms",
+               "miss_provers_ms", "coalesced_wait_ms", "extends_per_request",
+               "evictions_per_request"]
+
+
+@pytest.mark.parametrize("metric", NEW_METRICS)
+def test_benchmark_metric_reads_a_name_the_sweep_produced(sweep, metric):
+    with open(os.path.join(REPO, "benchmark", "metrics", f"{metric}.json"),
+              encoding="utf-8") as f:
+        spec = json.load(f)
+    with open(os.path.join(REPO, "BENCHMARK.json"), encoding="utf-8") as f:
+        entry = {m["name"]: m for m in json.load(f)["per_layer"]}[metric]
+    assert entry["workloads"] == ["k128-serve-catchup"]
+    assert entry["moves"] == "serve_rate"
+    delta = sweep["delta"]
+    if spec["reducer"] == "counter_ratio":
+        assert entry["source"] == "program_counter"
+        assert delta[spec["counter"]] > 0 and spec["per_unit"] == "requests"
+        return
+    assert spec["reducer"] == "span_total" and "per_unit" not in spec
+    assert entry["source"] == "program_span"
+    for span in spec["spans"] + spec.get("minus", []):
+        assert delta[f'obs.span_n{{name="{span}"}}'] > 0, span
+    reducer = _load("catchup_span_total", "reducers", "span_total.py")
+
+    class Reading:
+        counters, units = delta, {}
+
+    value = reducer.read(spec, Reading)
+    assert value is not None and value >= 0.0
+    if metric == "miss_layout_ms":
+        assert value > 0.0           # the self time is what is left, not 0

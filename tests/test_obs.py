@@ -329,7 +329,8 @@ TOTAL_FAMILIES = ("obs.span_n", "obs.span_wall_us", "obs.span_cpu_us")
 # one of these names would be added into the benchmark's row on the trace
 BENCHMARK_SPAN_NAMES = {"window", "produce_block", "broadcast_txs",
                         "first_sample", "warm_wait", "sample_many",
-                        "namespaces_many"}
+                        "namespaces_many", "catchup_request",
+                        "light_header"}
 
 
 def _totals(name: str) -> tuple[int, int, int]:
@@ -582,7 +583,10 @@ BLOCK_PATH = [
     ("da.prover_warm", None, 1),
     ("proof.levels.run", "da.prover_warm", 2),       # row and column
     ("das.entry_build", None, 1),
+    ("das.app_lock_wait", "das.entry_build", 1),
     ("query.rebuild_square", "das.entry_build", 1),
+    ("storage.load_block", "query.rebuild_square", 1),
+    ("da.ods_key", "das.entry_build", 1),            # a hit: no extend
     ("das.serve_sample", None, 1),
     ("das.build_provers", "das.serve_sample", 1),
     ("blob.namespaces_many", None, 1),
