@@ -41,7 +41,6 @@ from __future__ import annotations
 from celestia_app_tpu import appconsts
 from celestia_app_tpu.chain.state import InfiniteGasMeter
 from celestia_app_tpu.da import blob as blob_mod
-from celestia_app_tpu.da import dah as dah_mod
 from celestia_app_tpu.da import square as square_mod
 from celestia_app_tpu.da.square import PfbEntry
 from celestia_app_tpu.utils import telemetry
@@ -107,7 +106,7 @@ def warm_block_batch(app, plans: list[square_mod.Square]) -> int:
 
     by_k: dict[int, list] = {}
     for sq in plans:
-        ods = dah_mod.shares_to_ods(sq.share_bytes())
+        ods = sq.ods
         key = edscache_mod.cache_key(ods)
         if app.eds_cache.get(key) is not None:
             telemetry.incr("producer.plan_cached")

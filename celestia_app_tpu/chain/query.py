@@ -19,7 +19,6 @@ import base64
 
 from celestia_app_tpu import appconsts, obs
 from celestia_app_tpu.chain.state import Context, InfiniteGasMeter
-from celestia_app_tpu.da import dah as dah_mod
 from celestia_app_tpu.da import edscache as edscache_mod
 from celestia_app_tpu.da import square as square_mod
 from celestia_app_tpu.da.blob import is_blob_tx, unmarshal_blob_tx
@@ -72,8 +71,7 @@ def build_prover_entry(app, height: int):
     if cache is not None:
         entry = cache.entry_for_square(square, engine, scheme)
     else:  # bare apps (fixtures) still get the one-shot pipeline
-        entry = edscache_mod.compute_entry(
-            dah_mod.shares_to_ods(square.share_bytes()), engine, scheme)
+        entry = edscache_mod.compute_entry(square.ods, engine, scheme)
     if entry.data_root != block.header.data_hash:
         # a Byzantine (or corrupted-store) header can never be served
         # from the cache: the entry is a pure function of the ODS and the

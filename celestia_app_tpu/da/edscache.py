@@ -73,7 +73,6 @@ from celestia_app_tpu import obs
 from celestia_app_tpu.da.dah import (
     DataAvailabilityHeader,
     ExtendedDataSquare,
-    shares_to_ods,
 )
 from celestia_app_tpu.obs import xfer
 from celestia_app_tpu.utils import telemetry
@@ -116,9 +115,9 @@ def cache_key(ods: np.ndarray, scheme: str = "rs2d-nmt") -> bytes:
     count is k*k, so the byte string determines the geometry — two squares
     collide iff they are the same square.
 
-    Zero-copy: the usual producers (dah.shares_to_ods) hand over C-order
-    arrays, so hashing goes straight over the buffer (`arr.data`) with no
-    8 MB `.tobytes()` staging copy at k=128; `ascontiguousarray` is a
+    Zero-copy: the usual producers (`Square.ods`, dah.shares_to_ods) hand
+    over C-order arrays, so hashing goes straight over the buffer
+    (`arr.data`) with no 8 MB `.tobytes()` staging copy at k=128; `ascontiguousarray` is a
     no-op then and only copies for exotic layouts. The hash itself is
     single-digit ms at k=128 (OpenSSL SHA-NI) against the 2-3 full
     extend+NMT dispatches per height it deduplicates.
@@ -621,16 +620,17 @@ class EdsCache:
 
     def entry_for_square(self, square, engine: str = "auto",
                          scheme: str = "rs2d-nmt") -> EdsCacheEntry:
-        """`get_or_compute` from a laid-out Square, with the two phases
-        every caller of the lifecycle pays priced apart: ``da.ods_key``
-        (the square as one array + its content address + the lookup, hit
-        or miss) and, on a miss only, ``da.extend_shares`` (upload, device
-        run and download are priced inside compute_entry:
+        """`get_or_compute` from a laid-out Square — its array,
+        ``square.ods``, is the square; no share list is walked or joined —
+        with the two phases every caller of the lifecycle pays priced
+        apart: ``da.ods_key`` (the content address of the array + the
+        lookup, hit or miss) and, on a miss only, ``da.extend_shares``
+        (upload, device run and download are priced inside compute_entry:
         ``xfer.*:edscache.compute_entry``, ``da.extend.run``). The
         proposer's phases (App._data_root) and a read of a height nobody
         holds (chain/query.build_prover_entry) both come through here."""
         with obs.span("da.ods_key", k=square.size) as sp:
-            ods = shares_to_ods(square.share_bytes())
+            ods = square.ods
             key = cache_key(ods, scheme)
             entry = self.get(key)
             sp.set(hit=entry is not None)

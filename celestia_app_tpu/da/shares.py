@@ -13,11 +13,22 @@ as a single sequence, with 4 reserved bytes holding the in-share offset of the
 first unit that starts in the share (0 if none). Padding shares
 (namespace/primary-reserved/tail) have sequence_start=1, sequence_len=0 and a
 zero body.
+
+Two definitions of the same bytes live here. The share-by-share functions
+(`split_blob`, `split_txs`, the padding constructors) return `Share`
+objects: clients, proofs and tests use them, and they are what the array
+writers are tested against. The array writers (`write_blob`, `write_txs`,
+`padding_row`) put the same bytes straight into rows of a zeroed
+``(n, 512)`` uint8 array: da/square lays a whole square out with them, one
+handful of numpy calls a sequence and no Python object per share.
 """
 
 from __future__ import annotations
 
+import bisect
 import dataclasses
+
+import numpy as np
 
 from celestia_app_tpu import appconsts as c
 from celestia_app_tpu.da import namespace as ns_mod
@@ -192,8 +203,10 @@ def split_txs(ns: Namespace, txs: list[bytes]) -> list[Share]:
             fixed = ns.raw + bytes([_info_byte(0, False)])
             take = c.CONTINUATION_COMPACT_SHARE_CONTENT_SIZE
         content_abs_off = len(fixed) + c.SHARE_RESERVED_BYTES
-        starts_here = [u for u in unit_starts if pos <= u < pos + take]
-        reserved = (content_abs_off + starts_here[0] - pos) if starts_here else 0
+        # first unit starting in [pos, pos + take), if any
+        i = bisect.bisect_left(unit_starts, pos)
+        starts_here = i < len(unit_starts) and unit_starts[i] < pos + take
+        reserved = (content_abs_off + unit_starts[i] - pos) if starts_here else 0
         chunk = blob[pos : pos + take]
         pos += take
         share = fixed + reserved.to_bytes(4, "big") + chunk + b"\x00" * (take - len(chunk))
@@ -247,3 +260,83 @@ def tail_padding_share() -> bytes:
 
 def tail_padding_shares(n: int) -> list[Share]:
     return [Share(tail_padding_share()) for _ in range(n)]
+
+
+# ---------------------------------------------------------------------------
+# Array writers: the same bytes, into rows of a zeroed (n, 512) uint8 array
+# ---------------------------------------------------------------------------
+
+_INFO_OFF = c.NAMESPACE_SIZE
+_SEQ_LEN_OFF = _INFO_OFF + c.SHARE_INFO_BYTES
+_FIRST_OFF = _SEQ_LEN_OFF + c.SEQUENCE_LEN_BYTES  # data / reserved, first share
+_LATER_OFF = _SEQ_LEN_OFF  # data / reserved, continuation shares
+
+
+def _fill_rows(rows: np.ndarray, col: int, src: np.ndarray) -> None:
+    """Copy `src` into `rows[:, col:]` row after row; the zero fill of the
+    last row is the allocation's."""
+    width = rows.shape[1] - col
+    full = len(src) // width
+    if full:
+        rows[:full, col:] = src[: full * width].reshape(full, width)
+    if len(src) > full * width:
+        rows[full, col : col + len(src) - full * width] = src[full * width :]
+
+
+def write_blob(out: np.ndarray, start: int, ns: Namespace, data: bytes,
+               share_version: int = 0) -> int:
+    """`split_blob`'s shares written into `out[start:]` (zeroed rows);
+    returns how many."""
+    first_header, later_header = sparse_share_headers(
+        ns, len(data), share_version)
+    n = sparse_shares_needed(len(data))
+    rows = out[start : start + n]
+    rows[:, :_LATER_OFF] = np.frombuffer(later_header, dtype=np.uint8)
+    rows[0, :_FIRST_OFF] = np.frombuffer(first_header, dtype=np.uint8)
+    src = np.frombuffer(data, dtype=np.uint8)
+    head = src[: c.FIRST_SPARSE_SHARE_CONTENT_SIZE]
+    rows[0, _FIRST_OFF : _FIRST_OFF + len(head)] = head
+    _fill_rows(rows[1:], _LATER_OFF, src[c.FIRST_SPARSE_SHARE_CONTENT_SIZE :])
+    return n
+
+
+def write_txs(out: np.ndarray, start: int, ns: Namespace,
+              txs: list[bytes]) -> int:
+    """`split_txs`'s shares written into `out[start:]` (zeroed rows);
+    returns how many."""
+    units = [uvarint(len(tx)) + tx for tx in txs]
+    src = np.frombuffer(b"".join(units), dtype=np.uint8)
+    first = c.FIRST_COMPACT_SHARE_CONTENT_SIZE
+    later = c.CONTINUATION_COMPACT_SHARE_CONTENT_SIZE
+    n = 1 + -(-max(len(src) - first, 0) // later)
+    rows = out[start : start + n]
+    rows[:, :_INFO_OFF] = np.frombuffer(ns.raw, dtype=np.uint8)
+    rows[:, _INFO_OFF] = _info_byte(0, False)
+    rows[0, _INFO_OFF] = _info_byte(0, True)
+    rows[0, _SEQ_LEN_OFF:_FIRST_OFF] = np.frombuffer(
+        len(src).to_bytes(c.SEQUENCE_LEN_BYTES, "big"), dtype=np.uint8)
+    first_data = _FIRST_OFF + c.SHARE_RESERVED_BYTES
+    later_data = _LATER_OFF + c.SHARE_RESERVED_BYTES
+    head = src[:first]
+    rows[0, first_data : first_data + len(head)] = head
+    _fill_rows(rows[1:], later_data, src[first:])
+    if units:
+        # reserved bytes: in-share offset of the first unit that starts in
+        # each share, 0 where none does; unit 0 starts the first share
+        unit_starts = np.cumsum([0] + [len(u) for u in units[:-1]])
+        ends = first + np.arange(n) * later
+        pos = np.concatenate(([0], ends[:-1]))
+        i = np.searchsorted(unit_starts, pos)
+        u = unit_starts[np.minimum(i, len(unit_starts) - 1)]
+        starts_here = (i < len(unit_starts)) & (u < ends)
+        reserved = np.where(starts_here, later_data + u - pos, 0)
+        reserved[0] = first_data
+        as_bytes = reserved.astype(">u4").view(np.uint8).reshape(n, 4)
+        rows[0, _FIRST_OFF:first_data] = as_bytes[0]
+        rows[1:, _LATER_OFF:later_data] = as_bytes[1:]
+    return n
+
+
+def padding_row(ns: Namespace) -> np.ndarray:
+    """One padding share of `ns` as a (512,) row, to assign over a slice."""
+    return np.frombuffer(_padding_share(ns), dtype=np.uint8)

@@ -14,11 +14,19 @@ Layout rules implemented (specs/src/specs/data_square_layout.md):
 `build` mirrors go-square Build: greedily include txs in priority order,
 skipping any that would overflow the max square. `construct` mirrors
 Construct: all txs must fit or the whole layout fails (ProcessProposal path).
+
+The square is ONE array: `_export` writes every sequence straight into a
+zeroed C-order (k², 512) uint8 buffer (da/shares' array writers) and
+`Square.ods` is that buffer as (k, k, 512) — what the cache hashes and the
+pipeline extends. No `Share` object exists per share unless a caller asks
+for the derived `Square.shares` view.
 """
 
 from __future__ import annotations
 
 import dataclasses
+
+import numpy as np
 
 from celestia_app_tpu import appconsts
 from celestia_app_tpu.da import blob as blob_mod
@@ -27,6 +35,7 @@ from celestia_app_tpu.da import shares as shares_mod
 from celestia_app_tpu.da.blob import Blob
 from celestia_app_tpu.da.commitment import round_up_pow2, subtree_width
 from celestia_app_tpu.da.shares import Share, uvarint
+from celestia_app_tpu.utils import telemetry
 
 
 def next_share_index(cursor: int, blob_share_count: int, subtree_root_threshold: int) -> int:
@@ -59,10 +68,16 @@ class PfbEntry:
 
 @dataclasses.dataclass
 class Square:
-    """A built original data square plus the layout metadata proofs need."""
+    """A built original data square plus the layout metadata proofs need.
+
+    The square IS `ods`: the k*k shares row-major as one read-only C-order
+    (k, k, 512) uint8 array, handed as it is to the cache and the pipeline.
+    `shares` / `share_bytes()` are a derived view for tests, proofs and
+    tools that want one object per share: built anew on every call, never
+    kept, and counted in `square.share_objects` (no block path builds it)."""
 
     size: int  # k
-    shares: list[Share]  # k*k shares, row-major
+    ods: np.ndarray  # (k, k, 512) uint8: the square
     txs: list[bytes]  # normal txs included
     pfbs: list[PfbEntry]  # blob txs included (priority order)
     # start share index of each blob, parallel to the namespace-sorted order
@@ -74,7 +89,14 @@ class Square:
     pfb_shares_reserved: int = 0
 
     def share_bytes(self) -> list[bytes]:
-        return [s.raw for s in self.shares]
+        flat = self.ods.tobytes()
+        size = appconsts.SHARE_SIZE
+        telemetry.incr("square.share_objects", self.size * self.size)
+        return [flat[i : i + size] for i in range(0, len(flat), size)]
+
+    @property
+    def shares(self) -> list[Share]:
+        return [Share(raw) for raw in self.share_bytes()]
 
     def wrapped_pfb_txs(self) -> list[bytes]:
         """IndexWrapper-encoded PFB txs as placed in the square."""
@@ -153,10 +175,12 @@ class _Layout:
 
 
 def _export(layout: _Layout, k: int) -> Square:
-    """Materialize the share list for a computed layout."""
-    shares: list[Share] = []
+    """Write the shares of a computed layout into one (k*k, 512) array."""
+    assert layout.total <= k * k, "layout exceeds its square"
+    out = np.zeros((k * k, appconsts.SHARE_SIZE), dtype=np.uint8)
+    cursor = 0
     if layout.tx_shares:
-        shares += shares_mod.split_txs(ns_mod.TX_NAMESPACE, layout.txs)
+        cursor += shares_mod.write_txs(out, 0, ns_mod.TX_NAMESPACE, layout.txs)
     pfb_shares_actual = 0
     if layout.pfb_shares_reserved:
         wrapped = [
@@ -166,32 +190,29 @@ def _export(layout: _Layout, k: int) -> Square:
             )
             for i, e in enumerate(layout.pfbs)
         ]
-        pfb = shares_mod.split_txs(ns_mod.PAY_FOR_BLOB_NAMESPACE, wrapped)
-        pfb_shares_actual = len(pfb)
+        pfb_shares_actual = shares_mod.write_txs(
+            out, cursor, ns_mod.PAY_FOR_BLOB_NAMESPACE, wrapped)
         # real index varints ≤ the reserved worst case; the gap up to the
         # first blob becomes primary-reserved padding below
         assert pfb_shares_actual <= layout.pfb_shares_reserved
-        shares += pfb
+        cursor += pfb_shares_actual
 
-    cursor = len(shares)
     prev_ns: ns_mod.Namespace | None = None
     for ns_raw, i, j in layout.ordered:
         b = layout.pfbs[i].blobs[j]
         start = layout.starts[(i, j)]
         if start > cursor:
-            pad = (
-                [shares_mod.reserved_padding_share()] * (start - cursor)
-                if prev_ns is None
-                else [shares_mod.namespace_padding_share(prev_ns)] * (start - cursor)
-            )
-            shares += pad
-        shares += shares_mod.split_blob(b.namespace, b.data, b.share_version)
-        cursor = start + b.share_count()
+            out[cursor:start] = shares_mod.padding_row(
+                ns_mod.PRIMARY_RESERVED_PADDING_NAMESPACE
+                if prev_ns is None else prev_ns)
+        cursor = start + shares_mod.write_blob(
+            out, start, b.namespace, b.data, b.share_version)
         prev_ns = b.namespace
-    shares += shares_mod.tail_padding_shares(k * k - len(shares))
+    out[cursor:] = shares_mod.padding_row(ns_mod.TAIL_PADDING_NAMESPACE)
+    out.flags.writeable = False
     return Square(
         size=k,
-        shares=shares,
+        ods=out.reshape(k, k, appconsts.SHARE_SIZE),
         txs=layout.txs,
         pfbs=layout.pfbs,
         blob_start_indexes=layout.starts,
@@ -272,12 +293,4 @@ def build(
 
 def empty_square() -> Square:
     """The k=1 square holding a single tail-padding share."""
-    return Square(
-        size=1,
-        shares=shares_mod.tail_padding_shares(1),
-        txs=[],
-        pfbs=[],
-        blob_start_indexes={},
-        tx_shares_len=0,
-        pfb_shares_len=0,
-    )
+    return _export(_Layout([], [], 1, 1), 1)

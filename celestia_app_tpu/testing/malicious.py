@@ -33,10 +33,11 @@ class BlindNmtTree(nmt_host.NmtTree):
         self.leaves.append((ns, data))
 
 
-def swap_first_two_blobs(square) -> list[bytes]:
-    """Square share list with the first two blobs' share ranges swapped
-    (OutOfOrderExport, out_of_order_builder.go:62-79). Requires >= 2 blobs."""
-    shares = list(square.share_bytes())
+def swap_first_two_blobs(square) -> np.ndarray:
+    """A copy of the square's (k, k, 512) array with the first two blobs'
+    share ranges swapped (OutOfOrderExport, out_of_order_builder.go:62-79).
+    Requires >= 2 blobs."""
+    shares = square.ods.reshape(-1, appconsts.SHARE_SIZE).copy()
     keys = sorted(square.blob_start_indexes.keys())
     if len(keys) < 2:
         raise ValueError("need at least two blobs to swap")
@@ -48,9 +49,10 @@ def swap_first_two_blobs(square) -> list[bytes]:
     if c0 != c1:
         # swap equal-length prefixes so the layout geometry stays identical
         c0 = c1 = min(c0, c1)
-    a, b = shares[s0 : s0 + c0], shares[s1 : s1 + c1]
-    shares[s0 : s0 + c0], shares[s1 : s1 + c1] = b, a
-    return shares
+    a = shares[s0 : s0 + c0].copy()
+    shares[s0 : s0 + c0] = shares[s1 : s1 + c1]
+    shares[s1 : s1 + c1] = a
+    return shares.reshape(square.ods.shape)
 
 
 def blind_dah(ods: np.ndarray):
@@ -86,9 +88,7 @@ def out_of_order_prepare(app, raw_txs: list[bytes], t: float) -> Block:
     block = honest.block if hasattr(honest, "block") else honest
     if sq is None:
         raise ValueError("prepare_proposal result carries no square")
-    shares = swap_first_two_blobs(sq)
-    ods = dah_mod.shares_to_ods(shares)
-    _, root = blind_dah(ods)
+    _, root = blind_dah(swap_first_two_blobs(sq))
     import dataclasses
 
     # replace ONLY the data root: every other header field (including any

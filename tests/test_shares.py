@@ -102,3 +102,55 @@ def test_namespace_ordering():
 def test_padding_share_parse():
     s = shares.namespace_padding_share(ns_mod.Namespace.v0(b"pad"))
     assert s.is_padding() and s.sequence_len() == 0
+
+
+# -- the array writers against the share-by-share definition ----------------
+
+
+@pytest.mark.parametrize(
+    "size", [0, 1, 477, 478, 479, 478 + 482, 478 + 482 + 1, 5000, 200_000])
+def test_write_blob_is_split_blob(size):
+    rng = np.random.default_rng(size)
+    ns = ns_mod.Namespace.v0(b"writer")
+    data = rng.integers(0, 256, size, dtype=np.uint8).tobytes()
+    want = shares.split_blob(ns, data)
+    out = np.zeros((len(want) + 3, 512), dtype=np.uint8)
+    assert shares.write_blob(out, 2, ns, data) == len(want)
+    assert out[2:-1].tobytes() == b"".join(s.raw for s in want)
+    assert not out[:2].any() and not out[-1].any()  # nothing beside its rows
+
+
+@pytest.mark.parametrize(
+    "tx_sizes",
+    [[], [10], [100, 200, 300], [472], [472, 3 * 478 - 2, 100], [474],
+     [5000], [1, 473], [600, 600, 600], [1] * 700, [127, 128, 16383, 16384]],
+)
+def test_write_txs_is_split_txs(tx_sizes):
+    rng = np.random.default_rng(sum(tx_sizes) + len(tx_sizes))
+    txs = [rng.integers(0, 256, s, dtype=np.uint8).tobytes() for s in tx_sizes]
+    want = shares.split_txs(ns_mod.PAY_FOR_BLOB_NAMESPACE, txs)
+    out = np.zeros((len(want) + 2, 512), dtype=np.uint8)
+    assert shares.write_txs(
+        out, 1, ns_mod.PAY_FOR_BLOB_NAMESPACE, txs) == len(want)
+    assert out[1:-1].tobytes() == b"".join(s.raw for s in want)
+    assert not out[0].any() and not out[-1].any()
+
+
+def test_write_blob_refuses_what_split_blob_refuses():
+    ns = ns_mod.Namespace.v0(b"writer")
+    out = np.zeros((2, 512), dtype=np.uint8)
+    with pytest.raises(ValueError):
+        shares.split_blob(ns, b"x", share_version=9)
+    with pytest.raises(ValueError):
+        shares.write_blob(out, 0, ns, b"x", share_version=9)
+
+
+def test_padding_row_is_the_padding_share():
+    ns = ns_mod.Namespace.v0(b"pad")
+    assert shares.padding_row(ns).tobytes() \
+        == shares.namespace_padding_share(ns).raw
+    assert shares.padding_row(ns_mod.TAIL_PADDING_NAMESPACE).tobytes() \
+        == shares.tail_padding_share()
+    assert shares.padding_row(
+        ns_mod.PRIMARY_RESERVED_PADDING_NAMESPACE).tobytes() \
+        == shares.reserved_padding_share().raw

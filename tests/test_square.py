@@ -6,9 +6,14 @@ import pytest
 from celestia_app_tpu.da import namespace as ns_mod
 from celestia_app_tpu.da import shares as shares_mod
 from celestia_app_tpu.da import square as square_mod
-from celestia_app_tpu.da.blob import Blob, unmarshal_index_wrapper
+from celestia_app_tpu.da.blob import (
+    Blob,
+    marshal_index_wrapper,
+    unmarshal_index_wrapper,
+)
 from celestia_app_tpu.da.commitment import subtree_width
 from celestia_app_tpu.da.square import PfbEntry
+from celestia_app_tpu.utils import telemetry
 
 THRESHOLD = 64
 
@@ -263,3 +268,178 @@ def test_builder_reserve_invariants_fuzz():
         assert [s.raw for s in built.shares] == [
             s.raw for s in constructed.shares
         ], trial
+
+
+# ---------------------------------------------------------------------------
+# The square as one array: byte identity with the share-by-share definition
+# ---------------------------------------------------------------------------
+
+
+def _reference_share_bytes(txs, pfbs, max_sq, threshold=THRESHOLD) -> bytes:
+    """The square as da/shares defines it share by share: `split_txs`,
+    `split_blob` and the padding constructors placed by `_Layout`'s
+    indexes, joined."""
+    layout = square_mod._Layout(txs, pfbs, threshold, max_sq)
+    k = max(layout.square_size(), 1)
+    shares = []
+    if layout.tx_shares:
+        shares += shares_mod.split_txs(ns_mod.TX_NAMESPACE, txs)
+    if layout.pfb_shares_reserved:
+        shares += shares_mod.split_txs(ns_mod.PAY_FOR_BLOB_NAMESPACE, [
+            marshal_index_wrapper(
+                e.tx, [layout.starts[(i, j)] for j in range(len(e.blobs))])
+            for i, e in enumerate(pfbs)
+        ])
+    prev_ns = None
+    for _, i, j in layout.ordered:
+        b = pfbs[i].blobs[j]
+        gap = layout.starts[(i, j)] - len(shares)
+        shares += [shares_mod.reserved_padding_share() if prev_ns is None
+                   else shares_mod.namespace_padding_share(prev_ns)] * gap
+        shares += shares_mod.split_blob(b.namespace, b.data, b.share_version)
+        prev_ns = b.namespace
+    shares += shares_mod.tail_padding_shares(k * k - len(shares))
+    assert len(shares) == k * k
+    return b"".join(s.raw for s in shares)
+
+
+def _tx(rng, size: int) -> bytes:
+    return rng.integers(0, 256, size, dtype=np.uint8).tobytes()
+
+
+def _identity_case(name: str):
+    """(txs, pfbs, max square) of one branch of the writer."""
+    rng = np.random.default_rng(len(name))
+    if name == "empty":
+        return [], [], 64
+    if name == "txs_only":
+        return [_tx(rng, s) for s in (50, 700, 30)], [], 64
+    if name == "tx_units_on_share_boundaries":
+        # unit 1 = 2 + 472 = the first share's 474 content bytes, so unit 2
+        # starts on share 1's first content byte; unit 2 = 3 x 478 spans
+        # three shares none of which starts a unit; unit 3 starts on share
+        # 4's first content byte and ends mid-share
+        return [_tx(rng, 472), _tx(rng, 3 * 478 - 2), _tx(rng, 100)], [], 64
+    if name == "pfb_reservation_longer_than_written":
+        return [], [PfbEntry(b"p%02d" % i, (_blob(rng, 10 + i, 600),))
+                    for i in range(28)], 128
+    if name == "blob_size_edges":
+        sizes = (1, 478, 479, 478 + 482, 478 + 482 + 1, 478 + 482 * 7,
+                 478 + 482 * 7 + 1)
+        return [b"tx"], [PfbEntry(b"pfb-%d" % i, (_blob(rng, 20 + i, s),))
+                          for i, s in enumerate(sizes)], 64
+    if name == "equal_namespaces_and_namespace_padding":
+        # 70 shares a blob -> subtree width 2: odd starts leave namespace
+        # padding; two blobs share namespace 5, across two PFBs
+        big = 478 + 482 * 69
+        return [_tx(rng, 80)], [
+            PfbEntry(b"pfb-a", (_blob(rng, 5, big), _blob(rng, 9, big + 1))),
+            PfbEntry(b"pfb-b", (_blob(rng, 5, big - 3), _blob(rng, 7, 90))),
+        ], 64
+    if name == "build_drops_overflow":
+        return [_tx(rng, 40)], [
+            PfbEntry(b"big", (_blob(rng, 6, 200 * 478),)),
+            PfbEntry(b"small", (_blob(rng, 7, 100), _blob(rng, 3, 900))),
+        ], 4
+    raise AssertionError(name)
+
+
+@pytest.mark.parametrize("via", ["build", "construct"])
+@pytest.mark.parametrize("name", [
+    "empty", "txs_only", "tx_units_on_share_boundaries",
+    "pfb_reservation_longer_than_written", "blob_size_edges",
+    "equal_namespaces_and_namespace_padding", "build_drops_overflow",
+])
+def test_square_array_is_the_share_by_share_square(name, via):
+    txs, pfbs, max_sq = _identity_case(name)
+    if via == "build":
+        sq = square_mod.build(txs, pfbs, max_sq, THRESHOLD)
+        if name == "build_drops_overflow":
+            assert [e.tx for e in sq.pfbs] == [b"small"]
+    else:
+        if name == "build_drops_overflow":
+            with pytest.raises(ValueError):
+                square_mod.construct(txs, pfbs, max_sq, THRESHOLD)
+            pfbs = pfbs[1:]
+        sq = square_mod.construct(txs, pfbs, max_sq, THRESHOLD)
+    assert sq.ods.shape == (sq.size, sq.size, 512)
+    assert sq.ods.dtype == np.uint8 and sq.ods.flags.c_contiguous
+    assert not sq.ods.flags.writeable
+    assert sq.ods.tobytes() == _reference_share_bytes(sq.txs, sq.pfbs, max_sq)
+    # the branch the case is named for is really taken
+    raw = sq.share_bytes()
+    namespaces = [r[:29] for r in raw]
+    if name == "empty":
+        assert sq.size == 1
+    if name == "tx_units_on_share_boundaries":
+        reserved = [int.from_bytes(r[34:38] if i == 0 else r[30:34], "big")
+                    for i, r in enumerate(raw[: sq.tx_shares_len])]
+        assert reserved == [38, 34, 0, 0, 34]
+    if name == "pfb_reservation_longer_than_written":
+        assert sq.pfb_shares_len < sq.pfb_shares_reserved
+        assert ns_mod.PRIMARY_RESERVED_PADDING_NAMESPACE.raw in namespaces
+    if name == "equal_namespaces_and_namespace_padding":
+        padding = [r for r in raw if r[29] == 1 and r[30:34] == bytes(4)
+                   and r[:29] != ns_mod.TAIL_PADDING_NAMESPACE.raw]
+        assert padding and all(r[28] in (5, 7, 9) for r in padding)
+
+
+def _share_objects() -> int:
+    return telemetry.snapshot()["counters"].get("square.share_objects", 0)
+
+
+def test_shares_view_is_derived_and_counted():
+    """`.shares` / `share_bytes()` are built from the array on every call
+    and `square.share_objects` counts what they build."""
+    rng = np.random.default_rng(11)
+    before = _share_objects()
+    sq = square_mod.build([], [PfbEntry(b"p", (_blob(rng, 4, 3000),))],
+                          64, THRESHOLD)
+    assert _share_objects() == before
+    shares = sq.shares
+    assert _share_objects() == before + sq.size ** 2
+    assert shares is not sq.shares
+    assert [s.raw for s in shares] == sq.share_bytes()
+    assert _share_objects() == before + 3 * sq.size ** 2
+    assert b"".join(sq.share_bytes()) == sq.ods.tobytes()
+
+
+def test_block_path_builds_no_share_objects(tmp_path):
+    """Prepare -> Process -> commit -> a rebuild of the height (light round
+    and namespace read on a core the warmer does not seed), 8x8 squares:
+    the derived share view is never materialised."""
+    from obs_drive import drive
+
+    before = _share_objects()
+    out = drive("host", str(tmp_path / "home"), blocks=2)
+    assert out["height"] == 2
+    names = {r["name"] for r in out["rows"]}
+    assert {"square.build", "square.construct",
+            "query.rebuild_square"} <= names
+    assert _share_objects() == before
+
+
+def test_construct_leaves_few_tracked_objects():
+    """A full k=32 square leaves fewer GC-tracked objects behind than a
+    tenth of its shares (one object a share and more, before the square
+    was one array): what a layout promotes towards a full collection."""
+    import gc
+
+    rng = np.random.default_rng(32)
+    pfbs = [PfbEntry(b"pfb-%d" % i,
+                     tuple(_blob(rng, 1 + (i + j) % 8, 12_000)
+                           for j in range(6)))
+            for i in range(6)]
+    square_mod.construct([], pfbs, 32, THRESHOLD)  # anything lazy, once
+    gc.collect()
+    gc.disable()
+    try:
+        before = len(gc.get_objects())
+        sq = square_mod.construct([], pfbs, 32, THRESHOLD)
+        left = len(gc.get_objects()) - before
+    finally:
+        gc.enable()
+    assert sq.size == 32
+    used = max(sq.blob_start_indexes.values())
+    assert used > 0.8 * 32 * 32
+    assert left < 32 * 32 // 10, left
