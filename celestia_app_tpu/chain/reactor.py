@@ -107,8 +107,8 @@ class ReactorConfig:
     # runs the unchanged per-height verification — the replay loop is
     # verification-bound, not RTT-bound. `blocksync_serve_bytes` caps one
     # served range response; `blocksync_pipeline=False` keeps the
-    # per-height round-trip loop (the differential baseline bench.py
-    # --sync measures against).
+    # per-height round-trip loop (the differential baseline of
+    # tests/test_sync.py; neither is measured on the chip's host).
     blocksync_pipeline: bool = True
     blocksync_serve_bytes: int = 2 << 20
     # chunked state sync: parallel chunk fetchers per restore, and the

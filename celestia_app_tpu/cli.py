@@ -1104,7 +1104,7 @@ def cmd_multihost_dryrun(args) -> int:
 
 def cmd_e2e_bench(args) -> int:
     """Throughput benchmark on the autonomous process devnet — see
-    tools/e2e_bench.py (the test/e2e/benchmark/throughput.go analog)."""
+    tools.e2e_bench (the test/e2e/benchmark/throughput.go analog)."""
     from celestia_app_tpu.tools import e2e_bench
 
     return e2e_bench.run(args, _spawn_validator_processes,

@@ -14,7 +14,7 @@ Why a second scheme at all (the north star's economics):
   sibling hashes per layer step — 512 + 3*224 + varints = 1187 canonical
   wire bytes at k=128 (FORMATS §16.3) against 2D-RS+NMT's
   512 + 8*90 + varints = 1238 (and 4 sha256 invocations to verify
-  against 9): strictly smaller, `bench.py --codec` measures it.
+  against 9): strictly smaller (byte counts; no timing measured).
 - **O(1) fraud proofs.** Incorrect coding is proven by ONE violated
   parity equation — d+1 symbols with their inclusion proofs (~12 KB at
   k=128) — against a BEFP's k shares + orthogonal proofs (~160 KB).
@@ -31,8 +31,7 @@ each uniform sample then catches it with probability > 1/4. Unlike the
 2D-RS bound this threshold is empirical-random, not combinatorial —
 adversarially-shaped stopping sets below it are not excluded by
 construction (the paper's hand-designed ensembles bound them; ours pins
-the threshold by test) — which is exactly the kind of trade
-`bench.py --codec` exists to surface.
+the threshold by test).
 
 Engine gating mirrors da/edscache.compute_entry: "device" demands jax
 (LDPC bit-matmul + batched sha256 on device), "host" never touches it

@@ -144,8 +144,8 @@ class Codec:
 
     def sample_wire_bytes(self, doc: dict, commitments=None) -> int:
         """Exact canonical binary size of one sample proof (FORMATS
-        §16.3) — the honest per-sample cost `bench.py --codec` reports
-        (NOT the JSON/base64 transport inflation). Schemes whose wire
+        §16.3) — the per-sample cost on the wire (NOT the JSON/base64
+        transport inflation). Schemes whose wire
         size depends on geometry take the commitments too."""
         raise NotImplementedError
 

@@ -100,7 +100,7 @@ def jitted_pipeline_batched(k: int):
     and keeping the MXU fed when single squares underfill it (the
     one-chip analog of the sharded pipeline's `data` axis; BASELINE cfg 5
     throughput). vmap of the single-square program — bit-identical per
-    block (tests/test_streaming.py)."""
+    block (tests/test_sharded_eds.py)."""
     from celestia_app_tpu.obs import jax_profile
 
     jax_profile.note_compile("eds.pipeline_batched", k)
@@ -108,33 +108,10 @@ def jitted_pipeline_batched(k: int):
                                   jax.jit(jax.vmap(pipeline_fn(k))))
 
 
-def roots_only_fn(k: int):
-    """Variant that keeps the EDS on device and returns only roots (less HBM
-    traffic back to host for the PrepareProposal fast path)."""
-    full = pipeline_fn(k)
-
-    def run(ods: jax.Array):
-        _, row_roots, col_roots, data_root = full(ods)
-        return row_roots, col_roots, data_root
-
-    return run
-
-
-@functools.lru_cache(maxsize=None)
-def jitted_roots_only(k: int):
-    from celestia_app_tpu.obs import jax_profile
-
-    jax_profile.note_compile("eds.roots_only", k)
-    return jax_profile.instrument(f"eds.roots_only[{k}]",
-                                  jax.jit(roots_only_fn(k)))
-
-
 # live jit-cache-size accounting (obs/jax_profile collect_gauges): the
-# gauge reads cache_info().currsize, so bench-driven cache_clear() calls
-# keep it honest
+# gauge reads cache_info().currsize, so a cache_clear() keeps it honest
 from celestia_app_tpu.obs import jax_profile as _jax_profile  # noqa: E402
 
-for _factory in (jitted_pipeline, jitted_pipeline_batched,
-                 jitted_roots_only):
+for _factory in (jitted_pipeline, jitted_pipeline_batched):
     _jax_profile.register_cache(_factory)
 del _factory, _jax_profile

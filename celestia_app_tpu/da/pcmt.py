@@ -24,7 +24,7 @@ is the parent symbol. A sample proof step then carries 6 sibling
 hashes (192 B) instead of 63 (2016 B), and the layer recursion shrinks
 by ~Q/(C/D) ≈ 9-13x per step — at k=128 the tree telescopes in a few
 layers and a sample proof stays smaller than both other schemes
-(`bench.py --codec` measures the three-way).
+(byte counts; no timing measured).
 
 Sampling threshold: light clients draw uniformly over the C_0 BASE
 committed classes (each sample's proof carries one batch-subtree path
@@ -36,8 +36,7 @@ size (D = 16 through 16384, measured before this module was written),
 so a withholder must hide beyond that fraction to threaten recovery.
 Like the CMT's, this threshold is empirical-random, not combinatorial —
 the paper's informed frozen-set design *shrinks* stopping sets rather
-than excluding them — which is exactly the trade `bench.py --scenario`
-judges under identical seeded attacks.
+than excluding them.
 
 Engine gating mirrors da/cmt.py: "device" demands jax (polar bit-matmul
 butterflies + batched sha256 on device), "host" never touches it,

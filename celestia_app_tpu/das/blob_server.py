@@ -110,7 +110,8 @@ class BlobCore:
         """GET /blob/get: one namespace at one height, resolved with the
         host reference's per-query scan
         (da/namespace_data.get_namespace_data) — the per-request loop
-        the batched route is benchmarked against (bench.py --read)."""
+        the batched route replaces (the two are not measured against
+        each other on the chip's host)."""
         namespace = self._parse_namespace(namespace_hex)
         entry = self._entry(height)
         telemetry.incr("blob.namespace_queries")

@@ -21,9 +21,7 @@ Sampling is ``CELESTIA_OBS``-gated (the spans gate — `start` is a
 no-op when observability is off) and costs one mostly-sleeping thread
 per service: ~20 wakes/s of a few µs each (the interval sits well
 above CPython's 5 ms switch interval on purpose — a probe at the
-switch interval competes for the GIL instead of observing it), which
-is what ``bench.py --obs`` arms when it measures the observatory's
-overhead.
+switch interval competes for the GIL instead of observing it).
 
 The peak-RSS collector rides along because it is the same kind of
 process-level pressure number: PR 18 tracked ``peak_rss_bytes`` only

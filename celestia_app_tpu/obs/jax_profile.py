@@ -47,8 +47,7 @@ class ProfileError(ValueError):
 _lock = threading.Lock()
 _capturing = False
 # lru-cached jitted factories registered for live cache-size accounting
-# (reading cache_info() at scrape time stays honest across cache_clear(),
-# which bench.py calls repeatedly)
+# (reading cache_info() at scrape time stays honest across cache_clear())
 _factories: list = []
 
 MAX_CAPTURE_SECONDS = 30.0
@@ -91,9 +90,8 @@ class _Instrumented:
         out = self._fn(*args, **kwargs)
         if self._compiled:
             # steady state measures DISPATCH, deliberately: blocking here
-            # would serialize the streaming pipelines whose whole design
-            # is overlapping host work with device compute (parallel/
-            # streaming.py). On async backends this is enqueue latency,
+            # would serialize a caller that overlaps host work with
+            # device compute. On async backends this is enqueue latency,
             # and named so; the block path's execution time is the span
             # da.extend.run (da/edscache.compute_entry), device-side time
             # comes from /debug/profile (FORMATS §10.2).

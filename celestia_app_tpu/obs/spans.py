@@ -46,8 +46,8 @@ one instrument serves the operator, the benchmark and the profiler:
 
 Recording is gated by ``CELESTIA_OBS`` (off/0/false disables; see
 `enabled`): a disabled span is a shared no-op object, so the hot path
-pays one dict lookup and one truthiness check. ``bench.py --obs``
-measures exactly this on/off delta.
+pays one dict lookup and one truthiness check; the on/off delta on the
+chip's host is in PERF.md §6 (PR 26).
 """
 
 from __future__ import annotations

@@ -4,8 +4,8 @@ ROADMAP item 2 (zero-copy blob path) is blocked on one number that no
 counter in the tree produces: how many bytes cross the host↔device
 boundary per committed block. `edscache.host_crossings` counts one
 narrow path (lazy host materialization of a device-resident entry);
-the dispatch uploads, commitment fetches, streaming drains, and ops
-runner round-trips are all invisible. This module closes that hole the
+the dispatch uploads, commitment fetches, and ops runner round-trips
+are all invisible. This module closes that hole the
 way arXiv:2108.02692 profiles erasure-coding kernels — measure the
 memory traffic first, then optimize:
 
@@ -16,7 +16,7 @@ memory traffic first, then optimize:
   ``_calls`` twins) and latency histograms ``xfer.h2d``/``xfer.d2h``
   land in the telemetry registry, so /metrics exposes the full
   per-site traffic matrix. Every `device_put`/`device_get` in the
-  tree (edscache, mesh_engine, streaming, ops runners) routes through
+  tree (edscache, mesh_engine, ops runners) routes through
   them.
 - **Ledger rows.** When span recording is on (CELESTIA_OBS) and a span
   is active, each transfer also writes one row to the ``xfer`` trace
@@ -46,8 +46,8 @@ memory traffic first, then optimize:
 
 Counting is always-on (two dict writes under the registry lock — the
 same cost class as `edscache.host_crossings`); the ledger ROWS, the
-span totals and the annotation follow the CELESTIA_OBS gate.
-``bench.py --obs`` measures the armed on/off delta.
+span totals and the annotation follow the CELESTIA_OBS gate (its cost
+on the chip's host: PERF.md §6, PR 26).
 """
 
 from __future__ import annotations

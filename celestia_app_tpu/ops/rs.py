@@ -56,18 +56,6 @@ def bits_to_bytes(b: jax.Array) -> jax.Array:
 
 def _gf_mix(bit_mat: jax.Array, x_bits: jax.Array) -> jax.Array:
     """(8k,8k) x (..., 8k, S) -> (..., 8k, S), all arithmetic mod 2 via int matmul."""
-    if bit_mat.dtype == jnp.bfloat16:
-        # 0/1 products accumulate exactly in f32 up to 2^24 terms; the dot
-        # length is 8k (gf8, ≤1024) or 16k (gf16, ≤524288 at the field's
-        # max k=32768) — far below 2^24 — so the mod-2 result is exact
-        # while the matmul runs at the MXU's bf16 rate
-        out = jnp.einsum(
-            "pq,...qs->...ps",
-            bit_mat,
-            x_bits.astype(jnp.bfloat16),
-            preferred_element_type=jnp.float32,
-        )
-        return (out.astype(jnp.int32) & 1).astype(jnp.int8)
     out = jnp.einsum(
         "pq,...qs->...ps", bit_mat, x_bits, preferred_element_type=jnp.int32
     )
@@ -103,102 +91,31 @@ def bits_to_bytes16(b: jax.Array) -> jax.Array:
 
 
 def _codec(k: int):
-    """(bit_matrix, to_bits, from_bits, bits_per_symbol) for the field."""
+    """(bit_matrix, to_bits, from_bits) for the field."""
     if leopard.uses_gf16(k):
-        return leopard.bit_matrix16(k), bytes_to_bits16, bits_to_bytes16, 16
-    return leopard.bit_matrix(k), bytes_to_bits, bits_to_bytes, 8
+        return leopard.bit_matrix16(k), bytes_to_bits16, bits_to_bytes16
+    return leopard.bit_matrix(k), bytes_to_bits, bits_to_bytes
 
 
-def _gf_mix_flat(bit_mat: jax.Array, x_bits: jax.Array) -> jax.Array:
-    """Same contraction as _gf_mix but reshaped into ONE large GEMM:
-    (8k, 8k) @ (8k, batch*S). A single big matmul keeps the MXU pipeline
-    full where `batch` small GEMMs each pay their own tiling overhead —
-    the layout the bench's --stages probe compares against the batched
-    einsum on hardware (select with CELESTIA_RS_LAYOUT=flat)."""
-    lead = x_bits.shape[:-2]
-    q, s = x_bits.shape[-2], x_bits.shape[-1]
-    flat = x_bits.reshape(-1, q, s)
-    b = flat.shape[0]
-    x = jnp.transpose(flat, (1, 0, 2)).reshape(q, b * s)
-    if bit_mat.dtype == jnp.bfloat16:
-        out = jnp.matmul(
-            bit_mat, x.astype(jnp.bfloat16),
-            preferred_element_type=jnp.float32,
-        )
-        out = (out.astype(jnp.int32) & 1).astype(jnp.int8)
-    else:
-        out = jnp.matmul(bit_mat, x, preferred_element_type=jnp.int32)
-        out = (out & 1).astype(jnp.int8)
-    return jnp.transpose(out.reshape(q, b, s), (1, 0, 2)).reshape(*lead, q, s)
-
-
-def _rs_layout() -> str:
-    import os
-
-    return os.environ.get("CELESTIA_RS_LAYOUT", "batched")
-
-
-def _rs_dtype() -> str:
-    import os
-
-    return os.environ.get("CELESTIA_RS_DTYPE", "int8")
-
-
-def extend_square_fn(k: int, layout: str | None = None, dtype: str | None = None):
+def extend_square_fn(k: int):
     """Return a jittable fn: (k, k, 512) uint8 ODS -> (2k, 2k, 512) uint8 EDS.
 
     k <= 128 uses the GF(2^8) code; k >= 256 the GF(2^16) code (leopard16),
-    both as one bit-matrix MXU matmul per pass. `layout`/`dtype` (or envs
-    CELESTIA_RS_LAYOUT / CELESTIA_RS_DTYPE) pick the matmul schedule:
-    "batched" einsum vs "flat" single-GEMM, int8 accumulate-int32 vs bf16
-    accumulate-f32 — all four bit-identical, different hardware paths."""
-    mat, to_bits, from_bits, sym_bits = _codec(k)
-    dtype = dtype or _rs_dtype()
-    layout = layout or _rs_layout()
-    if dtype not in ("int8", "bf16"):
-        raise ValueError(f"RS dtype must be 'int8' or 'bf16', not {dtype!r}")
-    if layout not in ("batched", "flat", "fused", "pallas"):
-        raise ValueError(
-            f"RS layout must be 'batched', 'flat', 'fused' or 'pallas', "
-            f"not {layout!r}"
-        )
-    if layout == "pallas":
-        # the Pallas pass is inherently bf16-accumulate-f32 (dtype is
-        # implied; an explicit different dtype is a caller error)
-        if dtype not in (None, "bf16") and dtype != _rs_dtype():
-            raise ValueError("layout='pallas' implies dtype='bf16'")
-        if leopard.uses_gf16(k):
-            # the Pallas pass covers the 8-bit field; 16-bit squares use
-            # the XLA formulation
-            layout = "flat"
-        else:
-            from celestia_app_tpu.ops import rs_pallas
-
-            return rs_pallas.extend_square_fn(k)
-    mm_dtype = jnp.bfloat16 if dtype == "bf16" else jnp.int8
-    bit_mat = jnp.asarray(mat, dtype=mm_dtype)  # constant folded into the jaxpr
-    mix = _gf_mix_flat if layout in ("flat", "fused") else _gf_mix
+    both as one bit-matrix MXU matmul per pass: an int8 einsum batched
+    over the k rows (or columns), accumulated in int32."""
+    mat, to_bits, from_bits = _codec(k)
+    bit_mat = jnp.asarray(mat)  # constant folded into the jaxpr
 
     def extend(ods: jax.Array) -> jax.Array:
         assert ods.shape == (k, k, SHARE), ods.shape
         # Row pass: mix across the share index within each row.
-        q1 = from_bits(mix(bit_mat, to_bits(ods)))  # (k, k, S)
+        q1 = from_bits(_gf_mix(bit_mat, to_bits(ods)))  # (k, k, S)
         # Column pass: transpose so columns become the mixing axis.
-        col_bits = mix(bit_mat, to_bits(jnp.swapaxes(ods, 0, 1)))
+        col_bits = _gf_mix(bit_mat, to_bits(jnp.swapaxes(ods, 0, 1)))
         q2 = jnp.swapaxes(from_bits(col_bits), 0, 1)  # (k parity rows, k cols, S)
-        if layout == "fused":
-            # Q3 feeds on Q2's BITS directly: the column pass produced
-            # (col, sym_bits*parity_row + i, s); a pure bit-space transpose
-            # gives the row pass's (row, sym_bits*col + i, s) — eliding a
-            # pack+unpack round trip through the byte domain
-            sdim = col_bits.shape[-1]
-            b4 = col_bits.reshape(k, k, sym_bits, sdim)  # (c, r, i, s)
-            q3_in = jnp.transpose(b4, (1, 0, 2, 3)).reshape(k, sym_bits * k, sdim)
-            q3 = from_bits(mix(bit_mat, q3_in))
-        else:
-            # Q3 = row-extend Q2 (== column-extend Q1,
-            # data_structures.md:304-310)
-            q3 = from_bits(mix(bit_mat, to_bits(q2)))
+        # Q3 = row-extend Q2 (== column-extend Q1,
+        # data_structures.md:304-310)
+        q3 = from_bits(_gf_mix(bit_mat, to_bits(q2)))
         top = jnp.concatenate([ods, q1], axis=1)
         bottom = jnp.concatenate([q2, q3], axis=1)
         return jnp.concatenate([top, bottom], axis=0)

@@ -6,10 +6,9 @@ point is that the extension factor is a PROTOCOL KNOB, not a law of
 nature: stretching an axis to n > 2k raises the fraction an adversary
 must withhold (fewer samples to a confidence target, at more encoded
 bytes), while n < 2k trades the other way. This module is the
-bench-level instrument for that sweep — a systematic RS code with a
+instrument for that sweep — a systematic RS code with a
 *parametrized* (k, n) per axis, n_r x n_c rectangles included — NOT a
-registered wire codec: `bench.py --codec` sweeps it next to the three
-committed schemes so the knob's economics are measured, not assumed.
+registered wire codec, and on no benchmark cell's path: not measured.
 
 Construction: classic GF(2^8) evaluation RS. Data shard j sits at
 evaluation point j; the codeword is the degree-(k-1) interpolating

@@ -103,7 +103,7 @@ def _roots_from_leaves_local(
 
 def _local_pipeline(k: int, n_seq: int):
     """The per-device program run under shard_map."""
-    mat, to_bits, from_bits, _sym_bits = rs._codec(k)  # field by k
+    mat, to_bits, from_bits = rs._codec(k)  # field by k
     bit_mat = jnp.asarray(mat)
 
     def run(ods_local: jax.Array):

@@ -9,11 +9,11 @@ engine for tens of validators plus hundreds of DASer light nodes.
   SimLightNode (a real das/daser.DASer swept on the virtual timeline),
   and Simulation, which wires them and computes verdict metrics.
 - scenarios.py — the declarative adversarial scenario library (dict/JSON
-  specs -> faults + topology ops) and ``run_scenario``, the entry
-  ``bench.py --scenario`` and the tier-1 matrix share.
+  specs -> faults + topology ops) and ``run_scenario``, the entry of
+  the tier-1 matrix.
 
 docs/DESIGN.md "The scenario plane" is the normative description;
-docs/FORMATS.md §19 holds the spec grammar and the BENCH JSON schema.
+docs/FORMATS.md §19 holds the spec grammar and the verdict's schema.
 """
 
 from celestia_app_tpu.sim.scenarios import (  # noqa: F401

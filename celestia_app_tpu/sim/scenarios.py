@@ -6,9 +6,8 @@ primitives: the serving plane's withholding gate (das/server.withhold),
 the malicious-producer fixtures (testing/malicious.py), topology cuts
 (partitions, downs, eclipses), deterministic spam, and state-sync joins.
 ``run_scenario`` builds the world, installs the ops, runs the seeded
-timeline, and reduces the raw results to ONE verdict dict — the BENCH
-JSON payload of ``bench.py --scenario`` and the byte-identity witness of
-the tier-1 determinism matrix.
+timeline, and reduces the raw results to ONE verdict dict — the
+byte-identity witness of the tier-1 determinism matrix.
 
 Op grammar (each op is a dict with an ``op`` key):
 

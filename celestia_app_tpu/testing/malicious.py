@@ -177,7 +177,7 @@ def incorrect_coding_fixture(scheme: str, ods: np.ndarray,
                              engine: str = "host"):
     """THE scheme-keyed committed-non-codeword fixture: returns (entry,
     location, withheld_cells, wire_id) for any registered scheme — the
-    one hook sim/scenarios.py and bench.py drive, so judging a new
+    one hook sim/scenarios.py drives, so judging a new
     codec needs a fixture here and no if-chains there. ``location`` is
     what the scheme's repair provably raises; ``withheld_cells`` is a
     quarter-ish withholding set that forces samplers to escalate while

@@ -19,9 +19,8 @@ persistent connections, barrier start, one JSON report):
   against the manifest).
 
 Report: ``namespace_queries_per_sec``, per-request ``p50_ms``/``p99_ms``,
-``present_ratio``, ``pack_hit_ratio``, error counts. ``bench.py --read``
-drives single vs batch (the >=5x gate) and pack vs live head to head and
-emits the BENCH JSON lines; docs/FORMATS.md §21.5 is the schema.
+``present_ratio``, ``pack_hit_ratio``, error counts; docs/FORMATS.md
+§21.5 is the schema. No benchmark cell runs it: not measured on the chip.
 
 Standalone use against any devnet:
 

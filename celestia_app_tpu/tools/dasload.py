@@ -20,9 +20,8 @@ concurrency:
 
 Output (and the ``run_load`` return value) is one JSON report:
 ``samples_per_sec``, ``requests_per_sec``, ``p50_ms``/``p99_ms`` per
-request, ``pack_hit_ratio``, error counts. ``bench.py --serve`` drives
-two runs of this harness (live vs pack) head to head and emits the
-BENCH JSON lines; docs/FORMATS.md §17.5 is the schema.
+request, ``pack_hit_ratio``, error counts; docs/FORMATS.md §17.5 is the
+schema. No benchmark cell runs it: not measured on the chip.
 
 Standalone use against any devnet:
 

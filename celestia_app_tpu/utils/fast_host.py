@@ -1,10 +1,10 @@
 """Fast CPU implementation of the DA pipeline (numpy BLAS + hashlib).
 
-This is the *baseline to beat* for bench.py: the strongest CPU path we can
-field without the reference's Go toolchain — the same role rsmt2d's SIMD
-LeoRS codec + hardware SHA-256 play in the reference
-(pkg/da/data_availability_header.go:65-108). It is also a fast oracle for
-tests (bit-identical to utils/refimpl, which is pure-Python-slow).
+The strongest CPU path we can field without the reference's Go
+toolchain — the same role rsmt2d's SIMD LeoRS codec + hardware SHA-256
+play in the reference (pkg/da/data_availability_header.go:65-108). Tests
+use it as a fast oracle (bit-identical to utils/refimpl, which is
+pure-Python-slow).
 
 - RS extension: the GF(256) generator as an (8k, 8k) GF(2) bit matrix,
   applied as one float32 BLAS matmul per axis pass (exact: dot products of

@@ -1,7 +1,7 @@
 """Build + invoke the native C++ baseline pipeline (native/baseline_pipeline.cc).
 
-Shared by bench.py (baseline measurement) and tests (cross-validation of the
-independent C++ reimplementation against the Python pipelines)."""
+Used by tests: cross-validation of the independent C++ reimplementation
+against the Python pipelines."""
 
 from __future__ import annotations
 

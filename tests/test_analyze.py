@@ -1046,11 +1046,10 @@ def test_cli_effects_prints_symbol_summary():
 
 def test_full_tree_wall_time_budget():
     """The tier-1/pre-commit cost must stay interactive: < 10 s on CPU
-    cold (bench.py --analyze reports cold AND cache-warm numbers as
-    BENCH JSON; the warm gate lives there). Priced in this process's CPU
-    seconds, not wall: the analysis is one thread (5.6 s on an idle core),
-    and under `pytest -n 6` the wall clock measures the neighbours — it
-    read 12.4 s beside the TPU-compile tests."""
+    cold. Priced in this process's CPU seconds, not wall: the analysis is
+    one thread (5.6 s on an idle core), and under `pytest -n 6` the wall
+    clock measures the neighbours — it read 12.4 s beside the TPU-compile
+    tests."""
     import time
 
     cpu0 = time.process_time()

@@ -380,9 +380,9 @@ def test_save_block_prices_encode_against_put(db):
     assert all(p["parent_id"] == whole["span_id"] for p in parts)
     assert by_name["storage.block.put"]["bytes"] == len(
         db.backend.get(storage.BLOCK, 1))
-    # the two children are all of save_block but the spans' own exits
+    # the two children lie inside save_block; how nearly they fill it is
+    # a wall-clock reading, and the chip's to show (PERF.md §5)
     assert sum(p["dur_ms"] for p in parts) <= whole["dur_ms"] + 0.01
-    assert sum(p["dur_ms"] for p in parts) >= 0.8 * whole["dur_ms"] - 0.2
 
 
 @pytest.mark.parametrize("metric,span", [

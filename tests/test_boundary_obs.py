@@ -1,5 +1,5 @@
 """Boundary observatory: transfer ledger, residency pins, lock/GIL
-profiling, the SLO verdict engine, and the bench differ (ISSUE 19).
+profiling and the SLO verdict engine (ISSUE 19).
 
 The acceptance stories:
 - every lazy host materialization in the device EDS cache goes through
@@ -14,12 +14,9 @@ The acceptance stories:
   CELESTIA_OBS gate and lands its histogram + pressure gauge;
 - fleetmon evaluates declarative SLO rules against a LIVE HTTP node
   into a deterministic verdict (byte-identical across scrapes of the
-  same fleet state);
-- benchdiff flags a synthetic same-backend regression with exit code 2
-  and keeps cpu-fallback rounds out of hardware comparisons.
+  same fleet state).
 """
 
-import json
 import threading
 import time
 import urllib.request
@@ -30,7 +27,7 @@ import pytest
 import celestia_app_tpu.obs as obs
 from celestia_app_tpu.obs import gil, xfer
 from celestia_app_tpu.obs.xfer import ImplicitTransferError, no_implicit_transfers
-from celestia_app_tpu.tools import benchdiff, fleetmon
+from celestia_app_tpu.tools import fleetmon
 from celestia_app_tpu.tools.analyze import racecheck
 from celestia_app_tpu.utils import telemetry
 
@@ -329,67 +326,6 @@ def test_fleetmon_rejects_malformed_rules():
     ):
         with pytest.raises(ValueError):
             fleetmon.normalize_rules(doc)
-
-
-# ---------------------------------------------------------------------------
-# benchdiff: the bench-history differ
-# ---------------------------------------------------------------------------
-
-
-def _write_round(tmp_path, label, rows):
-    doc = dict(rows[0])
-    if len(rows) > 1:
-        doc["extras"] = rows[1:]
-    (tmp_path / f"BENCH_{label}.json").write_text(json.dumps(doc))
-
-
-def test_benchdiff_flags_regression_and_excludes_cpu_fallback(tmp_path):
-    _write_round(tmp_path, "r01", [
-        {"metric": "commit_ms", "value": 10.0, "unit": "ms"},
-        {"metric": "blocks_per_sec", "value": 100.0, "unit": "blocks/s"},
-    ])
-    _write_round(tmp_path, "r02", [
-        {"metric": "commit_ms", "value": 10.5, "unit": "ms"},
-        {"metric": "blocks_per_sec", "value": 60.0, "unit": "blocks/s"},
-    ])
-    # a cpu-fallback round between hardware rounds: shown, never judged
-    _write_round(tmp_path, "r03", [
-        {"metric": "commit_ms", "value": 99.0, "unit": "ms",
-         "backend": "cpu-fallback"},
-    ])
-    _write_round(tmp_path, "r04", [
-        {"metric": "commit_ms", "value": 20.0, "unit": "ms"},
-    ])
-
-    rounds = benchdiff.load_rounds(
-        sorted(str(p) for p in tmp_path.glob("BENCH_*.json")))
-    assert [label for label, _ in rounds] == ["r01", "r02", "r03", "r04"]
-
-    report = benchdiff.diff(rounds)
-    cm = report["metrics"]["commit_ms"]
-    # r04 (20.0) judged vs r02 (10.5) — r03 is cpu-fallback, skipped
-    assert cm["status"] == "regressed"
-    assert cm["samples"][2]["skipped"] is True
-    bs = report["metrics"]["blocks_per_sec"]
-    assert bs["direction"] == "higher"
-    assert bs["status"] == "regressed"  # throughput fell 40%
-    assert set(report["regressions"]) == {"commit_ms", "blocks_per_sec"}
-
-    assert benchdiff.main(["--dir", str(tmp_path)]) == 2
-    assert benchdiff.main(["--dir", str(tmp_path), "--tolerance", "5"]) == 0
-    assert benchdiff.main(["--dir", str(tmp_path / "empty")]) == 1
-
-
-def test_benchdiff_reads_capture_shape_tail():
-    doc = {"n": 7, "cmd": "python bench.py --obs", "rc": 0,
-           "tail": 'warmup noise\n'
-                   '{"metric": "obs_overhead_pct", "value": 9.0, "unit": "%"}\n'
-                   '{"metric": "obs_overhead_pct", "value": 2.0, "unit": "%"}\n'}
-    rows = benchdiff._metric_rows(doc)
-    assert [r["value"] for r in rows] == [9.0, 2.0]
-    # later lines supersede: the round's value is the retried probe's
-    assert benchdiff.load_rounds.__doc__  # API stability breadcrumb
-    assert benchdiff.direction_of("obs_overhead_pct", "%") == "lower"
 
 
 # ---------------------------------------------------------------------------

@@ -1,8 +1,5 @@
-"""fast_host (the bench baseline + fast oracle) is bit-identical to refimpl.
-
-A silent regression here would corrupt bench_baseline.json and every
-vs_baseline number derived from it.
-"""
+"""fast_host (the fast host oracle other tests compare against) is
+bit-identical to refimpl."""
 
 import numpy as np
 import pytest

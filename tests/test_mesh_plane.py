@@ -431,27 +431,6 @@ def test_edscache_bytes_aware_eviction():
 
 
 # ---------------------------------------------------------------------------
-# streaming observability (satellite)
-# ---------------------------------------------------------------------------
-
-
-def test_streaming_counters_and_fetch_timer():
-    from celestia_app_tpu.parallel import streaming
-
-    k = 8
-    layouts = [streaming._synthetic_layout(k, i) for i in range(3)]
-    roots = streaming.stream_blocks(lambda i: layouts[i], 3, k)
-    assert len(roots) == 3
-    snap = telemetry.snapshot()
-    timers = snap.get("timers", {})
-    assert any(name.startswith("streaming.fetch") for name in timers), \
-        f"fetch wall-clock must ride the telemetry timers: {list(timers)}"
-    gauges = snap.get("gauges", {})
-    assert "streaming.blocks_in_flight" in gauges
-    assert gauges["streaming.blocks_in_flight"] == 0  # drained
-
-
-# ---------------------------------------------------------------------------
 # the big squares themselves (slow tier: minutes of GF(2^16) on CPU)
 # ---------------------------------------------------------------------------
 

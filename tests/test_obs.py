@@ -355,11 +355,15 @@ def test_span_totals_reach_the_counters_and_obey_the_gate(family):
             while time.perf_counter() < t_end:  # burn CPU: both clocks move
                 pass
     grown = level() - before
-    # three occurrences; 3 x 2 ms on either clock, in microseconds
+    # three occurrences; the loop ends by the wall clock, so 3 x 2 ms is
+    # the least the wall total can grow; what the CPU clock saw of it is
+    # the scheduler's to say under other workers
     if family == "obs.span_n":
         assert grown == 3
+    elif family == "obs.span_wall_us":
+        assert grown >= 6_000
     else:
-        assert 5_000 < grown < 500_000
+        assert grown > 0
     row = tt.read("spans")[-1]
     assert 0 < row["cpu_ms"] <= row["dur_ms"] + 1.0
     obs.set_enabled(False)

@@ -5,8 +5,8 @@ kernel makes XLA's CPU backend compile for minutes), so the kernel *body* is
 driven directly with mock Refs under jax.disable_jit() — that executes the
 exact arithmetic the TPU kernel runs (rolling 16-word schedule window,
 unrolled rounds, multi-block fori_loop) eagerly against numpy buffers. The
-pallas_call plumbing itself (BlockSpec layout) is exercised on real TPU by
-bench.py, which falls back to the jnp path if the kernel fails to compile.
+pallas_call plumbing itself (BlockSpec layout) is compiled for a v5e by
+tests/test_tpu_compile.py and runs on the chip in every benchmark cell.
 """
 
 import hashlib

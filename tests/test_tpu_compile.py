@@ -7,9 +7,9 @@ Interpret-mode Pallas tests cannot see what the chip's compiler refuses:
 a slice off the tiling, too much fast memory, an unsupported cast, a
 kernel it cannot partition across a mesh. These cases ask it directly, at
 the shapes chip_smoke.py runs: the k=64 / k=128 block pipelines and the
-prover's level stack with the Pallas SHA-256 kernel, the fused Pallas RS
-pass, the batched secp256k1 verifier, the namespace search, the blob
-commitment batch, and the four-chip sharded k=256 program.
+prover's level stack with the Pallas SHA-256 kernel, the batched
+secp256k1 verifier, the namespace search, the blob commitment batch, and
+the four-chip sharded k=256 program.
 
 Rules this file keeps (they are what lets it run under `pytest -n 6`):
 the topology is described ONLY inside the module fixture (one process at
@@ -93,13 +93,6 @@ def test_block_pipeline(one_chip, k):
     from celestia_app_tpu.da import eds
 
     _compile(eds.pipeline_fn(k), _u8((k, k, 512), one_chip))
-
-
-def test_rs_pallas_pass_k128(one_chip):
-    from celestia_app_tpu.ops import rs_pallas
-
-    _compile(rs_pallas.extend_square_fn(128, interpret=False),
-             _u8((128, 128, 512), one_chip))
 
 
 @pytest.mark.parametrize("k", [64, 128])
