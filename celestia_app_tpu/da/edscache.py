@@ -42,20 +42,33 @@ protocols; this module is that amortization:
   shard_map pipeline (parallel/mesh_engine.py — k rows split over the
   ``seq`` ICI axis, bit-identical to the single-device program), and the
   auto/device engines route any square of ``k >= CELESTIA_MESH_MIN_K``
-  (default 256) there automatically. Mesh-built entries are
-  ``DeviceEntry``: the EDS and (once warmed) the NMT level arrays stay
-  on device, and host bytes materialize lazily — only when a proof or
-  serve path actually needs them — each materialization counting
-  ``edscache.host_crossings``. The produce path's batched dispatch
+  (default 256) there automatically. The produce path's batched dispatch
   (chain/producer.py) inserts the same entry type, so an
   extend→commit→prover-warm chain hands device arrays, not bytes,
   between stages.
+
+- **One entry class per engine class.** Every device-class engine —
+  mesh, batched and the single-device program every k <= 128 square
+  runs — returns a ``DeviceEntry``: the EDS and (once warmed) the NMT
+  level arrays stay on device, only the commitment (4k axis roots + the
+  data root) crosses inside ``compute_entry``, the provers' level passes
+  read the resident array, and host bytes materialize lazily — only
+  when a proof or serve path actually needs them — each materialization
+  counting ``edscache.host_crossings``. The single-device engine also
+  STARTS the square's host copy right after the run
+  (``obs/xfer.HostFetch``): a served height needs those bytes, and
+  started there they land in the shadow of process → commit instead of
+  blocking ``prepare_proposal``. ``EdsCacheEntry`` is the host engine's
+  entry (and ``auto``'s counted fallback).
 
 Telemetry: ``da.extend_runs`` (every real pipeline dispatch),
 ``edscache.{hits,misses,evictions,seeded}``, ``edscache.warm_coalesced``
 (a pending warm superseded by a newer commit), ``edscache.warm_errors``,
 ``edscache.host_crossings`` (device-resident arrays materialized to
-host). Wire/metric formats in docs/FORMATS.md §14 and §18; design in
+host), ``edscache.eds_fetch_started`` / ``eds_fetch_waited`` /
+``eds_fetch_ready`` (a host copy of the square started by the
+single-device engine; its first read found it still moving and blocked
+/ found it landed). Wire/metric formats in docs/FORMATS.md §14 and §18; design in
 docs/DESIGN.md "The block plane" and "The mesh plane".
 """
 
@@ -94,6 +107,20 @@ DEFAULT_MAX_ENTRIES = int(os.environ.get("CELESTIA_EDSCACHE_ENTRIES", "4"))
 # behavior is unchanged.
 DEFAULT_MAX_BYTES = int(os.environ.get("CELESTIA_EDSCACHE_BYTES",
                                        str(1 << 30)))
+
+
+telemetry.set_help(
+    "edscache.eds_fetch_started",
+    "host copies of an extended square started right after its extend",
+)
+telemetry.set_help(
+    "edscache.eds_fetch_waited",
+    "first host reads of a square that found its copy still moving",
+)
+telemetry.set_help(
+    "edscache.eds_fetch_ready",
+    "first host reads of a square whose copy had already landed",
+)
 
 
 def entry_nbytes(entry) -> int:
@@ -188,23 +215,27 @@ class EdsCacheEntry:
                 )
             return self._prover
 
-    def _transposed_square(self):
-        """(eds_t, dah_t): the transposed square whose ROW trees are
-        this square's column trees — the leaf-namespace rule is
+    def _transposed_dah(self) -> DataAvailabilityHeader:
+        """The header of the transposed square, whose ROW trees are this
+        square's column trees — the leaf-namespace rule is
         transpose-invariant (parity iff outside Q0 survives
-        (r,c)->(c,r)), so a col-axis prover is a row prover over this
-        pair. The ONE copy of the construction both col-prover builds
-        (base and device-resident) share."""
-        # the HOST entry class: self.eds.squares is numpy here (the
-        # device-resident twin overrides the col-prover path wholesale)
-        eds_t = ExtendedDataSquare(
-            np.ascontiguousarray(np.swapaxes(self.eds.squares, 0, 1))  # lint: disable=xfer-reach
-        )
-        dah_t = DataAvailabilityHeader(
+        (r,c)->(c,r)), so a col-axis prover is a row prover over the
+        transposed pair. Shared by both col-prover builds (base and
+        device-resident)."""
+        return DataAvailabilityHeader(
             row_roots=self.dah.col_roots,
             col_roots=self.dah.row_roots,
         )
-        return eds_t, dah_t
+
+    def _transposed_square(self):
+        """(eds_t, dah_t) for a prover that runs its own level pass: it
+        uploads (or BLAS-reads) the transpose, so a C-order copy."""
+        # the HOST entry class: self.eds.squares is numpy here (the
+        # device-resident twin is handed its levels and takes a view)
+        eds_t = ExtendedDataSquare(
+            np.ascontiguousarray(np.swapaxes(self.eds.squares, 0, 1))  # lint: disable=xfer-reach
+        )
+        return eds_t, self._transposed_dah()
 
     def get_col_prover(self, engine: str = "auto"):
         """Column-axis prover (BEFP escalation serving): see
@@ -236,7 +267,8 @@ class EdsCacheEntry:
 
 
 class DeviceEntry(EdsCacheEntry):
-    """A mesh-plane entry whose big arrays live on device.
+    """The entry of every device-class engine: its big arrays live on
+    device.
 
     Construction hands over the device EDS (sharded over the mesh when
     the sharded pipeline built it) plus the HOST commitments — axis
@@ -244,13 +276,21 @@ class DeviceEntry(EdsCacheEntry):
     4k x 90 B they are not worth keeping remote. Everything else obeys
     the device-residency contract:
 
-    - ``warm()`` runs the row+col NMT *level* passes on device and keeps
-      the results there — the prover-warm stage of a batched produce
-      chain never touches the host.
+    - ``warm()`` runs the row+col NMT *level* passes on device, over
+      the resident array (``jnp.swapaxes`` on the chip for the column
+      orientation), and keeps the results there — the prover-warm stage
+      never sends the square up again.
     - ``.eds`` / the provers materialize host bytes lazily, only when a
       proof or serve path actually needs them; every device->host array
-      fetch counts ``edscache.host_crossings`` (the --mesh bench pins
-      this at 0/block on the warmed produce path).
+      fetch counts ``edscache.host_crossings``.
+    - The single-device engine hands over ``eds_fetch``, the host copy
+      of the square it STARTED right after the run (obs/xfer
+      ``HostFetch``): ``.eds`` then waits for what is left of that
+      copy — nothing, once process -> commit have run in its shadow —
+      and counts which it was (``edscache.eds_fetch_waited`` /
+      ``eds_fetch_ready``). The mesh and batched engines start none
+      (a produce chain nobody samples never crosses at all) and fetch
+      on demand.
 
     Locking mirrors the base class's per-prover discipline: ONE lock
     per lazily-built resource (host EDS, row levels, col levels), so a
@@ -262,16 +302,28 @@ class DeviceEntry(EdsCacheEntry):
     possible."""
 
     def __init__(self, eds_dev, dah: DataAvailabilityHeader,
-                 data_root: bytes):
+                 data_root: bytes, eds_fetch=None):
         super().__init__(None, dah, data_root)
         self._eds_dev = eds_dev  # device (2k, 2k, 512), possibly sharded
         # _eds (inherited) is the lazily-materialized host square;
         # device-side NMT level stacks, row and col orientation
         self._eds_lock = threading.Lock()
+        self._eds_fetch = eds_fetch  # guarded-by: _eds_lock
         self._levels_lock = threading.Lock()
         self._col_levels_lock = threading.Lock()
         self._levels_dev = None  # guarded-by: _levels_lock
         self._col_levels_dev = None  # guarded-by: _col_levels_lock
+
+    @classmethod
+    def from_commitments(cls, eds_dev, rows, cols, root, eds_fetch=None):
+        """The entry over a device EDS and its HOST commitment arrays
+        ((2k, 90) row and column roots, the 32-byte data root) — the one
+        construction every device-class engine ends in."""
+        dah = DataAvailabilityHeader(
+            row_roots=tuple(bytes(r) for r in rows),
+            col_roots=tuple(bytes(c) for c in cols),
+        )
+        return cls(eds_dev, dah, bytes(root), eds_fetch=eds_fetch)
 
     @property
     def k(self) -> int:
@@ -294,13 +346,19 @@ class DeviceEntry(EdsCacheEntry):
     @property
     def eds(self) -> ExtendedDataSquare:
         """Host square bytes, materialized on first need (one counted
-        crossing; later reads are free)."""
+        crossing; later reads are free): the started copy's result
+        where the engine started one, a blocking fetch otherwise."""
         with self._eds_lock:
             if self._eds is None:
                 t0 = telemetry.start_timer()
-                self._eds = ExtendedDataSquare(
-                    xfer.to_host(self._eds_dev, "edscache.eds")
-                )
+                fetch, self._eds_fetch = self._eds_fetch, None
+                if fetch is not None:
+                    telemetry.incr("edscache.eds_fetch_ready" if fetch.ready()
+                                   else "edscache.eds_fetch_waited")
+                    host = fetch.result()
+                else:
+                    host = xfer.to_host(self._eds_dev, "edscache.eds")
+                self._eds = ExtendedDataSquare(host)
                 self._crossing("eds")
                 telemetry.measure_since("edscache.host_fetch", t0)
             return self._eds
@@ -315,37 +373,42 @@ class DeviceEntry(EdsCacheEntry):
         return self._device_col_levels() if col else \
             self._device_row_levels()
 
-    def _device_row_levels(self):
+    def _run_levels(self, eds_dev):
+        """One level pass over a resident (2k, 2k, 512) array, priced
+        like the extend: dispatch -> every level ready."""
+        import jax
+
         from celestia_app_tpu.da import proof_device
 
+        with obs.span("proof.levels.run", k=self.k):
+            return jax.block_until_ready(
+                proof_device._jitted_row_levels(self.k)(eds_dev))
+
+    def _device_row_levels(self):
         # build-once serialization (see _device_levels)
         with self._levels_lock:  # lint: disable=blocking-under-lock
             if self._levels_dev is None:
-                self._levels_dev = proof_device._jitted_row_levels(
-                    self.k)(self._eds_dev)
+                self._levels_dev = self._run_levels(self._eds_dev)
             return self._levels_dev
 
     def _device_col_levels(self):
         import jax.numpy as jnp
 
-        from celestia_app_tpu.da import proof_device
-
         # build-once serialization (see _device_levels)
         with self._col_levels_lock:  # lint: disable=blocking-under-lock
             if self._col_levels_dev is None:
-                arr = jnp.swapaxes(jnp.asarray(self._eds_dev), 0, 1)
-                self._col_levels_dev = proof_device._jitted_row_levels(
-                    self.k)(arr)
+                self._col_levels_dev = self._run_levels(
+                    jnp.swapaxes(jnp.asarray(self._eds_dev), 0, 1))
             return self._col_levels_dev
 
     def _host_levels(self, col: bool):
         """Materialized level arrays for a prover build (one counted
-        crossing per orientation)."""
+        crossing per orientation; the ledger site is the prover's,
+        whichever orientation and engine)."""
         levels = self._device_levels(col)
         t0 = telemetry.start_timer()
-        site = "edscache.col_levels" if col else "edscache.levels"
         out = [tuple(triple)
-               for triple in xfer.to_host(list(levels), site)]
+               for triple in xfer.to_host(list(levels), "proof.row_levels")]
         self._crossing("col_levels" if col else "levels")
         telemetry.measure_since("edscache.host_fetch", t0)
         return out
@@ -372,10 +435,11 @@ class DeviceEntry(EdsCacheEntry):
             if self._prover is None:
                 from celestia_app_tpu.da import proof_device
 
+                # levels first: whatever is left of a started copy of
+                # the square lands behind the level pass and its fetch
+                levels = self._host_levels(col=False)
                 self._prover = proof_device.BlockProver(
-                    self.eds, self.dah,
-                    levels=self._host_levels(col=False),
-                )
+                    self.eds, self.dah, levels=levels)
             return self._prover
 
     def get_col_prover(self, engine: str = "auto"):
@@ -383,10 +447,14 @@ class DeviceEntry(EdsCacheEntry):
             if self._col_prover is None:
                 from celestia_app_tpu.da import proof_device
 
-                eds_t, dah_t = self._transposed_square()
+                # the levels come from the chip, so nothing uploads the
+                # transpose and a proof reads one cell of it at a time:
+                # a view, not a 32 MiB copy at k=128
+                levels = self._host_levels(col=True)
+                eds_t = ExtendedDataSquare(
+                    np.swapaxes(self.eds.squares, 0, 1))
                 self._col_prover = proof_device.BlockProver(
-                    eds_t, dah_t, levels=self._host_levels(col=True)
-                )
+                    eds_t, self._transposed_dah(), levels=levels)
             return self._col_prover
 
 
@@ -447,22 +515,22 @@ def compute_entry(ods: np.ndarray, engine: str = "auto",
 
             ods_dev = xfer.to_device(ods, "edscache.compute_entry")
             # THE blocking device timer of the block path: dispatch ->
-            # all four outputs ready. to_host below waited for the same
-            # result before this span existed, so nothing that
-            # overlapped is serialised; it now times the download alone.
+            # all four outputs ready.
             with obs.span("da.extend.run", k=int(ods.shape[0])):
-                outs = jax.block_until_ready(
+                eds_dev, rows, cols, root = jax.block_until_ready(
                     eds_mod.jitted_pipeline(ods.shape[0])(ods_dev))
-            eds_h, rows_h, cols_h, root_h = xfer.to_host(
-                outs, "edscache.compute_entry"
+            # only the commitment crosses here (4k x 90 B + 32 B: all a
+            # proposal compares); the square stays on the chip, and its
+            # host copy is started, not waited for — the first proof or
+            # serve path that needs bytes (DeviceEntry.eds) waits for
+            # what is left of it
+            fetch = xfer.HostFetch(eds_dev, "edscache.compute_entry")
+            telemetry.incr("edscache.eds_fetch_started")
+            rows_h, cols_h, root_h = xfer.to_host(
+                (rows, cols, root), "edscache.compute_entry"
             )
-            dah = DataAvailabilityHeader(
-                row_roots=tuple(bytes(r) for r in rows_h),
-                col_roots=tuple(bytes(c) for c in cols_h),
-            )
-            return EdsCacheEntry(
-                ExtendedDataSquare(eds_h), dah, bytes(root_h),
-            )
+            return DeviceEntry.from_commitments(
+                eds_dev, rows_h, cols_h, root_h, eds_fetch=fetch)
         except Exception:
             if engine in ("device", "mesh"):
                 raise

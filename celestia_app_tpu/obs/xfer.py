@@ -44,6 +44,13 @@ memory traffic first, then optimize:
   the upload and the download of one site beside the spans around
   them, and a profiler trace shows them on the device's clock.
 
+- **Copies started ahead of their need.** `HostFetch(value, site)`
+  begins a device→host copy on a thread of its own; whoever needs the
+  bytes calls `result()`. The bytes are counted once, when they land;
+  the site's d2h span times what the reader waited, and the copy's own
+  length as its thread saw it is the `xfer.fetch:<site>` span (da/edscache.py: the extended square follows
+  the roots down in the shadow of process → commit).
+
 Counting is always-on (two dict writes under the registry lock — the
 same cost class as `edscache.host_crossings`); the ledger ROWS, the
 span totals and the annotation follow the CELESTIA_OBS gate (its cost
@@ -154,9 +161,19 @@ def _abandon(opened) -> None:
         spans.close(as_span)
 
 
-def _account(direction: str, site: str, nbytes: int, opened) -> None:
-    """Attribute one transfer to `site`: counters + latency histogram +
-    the span totals and (when a span is active) one ledger row in the
+def _count(direction: str, site: str, nbytes: int) -> None:
+    """The counters' half of one transfer: its bytes and its call, to
+    `site` and to the process-wide totals."""
+    telemetry.incr(f"xfer.{direction}_bytes", nbytes, labels={"site": site})
+    telemetry.incr(f"xfer.{direction}_calls", labels={"site": site})
+    with _totals_lock:
+        _totals[f"{direction}_bytes"] += nbytes
+        _totals[f"{direction}_calls"] += 1
+
+
+def _note(direction: str, site: str, nbytes: int, opened) -> None:
+    """The clocks' half: close the transfer's span, sample the latency
+    histogram and (when a span is active) write one ledger row in the
     span's trace sink."""
     t0, name, as_span = opened
     if as_span is not None:
@@ -164,11 +181,6 @@ def _account(direction: str, site: str, nbytes: int, opened) -> None:
     dur_s = telemetry.measure_since(
         f"xfer.{direction}", t0, labels={"site": site}
     )
-    telemetry.incr(f"xfer.{direction}_bytes", nbytes, labels={"site": site})
-    telemetry.incr(f"xfer.{direction}_calls", labels={"site": site})
-    with _totals_lock:
-        _totals[f"{direction}_bytes"] += nbytes
-        _totals[f"{direction}_calls"] += 1
     ctx = spans.capture() if as_span is not None else None
     if ctx is None:
         return
@@ -208,7 +220,9 @@ def to_device(value, site: str, *, placement=None):
     except BaseException:
         _abandon(opened)
         raise
-    _account("h2d", site, nbytes_of(value), opened)
+    nbytes = nbytes_of(value)
+    _count("h2d", site, nbytes)
+    _note("h2d", site, nbytes, opened)
     return out
 
 
@@ -224,8 +238,72 @@ def to_host(value, site: str):
     except BaseException:
         _abandon(opened)
         raise
-    _account("d2h", site, nbytes_of(out), opened)
+    nbytes = nbytes_of(out)
+    _count("d2h", site, nbytes)
+    _note("d2h", site, nbytes, opened)
     return out
+
+
+class HostFetch:
+    """A device→host copy that was started and is waited for only at its
+    first need: the copy runs on a thread of its own —
+    ``jax.device_get`` gives the GIL away for as long as the bytes move —
+    while the thread that started it goes on with host work.
+
+    The ledger sees both halves. The bytes (and the call) are counted
+    once, when they land, wherever the starter is by then; `result()`
+    is the site's ``xfer.d2h:<site>`` span and latency sample, and
+    times what blocked its caller — about nothing once the copy has
+    landed — never the copy's own length. That length is the thread's
+    own span, ``xfer.fetch:<site>``: start → landed as this thread saw
+    it, so the wait behind other copies and the wait to get the
+    interpreter back are in it; its total over a window divided by the
+    window is the number of copies in flight."""
+
+    def __init__(self, value, site: str):
+        self.site = site
+        self._done = threading.Event()
+        self._out = None
+        self._error: Exception | None = None
+        threading.Thread(target=self._run, args=(value,), daemon=True,
+                         name="xfer-fetch").start()
+
+    def _run(self, value) -> None:
+        import jax
+
+        name = f"xfer.fetch:{self.site}"
+        as_span = spans.begin(name) if spans.enabled() else None
+        try:
+            with _explicit():
+                self._out = jax.device_get(value)  # xfer: ledger
+        except Exception as e:
+            # handed to result(), which raises it; counted here, because
+            # a copy nobody reads would otherwise fail in silence
+            telemetry.incr("obs.xfer_fetch_errors")
+            self._error = e
+            if as_span is not None:
+                spans.close(as_span)
+        else:
+            if as_span is not None:
+                spans.end(name, as_span)
+            _count("d2h", self.site, nbytes_of(self._out))
+        finally:
+            self._done.set()
+
+    def ready(self) -> bool:
+        """True once the copy has landed (or failed): `result()` will
+        not block."""
+        return self._done.is_set()
+
+    def result(self):
+        """The host value; blocks until the copy has landed."""
+        opened = _open("d2h", self.site)
+        self._done.wait()
+        if self._error is not None:
+            _abandon(opened)
+            raise self._error
+        _note("d2h", self.site, nbytes_of(self._out), opened)
+        return self._out
 
 
 def ensure_host(value, site: str):

@@ -265,14 +265,10 @@ def compute_entries_batched(ods_batch: np.ndarray,
 
 def _device_entry(eds_dev, rows, cols, root, fetched: bool = False):
     from celestia_app_tpu.da import edscache as edscache_mod
-    from celestia_app_tpu.da.dah import DataAvailabilityHeader
 
     if not fetched:
         rows, cols, root = xfer.to_host(
             (rows, cols, root), "mesh.entry_commitments"
         )
-    dah = DataAvailabilityHeader(
-        row_roots=tuple(bytes(r) for r in rows),
-        col_roots=tuple(bytes(c) for c in cols),
-    )
-    return edscache_mod.DeviceEntry(eds_dev, dah, bytes(root))
+    return edscache_mod.DeviceEntry.from_commitments(
+        eds_dev, rows, cols, root)

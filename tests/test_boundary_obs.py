@@ -71,17 +71,19 @@ def test_ledger_counts_match_host_crossings():
     assert isinstance(entry, edscache.DeviceEntry)
 
     before_cross = _counter("edscache.host_crossings")
-    before = {site: _counter("xfer.d2h_calls", site=site)
-              for site in ("edscache.eds", "edscache.levels",
-                           "edscache.col_levels")}
+    # the host square is the entry's own site; both level stacks come
+    # down at the prover's (one name whichever engine built the entry)
+    sites = {"edscache.eds": 1, "proof.row_levels": 2}
+    before = {site: _counter("xfer.d2h_calls", site=site) for site in sites}
     bytes_before = xfer.totals()["d2h_bytes"]
 
     _ = entry.eds                       # host square
     entry.get_prover("auto")            # row levels -> host
     entry.get_col_prover("auto")        # col levels -> host
 
-    for site in before:
-        assert _counter("xfer.d2h_calls", site=site) - before[site] == 1, site
+    for site, calls in sites.items():
+        assert _counter("xfer.d2h_calls", site=site) - before[site] == calls, \
+            site
     assert _counter("edscache.host_crossings") - before_cross == 3
     assert xfer.totals()["d2h_bytes"] > bytes_before
 
@@ -431,3 +433,56 @@ def test_block_prover_device_levels_cross_counted():
     assert after["d2h_calls"] == before["d2h_calls"] + 1
     assert all(isinstance(arr, np.ndarray)
                for level in prover.levels for arr in level)
+
+
+def test_started_copy_counts_at_landing_and_times_the_wait():
+    """`HostFetch`: bytes and the call are counted when the copy lands,
+    on its own thread, whose `xfer.fetch:<site>` span is the copy's
+    length; `result()` is the reader's half — the site's d2h span and
+    latency sample, for what the reader waited."""
+    import jax.numpy as jnp
+
+    site = "test.started_copy"
+    value = jnp.arange(4096, dtype=jnp.uint8)
+    b0, c0 = _counter("xfer.d2h_bytes", site=site), \
+        _counter("xfer.d2h_calls", site=site)
+    span_n = f'obs.span_n{{name="xfer.d2h:{site}"}}'
+    fetch_n = f'obs.span_n{{name="xfer.fetch:{site}"}}'
+    n0, f0 = _counter(span_n), _counter(fetch_n)
+    total0 = xfer.totals()["d2h_bytes"]
+    fetch = xfer.HostFetch(value, site)
+    deadline = time.monotonic() + 30
+    while not fetch.ready():
+        assert time.monotonic() < deadline
+        time.sleep(0.002)
+    assert _counter("xfer.d2h_bytes", site=site) - b0 == 4096
+    assert _counter("xfer.d2h_calls", site=site) - c0 == 1
+    assert xfer.totals()["d2h_bytes"] - total0 >= 4096
+    with no_implicit_transfers():      # the read is a ledger read
+        out = fetch.result()
+    assert isinstance(out, np.ndarray) and out.tobytes() == bytes(
+        range(256)) * 16
+    assert _counter("xfer.d2h_bytes", site=site) - b0 == 4096   # once
+    assert _counter("xfer.d2h_calls", site=site) - c0 == 1
+    if obs.spans.enabled():
+        assert _counter(span_n) - n0 == 1
+        assert _counter(fetch_n) - f0 == 1
+
+
+def test_started_copy_failure_is_counted_and_raised_in_its_reader():
+    e0 = _counter("obs.xfer_fetch_errors")
+    b0 = xfer.totals()["d2h_bytes"]
+
+    class NotAnArray:
+        def __array__(self, *a, **k):
+            raise RuntimeError("device lost")
+
+    fetch = xfer.HostFetch([NotAnArray()], "test.started_copy_fails")
+    deadline = time.monotonic() + 30
+    while not fetch.ready():
+        assert time.monotonic() < deadline
+        time.sleep(0.002)
+    assert _counter("obs.xfer_fetch_errors") - e0 == 1
+    with pytest.raises(RuntimeError, match="device lost"):
+        fetch.result()
+    assert xfer.totals()["d2h_bytes"] == b0

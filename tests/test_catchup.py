@@ -474,6 +474,9 @@ NEW_METRICS = ["miss_build_ms", "miss_lock_wait_ms", "miss_load_ms",
                "evictions_per_request"]
 
 
+REUPLOAD = "xfer.h2d:proof.row_levels"
+
+
 @pytest.mark.parametrize("metric", NEW_METRICS)
 def test_benchmark_metric_reads_a_name_the_sweep_produced(sweep, metric):
     with open(os.path.join(REPO, "benchmark", "metrics", f"{metric}.json"),
@@ -491,6 +494,11 @@ def test_benchmark_metric_reads_a_name_the_sweep_produced(sweep, metric):
     assert spec["reducer"] == "span_total" and "per_unit" not in spec
     assert entry["source"] == "program_span"
     for span in spec["spans"] + spec.get("minus", []):
+        if span == REUPLOAD:
+            # the resident entry's level pass reads the array where it
+            # lies (ISSUE 32): of a miss's four transfers three are left
+            assert delta.get(f'obs.span_n{{name="{span}"}}', 0) == 0
+            continue
         assert delta[f'obs.span_n{{name="{span}"}}'] > 0, span
     reducer = _load("catchup_span_total", "reducers", "span_total.py")
 

@@ -349,3 +349,241 @@ def test_validator_service_serves_seeded_das_samples(tmp_path):
         except Exception:
             pass
         vnode.app.close()
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 32: the single-device engine's entry is resident — only the roots
+# cross inside compute_entry, the host copy follows, the prover's level
+# passes read the resident array
+# ---------------------------------------------------------------------------
+
+
+def _site(name: str, site: str) -> int:
+    return _c(f'{name}{{site="{site}"}}')
+
+
+def _quadrant_cells(k: int) -> list[tuple[int, int]]:
+    """Two cells of each quadrant of the 2k x 2k square (corners and an
+    inner one), Q0..Q3."""
+    lo, hi = (0, k - 1), (k, 2 * k - 1)
+    return [(r, c) for rows in (lo, hi) for cols in (lo, hi)
+            for r, c in ((rows[0], cols[0]), (rows[1], cols[1]))]
+
+
+@pytest.mark.backend
+@pytest.mark.parametrize("k", [2, 8, 32])
+def test_device_engine_entry_is_resident_and_byte_identical(k):
+    ods = _ods(k=k, seed=30 + k)
+    host = edscache.compute_entry(ods, "host")
+    dev = edscache.compute_entry(ods, "device")
+    assert isinstance(dev, edscache.DeviceEntry)
+    assert not isinstance(host, edscache.DeviceEntry)
+    assert len(dev.dah.row_roots) == len(dev.dah.col_roots) == 2 * k
+    _entries_equal(host, dev)
+    row_h, row_d = host.get_prover("host"), dev.get_prover("device")
+    col_h, col_d = host.get_col_prover("host"), dev.get_col_prover("device")
+    for r, c in _quadrant_cells(k):
+        for ph, pd, cell in ((row_h, row_d, (r, c)), (col_h, col_d, (c, r))):
+            sh, prh = ph.prove_cell(*cell)
+            sd, prd = pd.prove_cell(*cell)
+            assert sh == sd == host.eds.squares[r, c].tobytes()
+            assert prh.nodes == prd.nodes
+            assert (prh.start, prh.end, prh.total) == \
+                (prd.start, prd.end, prd.total)
+    # the column prover reads the host square through a transposed view
+    assert np.shares_memory(col_d.eds.squares, dev.eds.squares)
+
+
+class _FetchOnDemand:
+    """`xfer.HostFetch` with the start taken out: the copy happens
+    at `result()`, so a test can say exactly what has crossed when."""
+
+    def __init__(self, value, site):
+        self._value, self.site = value, site
+
+    def ready(self) -> bool:
+        return False
+
+    def result(self):
+        from celestia_app_tpu.obs import xfer
+
+        return xfer.to_host(self._value, self.site)
+
+
+@pytest.mark.backend
+@pytest.mark.parametrize("k", [2, 8])
+def test_only_the_roots_cross_inside_compute_entry(k, monkeypatch):
+    from celestia_app_tpu.obs import xfer
+
+    monkeypatch.setattr(xfer, "HostFetch", _FetchOnDemand)
+    site = "edscache.compute_entry"
+    ods = _ods(k=k, seed=40 + k)
+    d0, u0 = _site("xfer.d2h_bytes", site), _site("xfer.h2d_bytes", site)
+    entry = edscache.compute_entry(ods, "device")
+    assert _site("xfer.h2d_bytes", site) - u0 == ods.nbytes
+    assert _site("xfer.d2h_bytes", site) - d0 == 4 * k * 90 + 32
+    assert entry.residency() == "device"
+    # both level passes and the commitments: still nothing more
+    entry.warm()
+    assert len(entry.data_root) == 32
+    assert _site("xfer.d2h_bytes", site) - d0 == 4 * k * 90 + 32
+    # the first host read brings the square down, once
+    square = (2 * k) ** 2 * 512
+    assert entry.eds.squares.nbytes == square
+    assert _site("xfer.d2h_bytes", site) - d0 == 4 * k * 90 + 32 + square
+    assert entry.residency() == "device+host"
+    entry.get_prover("device")
+    entry.get_col_prover("device")
+    _ = entry.eds
+    assert _site("xfer.d2h_bytes", site) - d0 == 4 * k * 90 + 32 + square
+
+
+@pytest.mark.backend
+def test_started_copy_lands_and_is_counted_once():
+    """The real helper: the copy runs on its own thread, its bytes are
+    counted when they land whether or not anyone reads them, and the
+    first host read counts ready or waited — never both, never twice."""
+    from celestia_app_tpu.obs import xfer
+
+    k, site = 8, "edscache.compute_entry"
+    names = ("edscache.eds_fetch_started", "edscache.eds_fetch_waited",
+             "edscache.eds_fetch_ready")
+    c0 = [_c(n) for n in names]
+    d0, n0 = _site("xfer.d2h_bytes", site), _site("xfer.d2h_calls", site)
+    entries = [edscache.compute_entry(_ods(k=k, seed=50 + i), "device")
+               for i in range(3)]
+    fetches = [e._eds_fetch for e in entries]
+    assert all(isinstance(f, xfer.HostFetch) for f in fetches)
+    deadline = time.monotonic() + 30
+    while not all(f.ready() for f in fetches):
+        assert time.monotonic() < deadline
+        time.sleep(0.005)
+    per_entry = 4 * k * 90 + 32 + (2 * k) ** 2 * 512
+    assert _site("xfer.d2h_bytes", site) - d0 == 3 * per_entry
+    assert _site("xfer.d2h_calls", site) - n0 == 6  # roots + square, each
+    for e in entries[:2]:       # the third is never read
+        _ = e.eds
+        _ = e.eds
+    started, waited, ready = (_c(n) - c for n, c in zip(names, c0))
+    assert (started, waited, ready) == (3, 0, 2)
+    assert _site("xfer.d2h_bytes", site) - d0 == 3 * per_entry
+    assert entries[0]._eds_fetch is None
+
+
+@pytest.mark.backend
+def test_a_read_before_the_copy_lands_counts_waited(monkeypatch):
+    from celestia_app_tpu.obs import xfer
+
+    gate = threading.Event()
+    real_get = xfer.HostFetch._run
+
+    def held(self, value):
+        gate.wait(30)
+        real_get(self, value)
+
+    monkeypatch.setattr(xfer.HostFetch, "_run", held)
+    w0, r0 = _c("edscache.eds_fetch_waited"), _c("edscache.eds_fetch_ready")
+    host = edscache.compute_entry(_ods(k=4, seed=60), "host")
+    entry = edscache.compute_entry(_ods(k=4, seed=60), "device")
+    assert not entry._eds_fetch.ready()
+    got = []
+    reader = threading.Thread(target=lambda: got.append(entry.eds))
+    reader.start()
+    # the reader counts itself as waiting before it blocks; only then
+    # is the copy let go
+    deadline = time.monotonic() + 30
+    while _c("edscache.eds_fetch_waited") - w0 < 1:
+        assert time.monotonic() < deadline
+        time.sleep(0.002)
+    assert not got
+    gate.set()
+    reader.join(timeout=30)
+    assert not reader.is_alive()
+    assert np.array_equal(got[0].squares, host.eds.squares)
+    assert _c("edscache.eds_fetch_waited") - w0 == 1
+    assert _c("edscache.eds_fetch_ready") - r0 == 0
+
+
+@pytest.mark.backend
+def test_prover_on_a_resident_entry_uploads_nothing():
+    k, site = 8, "proof.row_levels"
+    entry = edscache.compute_entry(_ods(k=k, seed=70), "device")
+    u0, d0 = _site("xfer.h2d_bytes", site), _site("xfer.d2h_bytes", site)
+    up0 = _c("xfer.h2d_calls")
+    entry.warm()
+    row = entry.get_prover("device")
+    col = entry.get_col_prover("device")
+    assert _site("xfer.h2d_bytes", site) - u0 == 0
+    # each orientation's level stack came down at the prover's site, once
+    stack = sum(a.nbytes for lvl in row.levels for a in lvl)
+    assert stack == sum(a.nbytes for lvl in col.levels for a in lvl)
+    assert _site("xfer.d2h_bytes", site) - d0 == 2 * stack
+    share, proof = row.prove_cell(2 * k - 1, 3)
+    assert share == entry.eds.squares[2 * k - 1, 3].tobytes()
+
+
+@pytest.mark.backend
+def test_warmed_chain_answers_the_sample_from_the_built_prover(tmp_path):
+    """produce -> commit -> warm -> sample on the jitted engine: ONE
+    extend a block, the level pass of each orientation run once (by the
+    warmer, on the resident array) and the light round answered by the
+    prover built over it — no rebuild, no second level pass."""
+    app, signer, addrs = _app(tmp_path, engine="auto")
+    node = Node(app)
+    core = node.attach_das_core(SampleCore(app))
+    span_n = 'obs.span_n{name="proof.levels.run"}'
+    try:
+        t = 1_700_000_001.0
+        for height in (1, 2):
+            for raw in _txs(signer, addrs):
+                assert node.broadcast_tx(raw).code == 0
+            c0, l0 = _c("da.extend_runs"), _c(span_n)
+            b0, s0 = _c("das.square_builds"), _c("edscache.eds_fetch_started")
+            node.produce_block(t=t)
+            t += 1.0
+            assert app.da_warmer.wait_idle(30)
+            entry = core._cache[height].cache_entry
+            assert isinstance(entry, edscache.DeviceEntry)
+            assert entry.warmed()
+            levels = entry._levels_dev
+            out = core.sample_many(height, [(0, 0), (1, 1)])
+            assert len(out["samples"]) == 2
+            assert entry.get_prover("auto") is core._cache[height].prover
+            assert entry._levels_dev is levels
+            assert _c("da.extend_runs") - c0 == 1
+            assert _c("edscache.eds_fetch_started") - s0 == 1
+            assert _c("das.square_builds") - b0 == 0
+            if _c(span_n):  # span totals follow the CELESTIA_OBS gate
+                assert _c(span_n) - l0 == 2
+    finally:
+        app.close()
+
+
+@pytest.mark.backend
+def test_evicted_entry_frees_its_device_arrays_by_refcount():
+    """No cycle may hold `_eds_dev`: an entry the LRU drops gives its
+    device arrays back at once, not at the next full collection."""
+    import gc
+    import weakref
+
+    cache = edscache.EdsCache(max_entries=1)
+    first = cache.get_or_compute(_ods(k=4, seed=80), "device")
+    first.warm()
+    first.get_prover("device")
+    first.get_col_prover("device")
+    refs = [weakref.ref(first._eds_dev), weakref.ref(first._levels_dev[0][0]),
+            weakref.ref(first)]
+    gc.disable()
+    try:
+        ev0 = _c("edscache.evictions")
+        del first
+        cache.get_or_compute(_ods(k=4, seed=81), "device")
+        assert _c("edscache.evictions") - ev0 == 1
+        # the copy's thread lets go of the array as it ends
+        deadline = time.monotonic() + 30
+        while any(r() is not None for r in refs):
+            assert time.monotonic() < deadline, \
+                [type(r()).__name__ for r in refs if r() is not None]
+            time.sleep(0.005)
+    finally:
+        gc.enable()

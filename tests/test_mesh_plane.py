@@ -73,13 +73,17 @@ def test_mesh_entry_bit_identical_to_host(k):
 
 def test_mesh_engine_via_auto_routing(monkeypatch):
     """Under engine="auto", squares at/above CELESTIA_MESH_MIN_K route
-    through the mesh and come back device-resident; below it they take
-    the classic single-device path."""
+    through the mesh; below it they take the single-device program.
+    Both come back device-resident (one entry class for every
+    device-class engine): the distinction is where the square lives —
+    spread over the mesh, or on one device."""
     monkeypatch.setenv("CELESTIA_MESH_MIN_K", "16")
     big = edscache.compute_entry(_random_ods(16, 7), "auto")
     small = edscache.compute_entry(_random_ods(8, 7), "auto")
     assert isinstance(big, edscache.DeviceEntry)
-    assert not isinstance(small, edscache.DeviceEntry)
+    assert isinstance(small, edscache.DeviceEntry)
+    assert len(big._eds_dev.sharding.device_set) > 1
+    assert len(small._eds_dev.sharding.device_set) == 1
 
 
 def test_mesh_engine_unshardable_square_degrades():
