@@ -43,6 +43,8 @@ Telemetry (the counters the tier-1 no-re-verification test pins):
   admission.sig_scalar_verified  ante ran a scalar verify (cache miss)
   admission.batch_dispatches     device batch dispatches
   admission.batch_lanes          signatures sent through the device path
+  admission.batch_padded_lanes   lanes the device ran, padding included
+                                 (each dispatch adds its bucket)
   admission.batch_verified       lanes that verified and were cached
   admission.batch_rejected       lanes that failed batch verification
   admission.prevalidate_below_batch  batches too small for the device
@@ -465,7 +467,11 @@ def _prevalidate(app, raws, check_state: bool, commitments: bool) -> int:
         telemetry.incr("admission.prevalidate_below_batch")
         return 0
     try:
-        mask = fast.verify_batch(items)
+        # children (ops/secp256k1.verify_batch): admission.sig_prep, the
+        # host's per-lane Python, and admission.sig_dispatch, the device
+        with obs.span("admission.signatures", n_sigs=len(items),
+                      lanes=fast.padded_lanes(len(items))):
+            mask = fast.verify_batch(items)
     except Exception as e:
         # the scalar path in the ante stays authoritative; count + log
         telemetry.incr("admission.prevalidate_errors")

@@ -401,6 +401,9 @@ class App:
         # baseapp checkState: a cache branch over committed state that
         # accumulates CheckTx effects; reset at every commit
         self._check_state = None
+        # (height, bound) of the block being finalized: the square-size
+        # bound its layout used, recorded with the block at commit
+        self._layout_bound: tuple[int, int] | None = None
         # bumped on every rollback/resume so read-side caches (QueryRouter)
         # can invalidate without re-reading disk
         self.state_generation = 0
@@ -884,6 +887,12 @@ class App:
     def _finalize_inner(self, block: Block) -> list[TxResult]:
         h = block.header
         ctx = self._deliver_ctx(InfiniteGasMeter(), height=h.height, t=h.time_unix)
+        # the bound this block's square was laid out under: Prepare and
+        # Process read it from this same state, before any of the block's
+        # changes (a param change executes in this block's EndBlock and
+        # governs the NEXT layout). Commit records it with the block; a
+        # read lays the height out under it again (query.rebuild_square).
+        self._layout_bound = (h.height, self.max_effective_square_size(ctx))
 
         # BeginBlock via the versioned module manager (mint first, then
         # distribution, then slashing liveness — app/modules.go order);
@@ -1189,7 +1198,8 @@ class App:
         if self.db is not None:
             # durable commit: state + block hit disk atomically before the
             # commit is acknowledged (a killed process resumes here)
-            self.db.save_block(block)  # block first: LATEST implies block exists
+            # block first: LATEST implies block exists
+            self.db.save_block(block, self._layout_bound_of(block))
             self.db.save_commit(self.height, self.store, meta)
         else:
             self.store.drain_changes()  # keep the change log bounded
@@ -1249,6 +1259,14 @@ class App:
                 blob_pack_store=self.blob_pack_store,
             )
         return self.last_app_hash
+
+    def _layout_bound_of(self, block: Block) -> int | None:
+        """What finalize_block noted for this block; None (the record then
+        carries no bound) only for a commit that no finalize preceded."""
+        noted = self._layout_bound
+        if noted is not None and noted[0] == block.header.height:
+            return noted[1]
+        return None
 
     def _commit_meta(self) -> dict:
         """The identity document persisted beside every durable commit."""

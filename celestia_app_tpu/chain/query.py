@@ -38,7 +38,15 @@ def rebuild_square(app, height: int):
     # load + parse + lay out again: what a read of a height nobody
     # handed over pays before it can look anything up
     with obs.span("query.rebuild_square", height=height):
-        block = app.db.load_block(height)
+        block, bound = app.db.load_block_and_bound(height)
+        if bound is None:
+            # a block stored before the bound was recorded with it: the
+            # chain's bound as it stands now, which is the proposer's
+            # unless governance moved gov_max_square_size since
+            telemetry.incr("query.layout_bound_fallbacks")
+            bound = app.max_effective_square_size(Context(
+                app.store, InfiniteGasMeter(), app.height, 0,
+                app.chain_id, app.app_version))
         normal, pfbs = [], []
         for raw in block.txs:
             if is_blob_tx(raw):
@@ -48,8 +56,10 @@ def rebuild_square(app, height: int):
                 normal.append(raw)
         threshold = appconsts.subtree_root_threshold(
             block.header.app_version)
-        upper = appconsts.square_size_upper_bound(block.header.app_version)
-        square = square_mod.construct(normal, pfbs, upper, threshold)
+        # the bound the proposer laid the block out under, never the
+        # versioned constant: a PFB's reserved share-index bytes depend on
+        # it, and with them where every blob of the square starts
+        square = square_mod.construct(normal, pfbs, bound, threshold)
     return block, square
 
 

@@ -70,9 +70,15 @@ STATE, DELTA, BLOCK, LATEST = 0, 1, 2, 3
 # Integers little-endian. The header rides as THE header codec's JSON
 # (chain/consensus.py: block store, WAL and socket wire agree on every
 # field, or a stored block re-hashes differently than the chain committed);
-# tx bytes ride raw. The CRC is the record's own corruption check: the file
-# engine frames nothing, and a flipped or torn record must fail in
-# load_block, not parse into another block.
+# tx bytes ride raw. Beside the header's keys the document carries one of
+# the store's own, `layout_bound`: the square-size bound the block's
+# proposer laid it out under (App.max_effective_square_size at the state
+# the proposal was built on), which a read needs to lay the stored txs out
+# the same way (chain/query.rebuild_square). It is no header field and is
+# in no hash; a record without it (an earlier writer's) reads as None.
+# The CRC is the record's own corruption check: the file engine frames
+# nothing, and a flipped or torn record must fail in load_block, not parse
+# into another block.
 
 BLOCK_MAGIC = b"CBLK"
 BLOCK_VERSION = 1
@@ -92,10 +98,16 @@ class BlockRecordError(ValueError):
     """A stored block record that is torn, corrupt or of an unknown kind."""
 
 
-def _encode_block(block: Block) -> bytes:
+LAYOUT_BOUND_KEY = "layout_bound"
+
+
+def _encode_block(block: Block, layout_bound: int | None = None) -> bytes:
     from celestia_app_tpu.chain.consensus import header_to_json
 
-    header = _compact_json(header_to_json(block.header))
+    doc = header_to_json(block.header)
+    if layout_bound is not None:
+        doc[LAYOUT_BOUND_KEY] = layout_bound
+    header = _compact_json(doc)
     parts = [
         BLOCK_MAGIC + bytes([BLOCK_VERSION]) + _u32(len(header)),
         header,
@@ -111,7 +123,8 @@ def _encode_block(block: Block) -> bytes:
     return b"".join(parts)  # the one copy of the payload
 
 
-def _decode_block(blob: bytes) -> Block:
+def _decode_record(blob: bytes) -> tuple[Block, int | None]:
+    """(the block, the bound it was laid out under or None)."""
     from celestia_app_tpu.chain.consensus import header_from_json
 
     view = memoryview(blob)
@@ -139,12 +152,13 @@ def _decode_block(blob: bytes) -> Block:
     def u32() -> int:
         return int.from_bytes(take(_U32), "little")
 
-    header = header_from_json(json.loads(bytes(take(u32()))))
+    doc = json.loads(bytes(take(u32())))
+    header = header_from_json(doc)
     txs = tuple(bytes(take(u32())) for _ in range(u32()))
     if pos != end:
         raise BlockRecordError(
             f"block record: {end - pos} stray bytes after the last tx")
-    return Block(header=header, txs=txs)
+    return Block(header=header, txs=txs), doc.get(LAYOUT_BOUND_KEY)
 
 
 def _atomic_write(path: str, data: bytes) -> None:
@@ -507,13 +521,14 @@ class ChainDB:
 
     # -- blocks ----------------------------------------------------------
 
-    def save_block(self, block: Block) -> None:
+    def save_block(self, block: Block,
+                   layout_bound: int | None = None) -> None:
         from celestia_app_tpu import obs
 
         height = block.header.height
         with obs.span("storage.save_block", height=height):
             with obs.span("storage.block.encode"):
-                record = _encode_block(block)
+                record = _encode_block(block, layout_bound)
             # written AND synced on the caller's thread, before save_commit
             # may move LATEST (the crash-safety contract above)
             with obs.span("storage.block.put", bytes=len(record)):
@@ -521,6 +536,11 @@ class ChainDB:
                 self.backend.sync()
 
     def load_block(self, height: int) -> Block:
+        return self.load_block_and_bound(height)[0]
+
+    def load_block_and_bound(self, height: int) -> tuple[Block, int | None]:
+        """The block and the square-size bound its proposer laid it out
+        under; None for a block stored before the bound was recorded."""
         from celestia_app_tpu import obs
 
         # read + CRC + split: the first thing a read of a stored height
@@ -528,13 +548,13 @@ class ChainDB:
         with obs.span("storage.load_block", height=height):
             return self._load_block(height)
 
-    def _load_block(self, height: int) -> Block:
+    def _load_block(self, height: int) -> tuple[Block, int | None]:
         blob = self.backend.get(BLOCK, height)
         if blob is None:
             raise FileNotFoundError(f"no block at height {height}")
         # the bytes say which codec wrote them: no setting, no suffix
         if blob.startswith(BLOCK_MAGIC):
-            return _decode_block(blob)
+            return _decode_record(blob)
         if blob.startswith(GZIP_MAGIC):
             # a block an earlier version stored (gzip-JSON, txs base64):
             # read-only compatibility, counted so a home shows how often
@@ -543,7 +563,7 @@ class ChainDB:
 
             telemetry.incr("storage.legacy_block_reads")
             old = block_from_json(self._decode(blob))
-            return Block(header=old.header, txs=tuple(old.txs))
+            return Block(header=old.header, txs=tuple(old.txs)), None
         raise BlockRecordError(
             f"block {height}: unknown record magic {blob[:4]!r}")
 

@@ -494,6 +494,13 @@ def _bucket(n: int) -> int:
     return b
 
 
+def padded_lanes(n: int) -> int:
+    """The lanes the device runs for n signatures: full MAX_DISPATCH
+    chunks plus the last chunk's bucket."""
+    full, rest = divmod(n, MAX_DISPATCH)
+    return full * MAX_DISPATCH + (_bucket(rest) if rest else 0)
+
+
 _SLOW = object()  # sentinel: decomposition irregularity -> scalar fallback
 
 
@@ -532,7 +539,16 @@ def verify_batch(items, backend: str = "auto") -> np.ndarray:
     """Verify a batch of (pubkey33, signature, message) triples in one
     device dispatch per MAX_DISPATCH chunk; returns a bool lane mask with
     exactly `_py_verify`'s per-item semantics. backend: "auto" (device
-    when JAX imports, else scalar) | "device" | "scalar"."""
+    when JAX imports, else scalar) | "device" | "scalar".
+
+    Two spans price the device path's halves: `admission.sig_prep` (the
+    host's per-lane Python: decompression, s^-1, the GLV split, limb and
+    digit packing) and `admission.sig_dispatch` (device call -> mask on
+    the host; attr `lanes` = the padded bucket). The counter
+    `admission.batch_padded_lanes` adds each dispatch's bucket."""
+    from celestia_app_tpu import obs
+    from celestia_app_tpu.utils import telemetry
+
     out = np.zeros(len(items), dtype=bool)
     if not items:
         return out
@@ -542,22 +558,27 @@ def verify_batch(items, backend: str = "auto") -> np.ndarray:
             out[i] = _crypto._py_verify(pk, sig, msg)
         return out
 
-    preps = [_prep(pk, sig, msg) for pk, sig, msg in items]
-    lanes = []
-    for i, p in enumerate(preps):
-        if p is _SLOW:
-            out[i] = _crypto._py_verify(*items[i])
-        elif p is not None:
-            lanes.append(i)
-    for start in range(0, len(lanes), MAX_DISPATCH):
-        chunk = lanes[start : start + MAX_DISPATCH]
-        out[chunk] = _dispatch([preps[i] for i in chunk])
+    with obs.span("admission.sig_prep", n_sigs=len(items)):
+        preps = [_prep(pk, sig, msg) for pk, sig, msg in items]
+        lanes = []
+        for i, p in enumerate(preps):
+            if p is _SLOW:
+                out[i] = _crypto._py_verify(*items[i])
+            elif p is not None:
+                lanes.append(i)
+        chunks = [lanes[start : start + MAX_DISPATCH]
+                  for start in range(0, len(lanes), MAX_DISPATCH)]
+        packed = [_pack([preps[i] for i in chunk]) for chunk in chunks]
+    for chunk, arrays in zip(chunks, packed):
+        bucket = arrays[0].shape[0]
+        with obs.span("admission.sig_dispatch", lanes=bucket):
+            out[chunk] = _dispatch(arrays)[: len(chunk)]
+        telemetry.incr("admission.batch_padded_lanes", by=bucket)
     return out
 
 
-def _dispatch(preps) -> np.ndarray:
-    import jax
-
+def _pack(preps) -> tuple[np.ndarray, ...]:
+    """The kernel's twelve arguments for one chunk, padded to its bucket."""
     n = len(preps)
     b = _bucket(n)
     qx = np.zeros((b, N_LIMBS), np.uint64)
@@ -587,9 +608,13 @@ def _dispatch(preps) -> np.ndarray:
         if r + _N < _P:
             r2_l[i] = _to_limbs(r + _N)
             has_r2[i] = True
+    return (qx, qy, ydiff, kq1d, kq2d, kg1d, kg2d, sg1, sg2, r_l, r2_l,
+            has_r2)
+
+
+def _dispatch(arrays) -> np.ndarray:
+    """One device call over a packed chunk; the whole bucket's mask."""
+    import jax
+
     with jax.enable_x64(True):
-        mask = np.asarray(
-            jitted_verify(b)(qx, qy, ydiff, kq1d, kq2d, kg1d, kg2d,
-                             sg1, sg2, r_l, r2_l, has_r2)
-        )
-    return mask[:n]
+        return np.asarray(jitted_verify(arrays[0].shape[0])(*arrays))

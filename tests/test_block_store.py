@@ -199,7 +199,8 @@ def test_home_of_the_parent_commit_serves_every_height(
     from celestia_app_tpu.chain.app import App
     from celestia_app_tpu.chain.node import Node
 
-    def parents_save_block(self, block):
+    def parents_save_block(self, block, layout_bound=None):
+        # the parent's writer knew no bound: its records carry none
         self.backend.put(storage.BLOCK, block.header.height,
                          _legacy_record(block))
         self.backend.sync()
@@ -286,7 +287,7 @@ DAMAGE = {
 def test_a_damaged_record_raises_and_yields_no_block(db, damage):
     block = Block(_header(), (_random(3_000, 1), b"second", _random(500, 2)))
     record = storage._encode_block(block)
-    assert storage._decode_block(record) == block
+    assert storage._decode_record(record) == (block, None)
     bad = DAMAGE[damage](record)
     assert bad != record
     db.backend.put(storage.BLOCK, 1, bad)
@@ -400,11 +401,14 @@ def test_benchmark_metric_reads_the_span_of_that_name(db, metric, span):
                     "per_unit": "blocks"}
     with open(os.path.join(REPO, "BENCHMARK.json"), encoding="utf-8") as f:
         entry = {m["name"]: m for m in json.load(f)["per_layer"]}[metric]
+    # a later cell may be appended to the list (k64-pfb-light was): the
+    # entry is pinned without it, the two produce cells must be IN it
+    cells = entry.pop("workloads")
+    assert {"k64-pfb-full", "k128-pfb-full"} <= set(cells)
     assert entry == {
         "name": metric, "unit": "ms/block", "better": "lower",
         "source": "program_span", "layer": "block lifecycle",
-        "moves": "block_p90",
-        "workloads": ["k64-pfb-full", "k128-pfb-full"]}
+        "moves": "block_p90"}
 
     module_spec = importlib.util.spec_from_file_location(
         "bench_span_total",

@@ -6,8 +6,13 @@ results or speed).
 Interpret-mode Pallas tests cannot see what the chip's compiler refuses:
 a slice off the tiling, too much fast memory, an unsupported cast, a
 kernel it cannot partition across a mesh. These cases ask it directly, at
-the shapes chip_smoke.py runs: the k=64 / k=128 block pipelines and the
-prover's level stack with the Pallas SHA-256 kernel, the batched
+the shapes chip_smoke.py and the benchmark's cells run: the block
+pipelines and the prover's level stack with the Pallas SHA-256 kernel at
+k=64 / k=128 (full squares) and at k=8 / 16 / 32 (the small squares of
+`k64-pfb-light`; at k=8 no hash level reaches the kernel's 1,024-message
+tile, `ops/sha256.sha256` keeps to its jnp path, and the case checks that
+the program holds NO kernel, as the cases with `kernels=False` all do), the
+batched
 secp256k1 verifier, the namespace search, the blob commitment batch, and
 the four-chip sharded k=256 program.
 
@@ -69,9 +74,8 @@ def _compile(fn, *shapes, kernels: bool = True, **jit_kw):
     resident = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
                 + mem.output_size_in_bytes + mem.generated_code_size_in_bytes)
     assert resident < V5E_HBM_BYTES, f"{resident} bytes do not fit one v5e"
-    if kernels:
-        assert "tpu_custom_call" in compiled.as_text(), \
-            "no Pallas kernel in the compiled program"
+    assert ("tpu_custom_call" in compiled.as_text()) == kernels, \
+        "a Pallas kernel in the compiled program: wanted %s" % kernels
     return compiled
 
 
@@ -86,22 +90,30 @@ def test_sha256_pallas_kernel(one_chip):
     _compile(sha256.sha256, _u8((4096, 542), one_chip))
 
 
-@pytest.mark.parametrize("k", [64, 128])
+SQUARES = [8, 16, 32, 64, 128]
+# the Pallas SHA-256 kernel takes batches of >= 1,024 messages
+# (ops/sha256.sha256); a 16x16 extended square has 256 leaves an axis
+PALLAS_FROM_K = 16
+
+
+@pytest.mark.parametrize("k", SQUARES)
 def test_block_pipeline(one_chip, k):
     """da/eds.pipeline_fn: RS extend + 4k NMT roots + data root, the
     program behind compute_entry(ods, "device")."""
     from celestia_app_tpu.da import eds
 
-    _compile(eds.pipeline_fn(k), _u8((k, k, 512), one_chip))
+    _compile(eds.pipeline_fn(k), _u8((k, k, 512), one_chip),
+             kernels=k >= PALLAS_FROM_K)
 
 
-@pytest.mark.parametrize("k", [64, 128])
+@pytest.mark.parametrize("k", SQUARES)
 def test_prover_level_stack(one_chip, k):
     """BlockProver's level pass (da/proof_device._jitted_row_levels)."""
     from celestia_app_tpu.da import proof_device
 
     levels = proof_device._jitted_row_levels.__wrapped__(k)
-    _compile(levels, _u8((2 * k, 2 * k, 512), one_chip))
+    _compile(levels, _u8((2 * k, 2 * k, 512), one_chip),
+             kernels=k >= PALLAS_FROM_K)
 
 
 def test_namespace_search(one_chip):
