@@ -57,6 +57,8 @@ Commitment counters (the tier-1 no-recompute test pins; FORMATS §20):
                                  (per blob; host path or a cold batch)
   commitment.batch_dispatches    prevalidation commitment batches
   commitment.batch_lanes         blobs computed by prevalidation batches
+  commitment.batch_programs      device programs launched by commitment
+                                 batches (da/commitment_device: one a batch)
   commitment.prevalidate_below_batch  commitment batches below the gate
 """
 
@@ -287,9 +289,10 @@ def prevalidate_commitments(app, raws, btxs=None) -> int:
     """Phase 1, commitment half: compute the share commitments of every
     pending blob not already in the App's verified-commitment cache in
     ONE batched dispatch (da/commitment_device via
-    blob_validation.batch_commitments — device-class engines take the
-    vmapped SHA-256 subtree-root MMR launch, host engines the host
-    loop), and cache the results. Returns how many blobs were computed.
+    blob_validation.batch_commitments — device-class engines write the
+    blobs into one buffer and take every subtree root from one level
+    pass, host engines the host loop), and cache the results. Returns
+    how many blobs were computed.
     Never raises and never rejects: a blob that skips the batch simply
     meets `validate_blob_tx`'s per-blob host compute later, with
     identical bytes (counted `commitment.recomputes`)."""
@@ -327,7 +330,8 @@ def prevalidate_commitments(app, raws, btxs=None) -> int:
     from celestia_app_tpu.chain import blob_validation
 
     try:
-        # the one batch: hash, upload, jit_nmt_roots, download
+        # the one batch: pack one buffer, one program, fold (the device
+        # engines open admission.commit_pack / _dispatch / _fold under it)
         with obs.span("admission.commitments", n_blobs=len(pending)):
             commitments = blob_validation.batch_commitments(
                 pending, threshold, engine=getattr(app, "engine", "host"))

@@ -16,8 +16,9 @@ square.py), these subtree roots appear verbatim as inner nodes of the row NMTs
 for any square size — commitments are square-size independent (ADR-008/013).
 
 Host path here (hashlib, used per-tx in CheckTx); da/commitment_device.py
-batches every blob of a block into a few vectorized SHA launches (BASELINE
-config 3) and is what ProcessProposal uses via blob_validation.batch_commitments.
+writes every blob of a batch into one buffer and takes all their subtree
+roots from one device program (BASELINE config 3); admission and
+ProcessProposal reach it via blob_validation.batch_commitments.
 """
 
 from __future__ import annotations

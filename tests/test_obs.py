@@ -561,6 +561,9 @@ BLOCK_PATH = [
     ("node.broadcast_txs", None, 1),
     ("admission.prevalidate", "node.broadcast_txs", 1),
     ("admission.commitments", "admission.prevalidate", 1),
+    ("admission.commit_pack", "admission.commitments", 1),
+    ("admission.commit_dispatch", "admission.commitments", 1),
+    ("admission.commit_fold", "admission.commitments", 1),
     ("admission.check_txs", "node.broadcast_txs", 1),
     ("block.produce", None, 1),
     ("prepare_proposal", "block.produce", 1),

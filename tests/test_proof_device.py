@@ -4,6 +4,7 @@ import numpy as np
 import pytest
 
 from celestia_app_tpu.da import commitment, commitment_device, dah, proof, proof_device, square
+from celestia_app_tpu.da import shares as shares_mod
 from celestia_app_tpu.da.blob import Blob
 from celestia_app_tpu.da.dah import ExtendedDataSquare
 from celestia_app_tpu.da.namespace import Namespace
@@ -18,16 +19,94 @@ def _blobs(rng, spec):
     return out
 
 
-@pytest.mark.backend
-def test_commitments_device_match_host():
-    rng = np.random.default_rng(0)
+def _blob_bytes(n_shares: int) -> int:
+    """The largest blob that fills exactly `n_shares` shares."""
+    return (appconsts.FIRST_SPARSE_SHARE_CONTENT_SIZE
+            + (n_shares - 1) * appconsts.CONTINUATION_SPARSE_SHARE_CONTENT_SIZE)
+
+
+# blob sizes in bytes, one batch a case; counts reduced from the cells' 36 / 16
+COMMITMENT_BATCHES = {
     # sizes chosen to hit 1-share, multi-share, multi-subtree, and
     # non-power-of-two MMR decompositions
-    blobs = _blobs(rng, [10, 500, 2000, 480 * 9, 480 * 30, 7])
-    thr = appconsts.subtree_root_threshold(appconsts.LATEST_VERSION)
+    "assorted": [10, 500, 2000, 480 * 9, 480 * 30, 7],
+    "k128-pfb-full": [200_000] * 4,
+    "k64-pfb-full": [50_000] * 4,
+    "k64-pfb-light-1000": [1_000] * 4,
+    "k64-pfb-light-2000": [2_000] * 4,
+    "k64-pfb-light-8000": [8_000] * 4,
+    # more than 4,096 shares: width 128 at threshold 64
+    "wider-than-4096-shares": [_blob_bytes(4201), 300],
+    # widths 128, 1, 8, 2, 1 at threshold 64, in one buffer
+    "mixed-widths": [_blob_bytes(4201), _blob_bytes(3), 200_000,
+                     _blob_bytes(100), 9],
+    "one-share": [1, appconsts.FIRST_SPARSE_SHARE_CONTENT_SIZE, 40, 300],
+    # n an exact multiple of its width: 128 = 64 x 2, 416 = 52 x 8
+    "exact-width-multiples": [_blob_bytes(128), _blob_bytes(416),
+                              _blob_bytes(64), _blob_bytes(128)],
+}
+THRESHOLDS = [appconsts.subtree_root_threshold(appconsts.LATEST_VERSION), 8]
+
+
+@pytest.mark.backend
+@pytest.mark.parametrize("share_version", appconsts.SUPPORTED_SHARE_VERSIONS)
+@pytest.mark.parametrize("thr", THRESHOLDS)
+@pytest.mark.parametrize("case", COMMITMENT_BATCHES)
+def test_commitments_device_match_host(case, thr, share_version):
+    rng = np.random.default_rng(0)
+    blobs = [Blob(b.namespace, b.data, share_version)
+             for b in _blobs(rng, COMMITMENT_BATCHES[case])]
     host = commitment.create_commitments(blobs, thr)
     dev = commitment_device.commitments_device(blobs, thr)
     assert dev == host
+
+
+def test_mixed_widths_share_one_buffer_with_bounded_padding():
+    """The batch of the `mixed-widths` case is what it says: widths 1, 2,
+    8 and 128 in one buffer, each blob at a multiple of its own width, and
+    less padding between blobs than the batch has rows of its own."""
+    thr = THRESHOLDS[0]
+    blobs = _blobs(np.random.default_rng(0), COMMITMENT_BATCHES["mixed-widths"])
+    counts = [shares_mod.sparse_shares_needed(len(b.data)) for b in blobs]
+    widths = [commitment.subtree_width(n, thr) for n in counts]
+    assert sorted(set(widths)) == [1, 2, 8, 128]
+    buf, width, picks, per_blob, used = commitment_device._pack(blobs, thr)
+    assert width == 128 and buf.shape == (8192, 512)
+    assert used < 2 * sum(counts)
+    assert per_blob == [
+        len(commitment.merkle_mountain_range_sizes(n, w))
+        for n, w in zip(counts, widths)]
+    n_roots = sum(per_blob)
+    assert n_roots == 144 and len(picks) == 256
+    assert not picks[n_roots:].any()
+    # the buffer holds exactly split_blob's shares, each blob at its start
+    cursor = 0
+    for blob, n, w in zip(blobs, counts, widths):
+        cursor = commitment_device.aligned_start(cursor, w)
+        want = b"".join(s.raw for s in shares_mod.split_blob(
+            blob.namespace, blob.data, blob.share_version))
+        assert buf[cursor:cursor + n].tobytes() == want
+        cursor += n
+    assert cursor == used
+
+
+@pytest.mark.parametrize("thr", THRESHOLDS)
+def test_subtree_plan_names_every_share_once(thr):
+    """Host only. For every share count 1 .. 5,000: a blob that starts at
+    a multiple of its width has every MMR chunk start at a multiple of the
+    chunk's size, and the (level, index) plan walks the blob's rows in
+    order, each exactly once."""
+    for n in range(1, 5001):
+        w = commitment.subtree_width(n, thr)
+        assert w & (w - 1) == 0 and w <= n
+        start = 3 * w
+        sizes = np.asarray(commitment.merkle_mountain_range_sizes(n, w))
+        chunk_starts = start + np.cumsum(sizes) - sizes
+        assert not (chunk_starts % sizes).any(), n
+        levels, indices = commitment_device.subtree_plan(start, n, w)
+        assert ((1 << levels) == sizes).all(), n
+        assert ((indices << levels) == chunk_starts).all(), n
+        assert chunk_starts[-1] + sizes[-1] == start + n
 
 
 @pytest.mark.backend

@@ -125,18 +125,39 @@ def test_namespace_search(one_chip):
              kernels=False)
 
 
-def test_blob_commitment_batch(one_chip):
-    """da/commitment_device at chip_smoke's traffic: 64 blobs x 58 shares
-    decompose into width-1 subtrees, padded to 4096 trees per launch."""
-    from celestia_app_tpu.da import commitment
-    from celestia_app_tpu.ops import nmt
+# (blobs, bytes a blob) -> the program's shape key (rows, largest width,
+# padded picks): the three produce cells' batches (the light cell's largest
+# class) and chip_smoke's 64 blobs x 58 shares
+COMMITMENT_BATCHES = {
+    "k128-pfb-full": (36, 200_000, (16384, 8, 2048)),
+    "k64-pfb-full": (36, 50_000, (4096, 2, 2048)),
+    "k64-pfb-light": (16, 8_000, (512, 1, 512)),
+    "chip_smoke": (64, 478 + 57 * 482, (4096, 1, 4096)),
+}
 
-    width = commitment.subtree_width(58, 64)
-    sizes = set(commitment.merkle_mountain_range_sizes(58, width))
-    assert sizes == {1}
-    trees = commitment.round_up_pow2(64 * 58)
-    _compile(nmt.nmt_roots, _u8((trees, 1, 29), one_chip),
-             _u8((trees, 1, 512), one_chip))
+
+@pytest.mark.parametrize("batch", COMMITMENT_BATCHES)
+def test_blob_commitment_batch(one_chip, batch):
+    """da/commitment_device's one program: every level 0 .. log2(width)
+    over the whole buffer and the gather of the named roots. The Pallas
+    SHA-256 kernel takes a level from 1,024 messages: every batch here
+    but the light cell's 512 rows holds it."""
+    import functools
+
+    from celestia_app_tpu.da import commitment_device
+    from celestia_app_tpu.da.blob import Blob
+    from celestia_app_tpu.da.namespace import Namespace
+
+    n_blobs, size, key = COMMITMENT_BATCHES[batch]
+    buf, width, picks, _per_blob, _used = commitment_device._pack(
+        [Blob(Namespace.v0(b"\x01" * 8), bytes(size))] * n_blobs, 64)
+    assert (buf.shape[0], width, len(picks)) == key
+    rows = key[0]
+    program = functools.partial(
+        commitment_device.commitment_subtree_roots.__wrapped__, width=width)
+    _compile(program, _u8((rows, 512), one_chip),
+             jax.ShapeDtypeStruct(picks.shape, jnp.int32, sharding=one_chip),
+             kernels=rows >= 1024)
 
 
 def test_secp256k1_verify_batch(one_chip):

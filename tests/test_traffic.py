@@ -115,6 +115,51 @@ def test_no_commitment_recompute_through_lifecycle(monkeypatch):
     assert _counter("commitment.cache_hits") - h1 >= 2 * len(raws)
 
 
+@pytest.mark.backend
+def test_admitted_window_is_one_buffer_and_one_program(monkeypatch):
+    """A window of 16 uncached blobs on a device-class engine: ONE batch,
+    ONE device program, the batch's three phases once each under
+    `admission.commitments` — and no `Share` object anywhere on the
+    admission path (the blobs are written into one array)."""
+    from celestia_app_tpu.da import shares as shares_mod
+
+    node, signer, _p, addrs = _fresh_node(chain="traffic-one-program",
+                                          engine="auto")
+    raws = _pfb_raws(signer, addrs,
+                     [_blobs_for(60 + i, 2) for i in range(len(addrs))])
+    built = []
+    post_init = shares_mod.Share.__post_init__
+    monkeypatch.setattr(
+        shares_mod.Share, "__post_init__",
+        lambda self: (built.append(1), post_init(self))[1])
+    monkeypatch.setattr(
+        shares_mod, "split_blob",
+        lambda *a, **k: pytest.fail("split_blob on the admission path"))
+    names = ("commitment.batch_dispatches", "commitment.batch_programs",
+             "commitment.batch_lanes", "square.share_objects")
+    before = {n: _counter(n) for n in names}
+    mark = len(node.app.traces.read("spans", 0, 100_000))
+    assert all(r.code == 0 for r in node.broadcast_txs(raws))
+    moved = {n: _counter(n) - before[n] for n in names}
+    assert moved == {"commitment.batch_dispatches": 1,
+                     "commitment.batch_programs": 1,
+                     "commitment.batch_lanes": 16,
+                     "square.share_objects": 0}
+    assert built == []
+    rows = node.app.traces.read("spans", 0, 100_000)[mark:]
+    batch = [r for r in rows if r["name"] == "admission.commitments"]
+    assert len(batch) == 1
+    phases = {}
+    for phase in ("admission.commit_pack", "admission.commit_dispatch",
+                  "admission.commit_fold"):
+        [phases[phase]] = [r for r in rows if r["name"] == phase]
+        assert phases[phase]["parent_id"] == batch[0]["span_id"]
+    pack = phases["admission.commit_pack"]
+    assert 16 <= pack["rows"] <= pack["padded_rows"]
+    assert pack["padded_rows"] & (pack["padded_rows"] - 1) == 0
+    assert pack["levels"] == 1  # blobs of at most 4 shares: width 1
+
+
 def test_scalar_admission_fills_cache_for_later_phases():
     """A single /broadcast_tx (below any batch window) pays exactly ONE
     host recompute at CheckTx — and the proposal phases still resolve
