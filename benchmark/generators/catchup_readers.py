@@ -5,12 +5,18 @@ a few of them.
 Set-up commits `setup_blocks` blocks of the `setup_mix` traffic (the
 configuration's `stored_heights`); the window produces none. The
 `swept_heights` oldest are swept, tip-(setup_blocks-1) .. tip-(setup_blocks-
-swept_heights): client c starts `*_offsets[c]` heights into that range, all
-rotated by one amount the seed draws, asks one request at its height, moves
-to the next, and wraps. A light request is `light_header(h)` followed by
-`sample(h, cells_per_round seeded cells)`; a follower's request is one
-namespace read at h (its own namespace, every `absent_every`-th read one
-absent namespace besides). Every request runs under one benchmark span,
+swept_heights): client c owns the `range_heights` consecutive heights that
+start `*_offsets[c]` heights into that range, all rotated by one amount the
+seed draws; it asks one request at its height, moves to the next, and wraps
+inside its own range. Ranges that do not overlap are what a DASer hands its
+workers: no two readers ever ask one height, so none waits on another's
+build and every request pays its own miss as long as the ranges together
+are wider than the caches. (Walkers of equal speed on ONE shared ring fall
+into step behind each other's builds and never part: the rate then swings
+with how many do, PERF.md section 6.) A light request is `light_header(h)`
+followed by `sample(h, cells_per_round seeded cells)`; a follower's request
+is one namespace read at h (its own namespace, every `absent_every`-th read
+one absent namespace besides). Every request runs under one benchmark span,
 `catchup_request`, whatever its kind: a re-extend is set off under either.
 
 Kept whole for the comparison (lib/compare.serve_cell): the warm-up's
@@ -40,6 +46,12 @@ _tip = cells_mod.load_module(
 REQUEST_SPAN = "catchup_request"
 
 
+def height_index(plan: dict, done: int, swept: int) -> int:
+    """Where in the swept range a client's `done`-th request lands: `span`
+    heights from its start, over and over."""
+    return (plan["start"] + done % plan["span"]) % swept
+
+
 class Traffic(_tip.Traffic):
     def __init__(self, cell, seed: int):
         stored = cell.config["stored_heights"]
@@ -59,11 +71,12 @@ class Traffic(_tip.Traffic):
     def _schedule(self, client: int) -> dict:
         """One client's walk: its kind, where it starts, what it reads."""
         mix = self.mix
+        span = mix["range_heights"]
         if client < mix["sweepers"]:
-            return {"kind": "light",
+            return {"kind": "light", "span": span,
                     "start": mix["sweeper_offsets"][client] + self.rotation}
         f = client - mix["sweepers"]
-        return {"kind": "read",
+        return {"kind": "read", "span": span,
                 "start": mix["follower_offsets"][f] + self.rotation,
                 "namespace": self.chain.namespaces[
                     mix["follower_namespace_ranks"][f]]}
@@ -147,7 +160,8 @@ class Traffic(_tip.Traffic):
         kept, read_shapes = [], []
         try:
             while time.perf_counter() < deadline:
-                height = self.swept[(plan["start"] + done) % len(self.swept)]
+                height = self.swept[height_index(plan, done,
+                                                 len(self.swept))]
                 if plan["kind"] == "light":
                     asked, reply = self._light_request(sut, height, rng,
                                                        spans)
@@ -161,8 +175,9 @@ class Traffic(_tip.Traffic):
                     bad = (len(asked) if reply is None
                            else sut.refused_in(reply))
                     read_shapes.append((self.k, len(asked)))
-                # the 2nd, 10th, 18th ..: a follower's 10th read, its first
-                # with the absent namespace, is among them
+                # the 2nd, then every keep_every-th: with the mix's 9 and
+                # absent_every 10, a follower's 20th read, which asks the
+                # absent namespace besides, is among them
                 if done % keep_every == 1 and reply is not None:
                     kept.append((plan["kind"], height, asked, reply))
                 refused += bad

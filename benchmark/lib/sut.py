@@ -58,9 +58,12 @@ class Validator:
 
         # under TMPDIR, which the driver gives each side of a comparison
         self._dir = tempfile.mkdtemp(prefix="bench-validator-")
+        # `max_square_size`: the consensus-critical home-config cap, which a
+        # configuration above the versioned bound states (else None: App's)
         self.app = App(chain_id=config["chain_id"], engine=config["engine"],
                        app_version=config["app_version"],
-                       data_dir=os.path.join(self._dir, "data"))
+                       data_dir=os.path.join(self._dir, "data"),
+                       max_square_size=config.get("max_square_size"))
         self.app.init_chain({
             "time_unix": T0,
             "accounts": [{"address": a.hex(), "balance": b}

@@ -8,15 +8,22 @@ validator must have committed — what ProcessProposal recomputes:
   layout / build_ods   celestia-app v3 square layout (go-square builder:
                        worst-case PFB reservation, namespace-sorted blobs at
                        non-interactive default alignment), share splitting
-  extend               2D Reed-Solomon, Leopard GF(2^8) (Lin-Chung-Han additive
-                       FFT over the Cantor basis), run as the FFT itself
+  extend               2D Reed-Solomon, Leopard (Lin-Chung-Han additive FFT
+                       over the Cantor basis), run as the FFT itself: over
+                       GF(2^8) up to 256 shards an axis (k <= 128), over
+                       GF(2^16) beyond, the shard count alone deciding
   axis_roots/data_root namespaced Merkle trees over rows and columns, RFC 6962
                        root over the 4k axis roots
   verify_range         NMT range-proof check (light node's side of a sample)
 
 Sources: celestia-app specs/src/specs/{shares,data_square_layout,namespace}.md,
 go-square square/builder.go, celestiaorg/nmt hasher.go + proof.go,
-catid/leopard LeopardFF8.
+catid/leopard LeopardFF8; beyond 256 shards rsmt2d NewLeoRSCodec ->
+klauspost/reedsolomon WithLeopardGF (leopard.go, the 16-bit code) and
+catid/leopard LeopardFF16 (kPolynomial 0x1002D, kCantorBasis). FROM MEMORY,
+with no vector to check it against offline: which bytes of a shard make a
+16-bit symbol (`symbols_of_bytes` / `bytes_of_symbols`, the one place that
+says it).
 """
 
 from __future__ import annotations
@@ -34,6 +41,7 @@ CONT_SPARSE = SHARE - NS - 1               # 482
 FIRST_COMPACT = SHARE - NS - 1 - 4 - 4     # 474
 CONT_COMPACT = SHARE - NS - 1 - 4          # 478
 SUBTREE_ROOT_THRESHOLD = 64
+SQUARE_SIZE_UPPER_BOUND = 128   # celestia-app v3 pkg/appconsts/v3/app_consts.go
 
 TX_NS = b"\x00" * 28 + b"\x01"
 PFB_NS = b"\x00" * 28 + b"\x04"
@@ -253,103 +261,169 @@ def namespace_shares(ods: np.ndarray, ns: bytes) -> list[bytes]:
     return [flat[i].tobytes() for i in np.flatnonzero(hit)]
 
 
-# -- Leopard GF(2^8) --------------------------------------------------------
+# -- Leopard: GF(2^8) up to 256 shards an axis, GF(2^16) beyond --------------
 
-_CANTOR = (1, 214, 152, 146, 86, 200, 88, 230)
+# bits of a symbol -> (field polynomial, Cantor basis: beta_0 = 1,
+# beta_{i+1}^2 + beta_{i+1} = beta_i)
+_FIELDS = {
+    8: (0x11D, (1, 214, 152, 146, 86, 200, 88, 230)),
+    16: (0x1002D, (0x0001, 0xACCA, 0x3C0E, 0x163E, 0xC582, 0xED2E, 0x914C,
+                   0x4012, 0x6C98, 0x10D8, 0x6A72, 0xB900, 0xFDB8, 0xFB34,
+                   0xFF38, 0x991E)),
+}
+_SYMBOL = {8: np.uint8, 16: np.uint16}
+
+
+def field_bits(k: int) -> int:
+    """The field of an axis of k data + k recovery shards, chosen as the
+    chain's codec chooses it (rsmt2d NewLeoRSCodec, klauspost/reedsolomon
+    WithLeopardGF): 8-bit symbols up to 256 shards, 16-bit beyond. The shard
+    count alone decides."""
+    return 8 if 2 * k <= 256 else 16
 
 
 @functools.lru_cache(maxsize=None)
-def _mul_table() -> np.ndarray:
-    """(256, 256) products of byte labels: GF(2^8)/0x11D multiplication
-    conjugated by the Cantor change of basis (label bit b <-> beta_b)."""
-    log = np.zeros(256, dtype=np.int64)
+def _log_exp(bits: int) -> tuple[np.ndarray, np.ndarray]:
+    """(log, exp) of the field on labels: the polynomial-basis field
+    conjugated by the Cantor change of basis (label bit b <-> beta_b), so
+    that the product of labels a, b != 0 is exp[(log[a] + log[b]) % (2^bits
+    - 1)]. log[0] is meaningless."""
+    poly, basis = _FIELDS[bits]
+    order = 1 << bits
+    log = np.zeros(order, dtype=np.int64)
     state = 1
-    for i in range(255):
+    for i in range(order - 1):
         log[state] = i
         state <<= 1
-        if state & 0x100:
-            state ^= 0x11D
-    cantor = np.zeros(256, dtype=np.int64)
-    for b in range(8):
-        cantor[1 << b:2 << b] = cantor[:1 << b] ^ _CANTOR[b]
+        if state & order:
+            state ^= poly
+    cantor = np.zeros(order, dtype=np.int64)
+    for b in range(bits):
+        cantor[1 << b:2 << b] = cantor[:1 << b] ^ basis[b]
     label_log = log[cantor]
-    exp = np.zeros(255, dtype=np.int64)
-    exp[label_log[1:]] = np.arange(1, 256)
-    table = exp[(label_log[:, None] + label_log[None, :]) % 255]
-    table[0, :] = 0
-    table[:, 0] = 0
-    return table.astype(np.uint8)
+    exp = np.zeros(order - 1, dtype=np.int64)
+    exp[label_log[1:]] = np.arange(1, order)
+    return label_log, exp
+
+
+def gf_mul(bits: int, a: int, b: int) -> int:
+    if a == 0 or b == 0:
+        return 0
+    log, exp = _log_exp(bits)
+    return int(exp[(log[a] + log[b]) % (len(log) - 1)])
+
+
+def gf_inv(bits: int, a: int) -> int:
+    log, exp = _log_exp(bits)
+    return int(exp[-log[a] % (len(log) - 1)])
+
+
+def _times(bits: int, w: int) -> np.ndarray:
+    """The products of w != 0 with every label, as a lookup table."""
+    log, exp = _log_exp(bits)
+    table = exp[(log[w] + log) % (len(log) - 1)]
+    table[0] = 0
+    return table.astype(_SYMBOL[bits])
 
 
 @functools.lru_cache(maxsize=None)
-def _skews() -> np.ndarray:
+def _skews(bits: int) -> np.ndarray:
     """S[d, b] = s_d(2^b) / s_d(2^d) for b >= d, s_d the polynomial that
-    vanishes on labels 0..2^d-1. Linearized, so s_d at any label is the XOR
-    over the label's set bits."""
-    mul = _mul_table().astype(np.int64)
-
-    def s_at(d: int, x: int) -> int:
-        acc = 1
-        for a in range(1 << d):
-            acc = int(mul[acc, x ^ a])
-        return acc
-
-    out = np.zeros((8, 8), dtype=np.int64)
-    for d in range(8):
-        at_d = s_at(d, 1 << d)
-        inv = next(y for y in range(1, 256) if mul[at_d, y] == 1)
-        for b in range(d, 8):
-            out[d, b] = mul[s_at(d, 1 << b), inv]
+    vanishes on labels 0..2^d-1: s_0(x) = x, s_{d+1}(x) = s_d(x) * s_d(x ^
+    2^d). Linearized, so s_d at any label is the XOR over the label's set
+    bits, and the recursion needs its values at the labels 2^b alone."""
+    out = np.zeros((bits, bits), dtype=np.int64)
+    at = [1 << b for b in range(bits)]              # s_0(2^b)
+    for d in range(bits):
+        inv = gf_inv(bits, at[d])
+        for b in range(d, bits):
+            out[d, b] = gf_mul(bits, at[b], inv)
+        at = [gf_mul(bits, v, v ^ at[d]) for v in at]
     return out
 
 
-def _skew(d: int, gamma: int) -> int:
+def _skew(bits: int, d: int, gamma: int) -> int:
     acc, b, g = 0, d, gamma >> d
     while g:
         if g & 1:
-            acc ^= int(_skews()[d, b])
+            acc ^= int(_skews(bits)[d, b])
         g >>= 1
         b += 1
     return acc
 
 
 def rs_encode(data: np.ndarray) -> np.ndarray:
-    """(k, ...) uint8 data shards -> (k, ...) recovery shards: the data are
-    a polynomial's values at labels [k, 2k); recovery its values at [0, k)."""
+    """(k, ...) data shards -> (k, ...) recovery shards, symbol for symbol:
+    the data are a polynomial's values at labels [k, 2k); recovery its values
+    at [0, k). Symbols are uint8 over GF(2^8) or uint16 over GF(2^16), as
+    `field_bits(k)` says; shares' bytes become 16-bit symbols through
+    `symbols_of_bytes`."""
     k = data.shape[0]
+    bits = field_bits(k)
+    if data.dtype != _SYMBOL[bits]:
+        raise TypeError(f"{2 * k} shards an axis take {bits}-bit symbols, "
+                        f"not {data.dtype}")
     if k == 1:
         return data.copy()
-    mul = _mul_table()
-    buf = np.array(data, dtype=np.uint8)
+    buf = np.array(data, order="C")     # a copy, and rows of it contiguous
     for d in range(k.bit_length() - 1):             # IFFT at offset k
         half = 1 << d
         for j in range(0, k, 2 * half):
             x, y = buf[j:j + half], buf[j + half:j + 2 * half]
             y ^= x
-            w = _skew(d, k + j)
+            w = _skew(bits, d, k + j)
             if w:
-                x ^= mul[w][y]
+                x ^= _times(bits, w)[y]
     for d in range(k.bit_length() - 2, -1, -1):     # FFT at offset 0
         half = 1 << d
         for j in range(0, k, 2 * half):
             x, y = buf[j:j + half], buf[j + half:j + 2 * half]
-            w = _skew(d, j)
+            w = _skew(bits, d, j)
             if w:
-                x ^= mul[w][y]
+                x ^= _times(bits, w)[y]
             y ^= x
     return buf
+
+
+# Which bytes of a shard make a 16-bit symbol: klauspost/reedsolomon
+# leopard.go (refMulAdd) and catid/leopard LeopardFF16.cpp work on 64-byte
+# blocks in which byte i is the low and byte i + 32 the high half of symbol i.
+# FROM MEMORY: no copy of either source and no test vector is on this machine.
+_BLOCK = 64
+
+
+def symbols_of_bytes(shards: np.ndarray) -> np.ndarray:
+    """(..., n) uint8, n a multiple of 64 -> (..., n / 2) uint16."""
+    blocks = shards.reshape(*shards.shape[:-1], -1, 2, _BLOCK // 2)
+    low, high = blocks[..., 0, :], blocks[..., 1, :]
+    return (low | high.astype(np.uint16) << 8).reshape(
+        *shards.shape[:-1], -1)
+
+
+def bytes_of_symbols(symbols: np.ndarray) -> np.ndarray:
+    """The inverse of `symbols_of_bytes`."""
+    blocks = symbols.reshape(*symbols.shape[:-1], -1, 1, _BLOCK // 2)
+    halves = np.concatenate([blocks & 0xFF, blocks >> 8], axis=-2)
+    return halves.astype(np.uint8).reshape(*symbols.shape[:-1], -1)
 
 
 def extend(ods: np.ndarray) -> np.ndarray:
     """(k, k, 512) -> (2k, 2k, 512): Q1 extends rows, Q2 columns, Q3 the
     rows of Q2."""
     k = ods.shape[0]
+    to_symbols, to_bytes = ((symbols_of_bytes, bytes_of_symbols)
+                            if field_bits(k) == 16 else (np.asarray,) * 2)
+
+    def by_rows(q: np.ndarray) -> np.ndarray:
+        return to_bytes(rs_encode(q.transpose(1, 0, 2)).transpose(1, 0, 2))
+
+    q0 = to_symbols(ods)
+    q2 = rs_encode(q0)
     eds = np.zeros((2 * k, 2 * k, SHARE), dtype=np.uint8)
     eds[:k, :k] = ods
-    eds[:k, k:] = rs_encode(ods.transpose(1, 0, 2)).transpose(1, 0, 2)
-    eds[k:, :k] = rs_encode(ods)
-    eds[k:, k:] = rs_encode(
-        eds[k:, :k].transpose(1, 0, 2)).transpose(1, 0, 2)
+    eds[:k, k:] = by_rows(q0)
+    eds[k:, :k] = to_bytes(q2)
+    eds[k:, k:] = by_rows(q2)
     return eds
 
 

@@ -52,7 +52,12 @@ class PlainValidator:
                  sent: dict[bytes, tuple], breaks: str | None = None):
         if breaks is not None and breaks not in BREAKS:
             raise ValueError(f"unknown break {breaks!r}; one of {BREAKS}")
-        self.max_k = config["gov_max_square_size"]
+        # min(governed bound, hard cap), as the program lays blocks out: the
+        # hard cap is the configuration's `max_square_size` where it states
+        # one, else the versioned bound
+        self.max_k = min(config["gov_max_square_size"],
+                         config.get("max_square_size")
+                         or da.SQUARE_SIZE_UPPER_BOUND)
         self.keep = config["served_heights"]
         self.breaks = breaks
         self._sent = sent

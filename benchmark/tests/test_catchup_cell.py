@@ -1,11 +1,12 @@
-"""The catch-up cell rehearsed on the CPU at 8x8: a tiny history (12 stored
-heights, 8 swept, caches of 4) ADDED to the tiny tree as files and manifest
-entries, the way conftest.py adds the other two tiny cells; and the real
-cell's files held against each other."""
+"""The catch-up cell rehearsed on the CPU at 8x8: a tiny history (14 stored
+heights, 10 swept in five ranges of 2, caches of 4) ADDED to the tiny tree
+as files and manifest entries, the way conftest.py adds the other two tiny
+cells; and the real cell's files held against each other."""
 
 import json
 import os
 
+import numpy as np
 import pytest
 
 import run
@@ -25,21 +26,21 @@ def catchup_tree(tiny_tree):
     root = os.path.dirname(tiny_tree)
     config = cells.read_json(os.path.join(tiny_tree, "configs",
                                           "tiny-k8.json"))
-    config["stored_heights"] = 12
+    config["stored_heights"] = 14
     _write(os.path.join(tiny_tree, "configs", "tiny-k8-history.json"), config)
     mix = cells.read_json(os.path.join(BENCH_DIR, "traffic",
                                        "serve-catchup.json"))
-    mix.update(setup_mix="pfb-tiny", setup_blocks=12, swept_heights=8,
-               clients=5, sweepers=3, followers=2, cells_per_round=4,
-               sweeper_offsets=[0, 3, 6], follower_offsets=[1, 5],
-               follower_namespace_ranks=[0, 2], absent_every=2,
-               keep_every=2, warm_heights=4)
+    mix.update(setup_mix="pfb-tiny", setup_blocks=14, swept_heights=10,
+               range_heights=2, clients=5, sweepers=3, followers=2,
+               cells_per_round=4, sweeper_offsets=[0, 2, 6],
+               follower_offsets=[4, 8], follower_namespace_ranks=[0, 2],
+               absent_every=2, keep_every=2, warm_heights=4)
     _write(os.path.join(tiny_tree, "traffic", "catchup-tiny.json"), mix)
-    # 4 swept heights, all 4 warmed: both LRUs (4 entries) hold the whole
-    # range before the window, so no request of the window misses
+    # 4 swept heights, every client on all 4, all 4 warmed: both LRUs (4
+    # entries) hold the whole range before the window, so no request misses
     _write(os.path.join(tiny_tree, "traffic", "catchup-resident.json"),
-           {**mix, "swept_heights": 4, "sweeper_offsets": [0, 1, 2],
-            "follower_offsets": [1, 3]})
+           {**mix, "swept_heights": 4, "range_heights": 4,
+            "sweeper_offsets": [0, 1, 2], "follower_offsets": [1, 3]})
     with open(os.path.join(root, "BENCHMARK.json"), encoding="utf-8") as f:
         manifest = json.load(f)
     if not any(c["name"] == "tiny-k8-history" for c in manifest["configs"]):
@@ -73,6 +74,9 @@ def test_catchup_cell_runs_with_no_edit_and_every_window_re_extends(
     assert window["counters"]["das.entry_evictions"] > 0
     assert window["counters"]["edscache.evictions"] > 0
     assert window["requests"] == out["attempted"]
+    # ranges that do not overlap: no reader waited on another's build
+    assert window["counters"].get("das.entry_coalesced", 0) == 0
+    assert min(window["requests_by_client"]) > 0
     assert "bench.window_compiles" in window["counters"]
 
 
@@ -159,10 +163,14 @@ def test_stored_heights_the_set_up_and_the_swept_range_agree():
     assert mix["swept_heights"] == \
         config["stored_heights"] - config["served_heights"] == 24
     assert (mix["clients"], mix["sweepers"], mix["followers"]) == (8, 6, 2)
-    assert mix["sweeper_offsets"] == [0, 4, 8, 12, 16, 20]
-    assert mix["follower_offsets"] == [2, 14]
+    # eight ranges of 3 tile the 24 swept heights: no height has two readers
+    assert mix["range_heights"] * mix["clients"] == mix["swept_heights"]
+    assert mix["sweeper_offsets"] == [0, 3, 6, 12, 15, 18]
+    assert mix["follower_offsets"] == [9, 21]
+    # one client's kept replies are all at one of its heights
+    assert mix["keep_every"] % mix["range_heights"] == 0
     assert (mix["cells_per_round"], mix["absent_every"],
-            mix["keep_every"], mix["warm_heights"]) == (16, 10, 8, 5)
+            mix["keep_every"], mix["warm_heights"]) == (16, 10, 9, 5)
     assert mix["device_dispatch_counter"] == "da.extend_runs"
     assert mix["control_breaks"] == ["stale_sample", "partial_read"]
     hard_cap = cells.load_cell("k128-pfb-full").config
@@ -181,22 +189,48 @@ def test_stored_heights_the_set_up_and_the_swept_range_agree():
         "evictions_per_request", "catchup_extend_roofline",
         "sample_batch_ms", "ns_read_ms", "device_idle.serve",
         "window_compiles.serve"}
+    # no reader waits on another's build in this mix: the wait is read per
+    # BUILD (a mean over no wait at all would be left out of the line)
+    spec = {m.name: m for m in cell.per_layer}["coalesced_wait_ms"].spec
+    assert spec["spans"] == ["das.entry_build", "das.entry_wait"]
+    assert spec["minus"] == ["das.entry_build"] and "per_unit" not in spec
 
 
-def test_the_walks_are_a_function_of_the_seed_and_never_share_a_start(
+def _walks(gen, plans: list[dict], swept: int) -> list[set[int]]:
+    """The heights each client asks, over a walk much longer than a range."""
+    return [{gen.height_index(plan, done, swept)
+             for done in range(3 * swept)} for plan in plans]
+
+
+def test_the_real_mix_gives_no_height_two_readers_whatever_the_seed():
+    cell = cells.load_cell(CELL)
+    mix, gen = cell.mix, cell.generator()
+    rotations = {int(np.random.default_rng([s, 21]).integers(
+        0, mix["swept_heights"])) for s in range(40)}
+    assert len(rotations) > 10
+    for rotation in rotations:
+        walks = _walks(gen, [
+            {"start": start + rotation, "span": mix["range_heights"]}
+            for start in mix["sweeper_offsets"] + mix["follower_offsets"]],
+            mix["swept_heights"])
+        assert sorted(h for w in walks for h in w) == list(range(24))
+
+
+def test_the_walks_are_a_function_of_the_seed_and_never_share_a_height(
         catchup_tree):
     cell = cells.load_cell("tiny-catchup", bench_dir=catchup_tree)
     gen = cell.generator()
     a, b, c = (gen.Traffic(cell, s) for s in (2**31 + 11, 2**31 + 11, 12))
     assert a.schedules == b.schedules
     for t in (a, c):
-        starts = [p["start"] % cell.mix["swept_heights"]
-                  for p in t.schedules]
-        assert len(set(starts)) == len(starts) == cell.mix["clients"]
+        walks = _walks(gen, t.schedules, cell.mix["swept_heights"])
+        assert all(len(w) == cell.mix["range_heights"] for w in walks)
+        asked = [h for w in walks for h in w]
+        assert len(set(asked)) == len(asked) == cell.mix["swept_heights"]
         assert [p["kind"] for p in t.schedules] == \
             ["light"] * 3 + ["read"] * 2
         assert [p["namespace"] for p in t.schedules[3:]] == \
             [t.chain.namespaces[0], t.chain.namespaces[2]]
-    with pytest.raises(cells.CellError, match="stores 12"):
+    with pytest.raises(cells.CellError, match="stores 14"):
         cell.mix["setup_blocks"] = 11
         gen.Traffic(cell, 1)

@@ -82,11 +82,12 @@ def test_the_manifest_lists_the_nineteen_with_their_cells():
     with open(os.path.join(REPO_DIR, "BENCHMARK.json"),
               encoding="utf-8") as f:
         per_layer = {m["name"]: m for m in json.load(f)["per_layer"]}
+    # `in`: later PRs append their cells to these lists
     for name in PRODUCE_METRICS:
-        assert per_layer[name]["workloads"] == ["k64-pfb-full",
-                                                "k128-pfb-full"]
+        assert "k64-pfb-full" in per_layer[name]["workloads"]
+        assert "k128-pfb-full" in per_layer[name]["workloads"]
     for name in SERVE_METRICS:
-        assert per_layer[name]["workloads"] == ["k64-serve-tip"]
+        assert "k64-serve-tip" in per_layer[name]["workloads"]
     for name in PRODUCE_METRICS + SERVE_METRICS:
         entry = per_layer[name]
         assert entry["better"] == "lower"

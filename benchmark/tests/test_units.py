@@ -351,6 +351,45 @@ def test_overlapping_spans_of_many_threads_and_empty_traces():
     assert xplane.reduce_events({**ev, "device_ops": {}}) is None
 
 
+def test_four_planes_average_over_the_chips():
+    """A four-chip host: one plane a chip, unequal busy time, one sharded
+    program on all four. Seconds are the mean over the chips, so the idle
+    share stays inside 0..100 whatever one chip does."""
+    import run
+    from reducers import xplane
+
+    ops = {0: [[0, 400]], 1: [[100, 200]], 2: [[-50, 1100]],   # all of it
+           3: [[0, 100], [50, 100], [900, 300]]}               # 150 + 100
+    ev = {"device_ops": {f"/device:TPU:{n}": o for n, o in ops.items()},
+          "device_programs": {
+              f"/device:TPU:{n}": [["jit_sharded(7)", 100, 100 + 40 * n]]
+              for n in range(4)},
+          "host_spans": [["bench.window", 0, 1000], ["bench.a", 0, 600]]}
+    ev["device_programs"]["/device:TPU:1"].append(["jit_alone(8)", 700, 80])
+    got = xplane.reduce_events(ev)
+    assert got["window_s"] == pytest.approx(1000e-9)
+    assert got["busy_s"] == pytest.approx((400 + 200 + 1000 + 250) / 4 * 1e-9)
+    sharded = (100 + 140 + 180 + 220) / 4 * 1e-9
+    assert dict(got["device_ops"]) == pytest.approx(
+        {"jit_sharded(7)": sharded, "jit_alone(8)": 20e-9})
+    assert got["programs_in_spans"] == {"a": {"jit_sharded(7)":
+                                              pytest.approx(sharded)}}
+    gaps = dict(got["idle_gaps"])
+    # idle inside [0, 600): 200 + 400 + 0 + 450; outside: 400 + 400 + 0 + 300
+    assert gaps["a"] == pytest.approx(1050 / 4 * 1e-9)
+    assert gaps["between_spans"] == pytest.approx(1100 / 4 * 1e-9)
+    assert sum(gaps.values()) == pytest.approx(
+        got["window_s"] - got["busy_s"])
+    idle = cells.load_module("reducers", "device_idle")
+    for one_chip_does in ([[0, 1000]], []):
+        ev["device_ops"]["/device:TPU:3"] = one_chip_does
+        reading = run.Reading(spans={}, counters={}, units={},
+                              trace=xplane.reduce_events(ev), peaks=PEAKS,
+                              bench_dir=BENCH_DIR)
+        assert 0.0 <= idle.read({}, reading) <= 100.0
+    assert idle.read({}, reading) == pytest.approx(100 * (1 - 1600 / 4000))
+
+
 def test_a_reader_with_nothing_to_read_returns_nothing():
     import run
 
