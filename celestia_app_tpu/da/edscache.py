@@ -279,7 +279,11 @@ class DeviceEntry(EdsCacheEntry):
     - ``warm()`` runs the row+col NMT *level* passes on device, over
       the resident array (``jnp.swapaxes`` on the chip for the column
       orientation), and keeps the results there — the prover-warm stage
-      never sends the square up again.
+      never sends the square up again. A square whose rows are split
+      over a mesh is read where it lies: the same passes inside a
+      ``shard_map`` on that mesh (``_level_pass``), the level stacks
+      left split by tree — a plain jit over it is refused by the TPU
+      compiler, which partitions no Pallas kernel.
     - ``.eds`` / the provers materialize host bytes lazily, only when a
       proof or serve path actually needs them; every device->host array
       fetch counts ``edscache.host_crossings``.
@@ -384,21 +388,49 @@ class DeviceEntry(EdsCacheEntry):
             return jax.block_until_ready(
                 proof_device._jitted_row_levels(self.k)(eds_dev))
 
+    def _run_levels_sharded(self, mesh, axis: str, col: bool):
+        """The same pass over a square whose rows are split over a mesh:
+        inside a shard_map on that mesh (the chip's compiler partitions
+        no Pallas kernel outside one), each chip hashing the trees it
+        holds, the level stacks left split by tree where the square is."""
+        import jax
+
+        from celestia_app_tpu.da import proof_device
+
+        with obs.span("mesh.levels.run", k=self.k, chips=mesh.shape[axis],
+                      col=col):
+            levels = jax.block_until_ready(
+                proof_device._jitted_sharded_levels(mesh, axis, self.k, col)(
+                    self._eds_dev))
+        telemetry.incr("mesh.sharded_level_passes")
+        return levels
+
+    def _level_pass(self, col: bool):
+        """One orientation's level pass over whatever this entry holds."""
+        from celestia_app_tpu.da import proof_device
+
+        placed = proof_device.rows_sharded_over(self._eds_dev)
+        if placed is not None:
+            return self._run_levels_sharded(*placed, col)
+        if col:
+            import jax.numpy as jnp
+
+            return self._run_levels(
+                jnp.swapaxes(jnp.asarray(self._eds_dev), 0, 1))
+        return self._run_levels(self._eds_dev)
+
     def _device_row_levels(self):
         # build-once serialization (see _device_levels)
         with self._levels_lock:  # lint: disable=blocking-under-lock
             if self._levels_dev is None:
-                self._levels_dev = self._run_levels(self._eds_dev)
+                self._levels_dev = self._level_pass(col=False)
             return self._levels_dev
 
     def _device_col_levels(self):
-        import jax.numpy as jnp
-
         # build-once serialization (see _device_levels)
         with self._col_levels_lock:  # lint: disable=blocking-under-lock
             if self._col_levels_dev is None:
-                self._col_levels_dev = self._run_levels(
-                    jnp.swapaxes(jnp.asarray(self._eds_dev), 0, 1))
+                self._col_levels_dev = self._level_pass(col=True)
             return self._col_levels_dev
 
     def _host_levels(self, col: bool):

@@ -23,6 +23,9 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from celestia_app_tpu import appconsts
 from celestia_app_tpu.da import eds as eds_mod
@@ -47,6 +50,63 @@ def _jitted_row_levels(k: int):
         return nmt.nmt_levels(leaf_ns, eds)
 
     return jax.jit(prover_levels)
+
+
+def rows_sharded_over(eds):
+    """(mesh, axis name) when a device square's ROWS are split over one
+    named axis of a mesh (what the sharded pipeline leaves resident:
+    parallel/sharded_eds), else None — an array one chip holds, which a
+    plain jit can read."""
+    sharding = getattr(eds, "sharding", None)
+    if not isinstance(sharding, NamedSharding) or not sharding.spec:
+        return None
+    axis = sharding.spec[0]
+    if not isinstance(axis, str) or sharding.mesh.shape[axis] < 2 \
+            or any(a is not None for a in sharding.spec[1:]):
+        return None
+    return sharding.mesh, axis
+
+
+@functools.lru_cache(maxsize=None)
+def _jitted_sharded_levels(mesh, axis: str, k: int, col: bool):
+    """Compiled: the level pass of `_jitted_row_levels` over a (2k, 2k,
+    512) EDS whose rows are split over `axis` of `mesh`, inside a
+    shard_map on that mesh — the TPU compiler cannot partition the Pallas
+    SHA-256 kernel that hashes the leaves of a plain jit ("Mosaic kernels
+    cannot be automatically partitioned"). Each chip hashes the trees of
+    the rows it holds; for the column orientation (`col`) one all-to-all
+    over `axis` hands it whole columns first, which it reads column-major.
+    Every level array stays split over `axis` by tree, in the global order
+    a gather to the host restores: bit-identical to the one-chip pass."""
+    n = mesh.shape[axis]
+    if (2 * k) % n:
+        raise ValueError(f"{n} chips do not divide {2 * k} axes")
+    per_chip = 2 * k // n
+
+    def levels_local(eds_local: jax.Array):
+        # (2k/n, 2k, 512): this chip's rows of the square
+        if col:
+            mine = lax.all_to_all(eds_local, axis, split_axis=1,
+                                  concat_axis=0, tiled=True)
+            eds_local = jnp.swapaxes(mine, 0, 1)  # (2k/n cols, 2k, 512)
+        first = lax.axis_index(axis) * per_chip
+        leaf_ns = nmt.eds_axis_leaf_ns(
+            eds_local, first + jnp.arange(per_chip), k)
+        return nmt.nmt_levels(leaf_ns, eds_local)
+
+    by_tree = P(axis, None, None)
+    # check_vma=False as in parallel/sharded_eds: the SHA-256 loop mixes
+    # a replicated initial state with device-varying data
+    sharded = jax.shard_map(levels_local, mesh=mesh, in_specs=by_tree,
+                            out_specs=by_tree, check_vma=False)
+
+    # named for the trace: jit_mesh_prover_levels(...), apart from the
+    # one-chip jit_prover_levels
+    def mesh_prover_levels(eds: jax.Array):
+        return sharded(eds)
+
+    return jax.jit(mesh_prover_levels,
+                   in_shardings=NamedSharding(mesh, by_tree))
 
 
 class BlockProver:

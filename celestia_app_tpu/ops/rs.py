@@ -62,32 +62,70 @@ def _gf_mix(bit_mat: jax.Array, x_bits: jax.Array) -> jax.Array:
     return (out & 1).astype(jnp.int8)
 
 
-def bytes_to_bits16(x: jax.Array) -> jax.Array:
-    """(..., n, D) uint8 -> (..., 16n, D//2) int8 bits of LE uint16 symbols.
+# Which bytes of a share make a 16-bit symbol. The chain's codec above 256
+# shards an axis is rsmt2d NewLeoRSCodec -> klauspost/reedsolomon
+# WithLeopardGF, whose GF(2^16) code works on 64-byte blocks: byte i is the
+# low and byte i + 32 the high half of symbol i of its block
+# (klauspost/reedsolomon leopard.go refMulAdd: `loA := y[:32]; hiA :=
+# y[32:64]`; catid/leopard LeopardFF16.cpp: `lo = x[i]`, `hi = x[i + 32]`).
+# Both sources are quoted from memory: no copy of either and no vector made
+# by them is on this machine (docs/DESIGN.md "Reed-Solomon"). The two pairs
+# below — device bits and host symbols — are the only places that know it.
+SYMBOL_BLOCK = 64
+_HALF = SYMBOL_BLOCK // 2
 
-    Symbol p of a share is bytes (2p, 2p+1) little-endian; symbol-bit b is
-    bit b%8 of byte 2p + b//8. Row 16l+b = bit b of shard l's symbols."""
-    n, d = x.shape[-2], x.shape[-1]
-    sym = x.reshape(*x.shape[:-2], n, d // 2, 2)
+
+def _blocks(d: int) -> int:
+    if d % SYMBOL_BLOCK:
+        raise ValueError(
+            f"a shard of {d} bytes is not whole {SYMBOL_BLOCK}-byte blocks")
+    return d // SYMBOL_BLOCK
+
+
+def bytes_to_bits16(x: jax.Array) -> jax.Array:
+    """(..., n, D) uint8 -> (..., 16n, D//2) int8 bits of uint16 symbols.
+
+    Symbol p = 32b + i of a shard is bytes 64b + i (low) and 64b + 32 + i
+    (high); symbol-bit j is bit j%8 of the low (j < 8) or high byte. Row
+    16l + j = bit j of shard l's symbols."""
+    lead, n, d = x.shape[:-2], x.shape[-2], x.shape[-1]
+    blk = x.reshape(*lead, n, _blocks(d), 2, _HALF)
     shifts = jnp.arange(8, dtype=jnp.uint8)
-    # (..., n, d/2, byte(2), bit(8)): symbol-bit order is byte0 bits 0..7
-    # then byte1 bits 0..7, so flattening (byte, bit) is already LE order
-    bits = (sym[..., None] >> shifts) & 1
-    bits = bits.reshape(*x.shape[:-2], n, d // 2, 16)
-    bits = jnp.swapaxes(bits, -2, -1)  # (..., n, 16, d/2)
-    return bits.reshape(*x.shape[:-2], 16 * n, d // 2).astype(jnp.int8)
+    bits = (blk[..., None] >> shifts) & 1  # (..., n, block, half, i, bit)
+    at = len(lead)
+    bits = bits.transpose(*range(at), at, at + 2, at + 4, at + 1, at + 3)
+    # (..., n, half, bit, block, i): (half, bit) is the symbol-bit, low
+    # byte first, and (block, i) the symbol's place in the shard
+    return bits.reshape(*lead, 16 * n, d // 2).astype(jnp.int8)
 
 
 def bits_to_bytes16(b: jax.Array) -> jax.Array:
     """Inverse of bytes_to_bits16: (..., 16n, D//2) -> (..., n, D) uint8."""
-    n = b.shape[-2] // 16
-    half = b.shape[-1]
-    bits = b.reshape(*b.shape[:-2], n, 16, half).astype(jnp.int32)
-    bits = jnp.swapaxes(bits, -2, -1)  # (..., n, half, 16)
-    bits = bits.reshape(*b.shape[:-2], n, half, 2, 8)
-    weights = (1 << jnp.arange(8, dtype=jnp.int32))
-    by = jnp.sum(bits * weights, axis=-1).astype(jnp.uint8)  # (..., n, half, 2)
-    return by.reshape(*b.shape[:-2], n, 2 * half)
+    lead, n, half_d = b.shape[:-2], b.shape[-2] // 16, b.shape[-1]
+    n_blocks = _blocks(2 * half_d)
+    bits = b.reshape(*lead, n, 2, 8, n_blocks, _HALF).astype(jnp.int32)
+    weights = (1 << jnp.arange(8, dtype=jnp.int32))[:, None, None]
+    by = jnp.sum(bits * weights, axis=-3).astype(jnp.uint8)
+    # (..., n, half, block, i) -> (..., n, block, half, i)
+    return jnp.swapaxes(by, -3, -2).reshape(*lead, n, 2 * half_d)
+
+
+def symbols_of_bytes(shards: np.ndarray) -> np.ndarray:
+    """Host twin of bytes_to_bits16: (..., D) uint8 -> (..., D//2) uint16."""
+    shards = np.asarray(shards)
+    lead, d = shards.shape[:-1], shards.shape[-1]
+    blk = shards.reshape(*lead, _blocks(d), 2, _HALF)
+    sym = blk[..., 0, :] | (blk[..., 1, :].astype(np.uint16) << 8)
+    return sym.reshape(*lead, d // 2)
+
+
+def bytes_of_symbols(symbols: np.ndarray) -> np.ndarray:
+    """Inverse of symbols_of_bytes: (..., D//2) uint16 -> (..., D) uint8."""
+    symbols = np.asarray(symbols)
+    lead, half_d = symbols.shape[:-1], symbols.shape[-1]
+    blk = symbols.reshape(*lead, _blocks(2 * half_d), 1, _HALF)
+    halves = np.concatenate([blk & 0xFF, blk >> 8], axis=-2)
+    return halves.astype(np.uint8).reshape(*lead, 2 * half_d)
 
 
 def _codec(k: int):
@@ -138,8 +176,7 @@ def _encode_axis_np(block: np.ndarray) -> np.ndarray:
     """(k, D) data shards -> (k, D) parity, byte domain, codec by k."""
     k = block.shape[0]
     if leopard.uses_gf16(k):
-        u16 = np.ascontiguousarray(block).view("<u2").reshape(k, -1)
-        return leopard.encode16(u16).view(np.uint8).reshape(k, -1)
+        return bytes_of_symbols(leopard.encode16(symbols_of_bytes(block)))
     return leopard.encode(block)
 
 
@@ -338,9 +375,8 @@ def repair_axis(symbols: np.ndarray, present: list[int]) -> np.ndarray:
     if len(present) < k:
         raise ValueError(f"need at least {k} of {two_k} symbols, got {len(present)}")
     if leopard.uses_gf16(k):
-        sym16 = np.ascontiguousarray(symbols).view("<u2").reshape(2 * k, -1)
-        out = leopard_decode.decode16(sym16, list(present))
-        return out.view(np.uint8).reshape(2 * k, -1)
+        return bytes_of_symbols(leopard_decode.decode16(
+            symbols_of_bytes(symbols), list(present)))
     return leopard_decode.decode8(
         np.ascontiguousarray(symbols), list(present)
     )
@@ -355,11 +391,9 @@ def repair_axis_matrix(symbols: np.ndarray, present: list[int]) -> np.ndarray:
     use = tuple(sorted(present)[:k])
     if leopard.uses_gf16(k):
         m = leopard.decode_matrix16(k, use)
-        sym16 = np.ascontiguousarray(symbols).view("<u2").reshape(2 * k, -1)
-        data16 = leopard.matmul16(m, sym16[list(use)])
+        data16 = leopard.matmul16(m, symbols_of_bytes(symbols)[list(use)])
         parity16 = leopard.encode16(data16)
-        out = np.concatenate([data16, parity16], axis=0)
-        return out.view(np.uint8).reshape(2 * k, -1)
+        return bytes_of_symbols(np.concatenate([data16, parity16], axis=0))
     m = leopard.decode_matrix(k, use)
     data = leopard.matmul(m, symbols[list(use)])
     parity = leopard.matmul(leopard.encode_matrix(k), data)

@@ -50,6 +50,7 @@ import os
 
 import numpy as np
 
+from celestia_app_tpu import obs
 from celestia_app_tpu.obs import xfer
 from celestia_app_tpu.utils import telemetry
 
@@ -203,13 +204,19 @@ def _run_sharded(mesh, ods_batch: np.ndarray, k: int):
     (eds, row_roots, col_roots, data_roots) with the EDS left sharded.
     The upload is explicit (the pipeline's own input sharding) so the
     transfer ledger counts it instead of jit doing it silently."""
+    import jax
+
     from celestia_app_tpu.parallel import sharded_eds
 
     run = sharded_eds.jitted_sharded_pipeline(mesh, k)
-    return run(xfer.to_device(
+    ods_dev = xfer.to_device(
         ods_batch, "mesh.sharded_dispatch",
         placement=sharded_eds.input_sharding(mesh),
-    ))
+    )
+    # the mesh's da.extend.run: dispatch -> all four outputs ready
+    with obs.span("mesh.extend.run", k=k, blocks=int(ods_batch.shape[0]),
+                  chips=mesh.size):
+        return jax.block_until_ready(run(ods_dev))
 
 
 def compute_entry_mesh(ods: np.ndarray):

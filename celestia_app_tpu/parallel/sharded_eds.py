@@ -221,7 +221,13 @@ def input_sharding(mesh: Mesh) -> NamedSharding:
 @functools.lru_cache(maxsize=None)
 def _jitted(mesh: Mesh, k: int):
     fn = sharded_pipeline_fn(mesh, k)
-    return jax.jit(fn, in_shardings=input_sharding(mesh))
+
+    # named for the trace: jit_mesh_pipeline(...), apart from the one-chip
+    # pipeline's and the namespace search's jit_run
+    def mesh_pipeline(ods_batch: jax.Array):
+        return fn(ods_batch)
+
+    return jax.jit(mesh_pipeline, in_shardings=input_sharding(mesh))
 
 
 def jitted_sharded_pipeline(mesh: Mesh, k: int):

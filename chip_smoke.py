@@ -22,7 +22,9 @@ call real:
 `--chips 4` runs ONLY the sharded path and what it is compared with: a
 seeded k=256 ODS through compute_entry(ods, "mesh") on four chips, the
 one-chip program on chip 0 and the host engine; the three data roots
-equal and the resident entry spread over four devices.
+equal, the resident entry spread over four devices, and 16 cells proved
+from the mesh entry's sharded level passes (row and column axis) equal
+to the host engine's proofs.
 
 Any failed check or exception ends the run with a traceback and a
 non-zero exit; no phase is skipped over. The LAST stdout line of a
@@ -395,6 +397,7 @@ def run_one_chip(args, sizes, counters: DeviceCounters) -> None:
 
 def run_four_chips(args, sizes, counters: DeviceCounters) -> None:
     import jax
+    import numpy as np
 
     from celestia_app_tpu.da import edscache
     from celestia_app_tpu.da import eds as eds_mod
@@ -441,11 +444,33 @@ def run_four_chips(args, sizes, counters: DeviceCounters) -> None:
         ph.checked.update(k=k, engine="host",
                           data_root=ref.data_root.hex())
 
+    with Phase("mesh_samples") as ph, counters.section():
+        # the level passes over the square where it lies, sharded: on the
+        # chips a plain jit of them is refused (a Mosaic kernel outside a
+        # shard_map), which no CPU run can show
+        entry.warm()
+        ph.first_result()
+        width = 2 * k
+        cells = [(int(r), int(c)) for r, c in np.random.default_rng(
+            args.seed).integers(0, width, size=(16, 2))]
+        rows_m, rows_h = entry.get_prover(), ref.get_prover("host")
+        cols_m, cols_h = entry.get_col_prover(), ref.get_col_prover("host")
+        for r, c in cells:
+            check(rows_m.prove_cell(r, c) == rows_h.prove_cell(r, c),
+                  f"k={k}: mesh and host row proofs of ({r}, {c}) differ")
+            check(cols_m.prove_cell(c, r) == cols_h.prove_cell(c, r),
+                  f"k={k}: mesh and host column proofs of ({r}, {c}) differ")
+        ph.checked.update(
+            k=k, samples=len(cells), axes=["row", "col"],
+            level_devices=len(entry._levels_dev[0][0].sharding.device_set))
+
     with Phase("counters") as ph:
         fired = {n: counters.get(n) for n in FALLBACK_COUNTERS}
         check(not any(fired.values()), f"a fallback fired: {fired}")
         check(counters.get("da.extend_runs") == 1,
               "the mesh phase did not run exactly one extend")
+        check(counters.get("mesh.sharded_level_passes") == 2,
+              "the two level passes did not run sharded")
         ph.checked.update(fallbacks=fired,
                           da_extend_runs=counters.get("da.extend_runs"))
 

@@ -30,8 +30,8 @@ def _run(*args, devices: int | None = None, **env_extra):
 @pytest.mark.parametrize("chips,devices,phases", [
     (1, 1, ["device", "validator", "host_ref", "hard_cap", "counters",
             "total"]),
-    (4, 4, ["device", "mesh", "one_chip_ref", "host_ref", "counters",
-            "total"]),
+    (4, 4, ["device", "mesh", "one_chip_ref", "host_ref", "mesh_samples",
+            "counters", "total"]),
 ])
 def test_rehearsal_walks_every_phase_and_never_passes(chips, devices,
                                                       phases):
@@ -53,6 +53,8 @@ def test_rehearsal_walks_every_phase_and_never_passes(chips, devices,
         assert by_phase["mesh"]["data_root"] \
             == by_phase["one_chip_ref"]["data_root"] \
             == by_phase["host_ref"]["data_root"]
+        assert by_phase["mesh_samples"]["samples"] == 16
+        assert by_phase["mesh_samples"]["level_devices"] == 4
 
 
 @pytest.mark.parametrize("args,devices", [

@@ -1,9 +1,15 @@
 """GF(2^16) leopard16: the k>=256 codec (BASELINE config 5 scale-out)."""
 
+import os
+import sys
+
 import numpy as np
 import pytest
 
 from celestia_app_tpu.ops import gf256, leopard, rs
+
+sys.path.insert(0, os.path.dirname(__file__))
+import gf16_plain  # noqa: E402
 
 
 def _gmul16(a, b):
@@ -77,31 +83,125 @@ def test_bit_matrix16_equals_symbol_domain():
 
 @pytest.mark.backend
 def test_device_bits16_pack_roundtrip_and_extend():
-    """The LE-symbol bit pack/unpack and the device extension using the
-    16-bit matrix agree with the host FFT encode (small payload, forced
-    16-bit formulation via direct kernel plumbing at test scale)."""
+    """The device bit pack/unpack and one row-extension pass with the
+    16-bit matrix agree with the plain encode under the published 64-byte
+    block (small payload, forced 16-bit formulation via direct kernel
+    plumbing at test scale)."""
     import jax.numpy as jnp
 
     rng = np.random.default_rng(4)
-    x = jnp.asarray(rng.integers(0, 256, (3, 4, 16), dtype=np.uint8))
+    x = jnp.asarray(rng.integers(0, 256, (3, 4, 128), dtype=np.uint8))
     back = rs.bits_to_bytes16(rs.bytes_to_bits16(x))
     assert np.array_equal(np.asarray(back), np.asarray(x))
 
-    # one row-extension pass with the 16-bit matrix at k=8 vs host encode16
-    k, d = 8, 32
+    k, d = 8, 64
     block = rng.integers(0, 256, (k, d), dtype=np.uint8)
     bits = rs.bytes_to_bits16(jnp.asarray(block)[None])  # (1, 16k, d/2)
     mixed = rs._gf_mix(jnp.asarray(leopard.bit_matrix16(k)), bits)
     got = np.asarray(rs.bits_to_bytes16(mixed))[0]
-    want_u16 = leopard.encode16(block.view("<u2").reshape(k, -1))
-    assert np.array_equal(got, want_u16.view(np.uint8).reshape(k, d))
+    assert np.array_equal(got, gf16_plain.parity(block))
+    assert not np.array_equal(got, gf16_plain.parity_adjacent_pairs(block))
+
+
+# -- which bytes of a share make a 16-bit symbol ------------------------------
+
+
+def test_host_pair_is_the_published_64_byte_block():
+    """Symbol p = 32b + i of a shard: low byte 64b + i, high byte
+    64b + 32 + i — stated here on the bytes 1, 3, 5, ... so that every
+    position is told apart."""
+    shard = (np.arange(128, dtype=np.uint8) * 2 + 1)
+    byte = shard.tolist()
+    want = ([byte[i] | byte[i + 32] << 8 for i in range(32)]
+            + [byte[64 + i] | byte[96 + i] << 8 for i in range(32)])
+    sym = rs.symbols_of_bytes(shard[None])
+    assert sym.dtype == np.uint16 and sym.shape == (1, 64)
+    assert sym[0].tolist() == want == gf16_plain.symbols(shard).tolist()
+    assert rs.bytes_of_symbols(sym).dtype == np.uint8
+    assert np.array_equal(rs.bytes_of_symbols(sym)[0], shard)
+    assert np.array_equal(gf16_plain.shard_bytes(sym[0]), shard)
+    # any leading shape, a share's 512 bytes
+    x = np.random.default_rng(36).integers(0, 256, (3, 5, 512),
+                                           dtype=np.uint8)
+    assert np.array_equal(rs.bytes_of_symbols(rs.symbols_of_bytes(x)), x)
+    assert rs.symbols_of_bytes(x).shape == (3, 5, 256)
+    # and never adjacent little-endian pairs
+    assert not np.array_equal(rs.symbols_of_bytes(x),
+                              np.ascontiguousarray(x).view("<u2"))
+
+
+@pytest.mark.backend
+def test_device_packers_equal_the_host_pair():
+    """Row 16l + j of the bit array = bit j of shard l's symbols, the
+    symbols being the host pair's: one mapping, two forms."""
+    import jax.numpy as jnp
+
+    x = np.random.default_rng(37).integers(0, 256, (2, 3, 512),
+                                           dtype=np.uint8)
+    bits = np.asarray(rs.bytes_to_bits16(jnp.asarray(x)))
+    assert bits.shape == (2, 48, 256) and bits.dtype == np.int8
+    sym = rs.symbols_of_bytes(x)                               # (2, 3, 256)
+    want = (sym[:, :, None, :] >> np.arange(16)[None, None, :, None]) & 1
+    assert np.array_equal(bits, want.reshape(2, 48, 256))
+    back = np.asarray(rs.bits_to_bytes16(jnp.asarray(bits)))
+    assert back.dtype == np.uint8 and np.array_equal(back, x)
+
+
+@pytest.mark.parametrize("fn,arg", [
+    ("symbols_of_bytes", np.zeros((2, 96), np.uint8)),
+    ("bytes_of_symbols", np.zeros((2, 48), np.uint16)),
+    ("bytes_to_bits16", np.zeros((1, 2, 96), np.uint8)),
+    ("bits_to_bytes16", np.zeros((1, 32, 48), np.int8)),
+])
+def test_a_shard_that_is_not_whole_blocks_is_refused(fn, arg):
+    with pytest.raises(ValueError, match="64-byte blocks"):
+        getattr(rs, fn)(arg)
+
+
+@pytest.fixture(scope="module")
+def plain_da():
+    """The benchmark's plain reference (numpy + hashlib; its own field,
+    skews and FFT, nothing of the program imported)."""
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    sys.path.insert(0, bench)
+    try:
+        from reference import plain_da as da
+    finally:
+        sys.path.remove(bench)
+    return da
+
+
+@pytest.mark.backend
+@pytest.mark.parametrize("k", [256, 512])
+def test_program_parity_is_the_published_mapping_byte_for_byte(k, plain_da):
+    """512 and 1,024 shards an axis — real k = 256 and 512, what
+    `extend_square_fn` runs on every axis (`rs._codec`): the device route
+    bytes -> bits -> bit matrix -> bytes, the host route and the plain
+    in-test encode give one answer, the plain reference's, and not the
+    adjacent-pairs bytes the program gave before PR 36."""
+    import jax.numpy as jnp
+
+    axes = np.random.default_rng([36, k]).integers(
+        0, 256, (2, k, 128), dtype=np.uint8)
+    matrix, to_bits, from_bits = rs._codec(k)
+    device = np.asarray(from_bits(rs._gf_mix(
+        jnp.asarray(matrix), to_bits(jnp.asarray(axes)))))
+    for axis, got in zip(axes, device):
+        assert np.array_equal(got, gf16_plain.parity(axis))
+        assert np.array_equal(got, rs._encode_axis_np(axis))
+        assert np.array_equal(got, plain_da.bytes_of_symbols(
+            plain_da.rs_encode(plain_da.symbols_of_bytes(axis))))
+        assert not np.array_equal(got,
+                                  gf16_plain.parity_adjacent_pairs(axis))
 
 
 def test_repair_axis_gf16():
     rng = np.random.default_rng(5)
     k = 256
-    data = rng.integers(0, 256, (k, 8), dtype=np.uint8)
+    data = rng.integers(0, 256, (k, 64), dtype=np.uint8)
     parity = rs._encode_axis_np(data)
+    assert np.array_equal(parity, gf16_plain.parity(data))
     row = np.concatenate([data, parity], axis=0)
     present = sorted(rng.choice(2 * k, k, replace=False).tolist())
     corrupted = row.copy()
@@ -110,6 +210,7 @@ def test_repair_axis_gf16():
             corrupted[i] = 0
     rec = rs.repair_axis(corrupted, present)
     assert np.array_equal(rec, row)
+    assert np.array_equal(rs.repair_axis_matrix(corrupted, present), row)
 
 
 @pytest.mark.slow

@@ -11,6 +11,7 @@ proof/serve path actually needs host bytes (pinned by the
 commits the exact block/app hashes of per-block production.
 """
 
+import base64
 import os
 
 import numpy as np
@@ -306,6 +307,101 @@ def test_mesh_engine_chain_matches_host_chain():
     assert core_m.sample(1, 0, 0) == core_h.sample(1, 0, 0)
     assert core_m.sample(1, 1, 1, axis="col") == \
         core_h.sample(1, 1, 1, axis="col")
+
+
+def test_mesh_height_serves_a_whole_light_round(tmp_path):
+    """The benchmark's loop at 8x8 on the forced 8-device mesh: one PFB
+    block through broadcast_txs -> produce_block on engine="mesh", then a
+    light node's round of 16 cells at the new height, racing the prover
+    warmer. 16 of 16 samples are served, verify against the header's
+    data hash and equal the host engine's byte for byte, row and column
+    axis; both level passes ran sharded (the square never left the mesh
+    for them), under spans and a counter of their own, and the warmer
+    met no error. On real chips this is what PR 35's rehearsal saw fail
+    24 heights of 24 (tests/test_tpu_compile.py asks the chip's compiler
+    for the same pass)."""
+    import sys
+
+    from celestia_app_tpu.chain.app import App
+    from celestia_app_tpu.chain.node import Node
+    from celestia_app_tpu.da import sampling
+    from celestia_app_tpu.da.dah import DataAvailabilityHeader
+    from celestia_app_tpu.da.proof_device import rows_sharded_over
+    from celestia_app_tpu.das.server import SampleCore
+    from celestia_app_tpu.utils import merkle_host, nmt_host
+
+    sys.path.insert(0, os.path.dirname(__file__))
+    from obs_drive import _T0, pfb_rounds
+
+    chain_id = "mesh-light-round"
+    privs, (raws,), _namespaces = pfb_rounds(chain_id, 1)
+    addrs = [p.public_key().address() for p in privs]
+    cells = [(int(r), int(c)) for r, c in
+             np.random.default_rng(36).integers(0, 16, size=(16, 2))]
+
+    def height_one(engine):
+        app = App(chain_id=chain_id, engine=engine,
+                  data_dir=str(tmp_path / engine))
+        app.init_chain({
+            "time_unix": _T0,
+            "accounts": [{"address": a.hex(), "balance": 10**15}
+                         for a in addrs],
+            "validators": [{"operator": addrs[0].hex(), "power": 10}],
+            "gov_max_square_size": 8,
+        })
+        node = Node(app)
+        core = node.attach_das_core(SampleCore(app))
+        assert [r.code for r in node.broadcast_txs(raws)] == [0] * 4
+        block, _ = node.produce_block(t=_T0 + 1)
+        assert block.header.square_size == 8
+        # the light round comes with the commit: no wait for the warmer
+        header = core.header(1)
+        rows = core.sample_many(1, cells)
+        cols = core.sample_many(1, cells, axis="col")
+        assert app.da_warmer.wait_idle(60)
+        return app, core, block, header, rows, cols
+
+    passes0 = _counter("mesh.sharded_level_passes")
+    errors0 = _counter("edscache.warm_errors")
+    runs0 = _counter('obs.span_n{name="mesh.levels.run"}')
+    extends0 = _counter('obs.span_n{name="mesh.extend.run"}')
+    app_m, core_m, block, header, rows, cols = height_one("mesh")
+    try:
+        entry = core_m._entry(1).cache_entry
+        assert isinstance(entry, edscache.DeviceEntry)
+        mesh, axis = rows_sharded_over(entry._eds_dev)
+        assert mesh.shape[axis] == 8 and entry.warmed()
+        assert all(len(level[0].sharding.device_set) == 8
+                   for level in entry._levels_dev + entry._col_levels_dev)
+        assert _counter("mesh.sharded_level_passes") - passes0 == 2
+        assert _counter('obs.span_n{name="mesh.levels.run"}') - runs0 == 2
+        assert _counter('obs.span_n{name="mesh.extend.run"}') - extends0 == 1
+        assert _counter("edscache.warm_errors") == errors0
+    finally:
+        app_m.close()
+
+    # 16 of 16, each against the header's data hash
+    row_roots = [bytes.fromhex(r) for r in header["row_roots"]]
+    col_roots = [bytes.fromhex(c) for c in header["col_roots"]]
+    assert merkle_host.hash_from_leaves(row_roots + col_roots) == \
+        block.header.data_hash
+    dah = DataAvailabilityHeader(tuple(row_roots), tuple(col_roots))
+    assert len(rows["samples"]) == 16
+    for doc in rows["samples"]:
+        assert "error" not in doc, doc
+        proof = nmt_host.NmtRangeProof(
+            start=doc["proof"]["start"], end=doc["proof"]["end"],
+            total=doc["proof"]["total"],
+            nodes=[base64.b64decode(n) for n in doc["proof"]["nodes"]])
+        assert sampling.verify_sample(
+            dah, doc["row"], doc["col"], base64.b64decode(doc["share"]),
+            proof)
+    assert not any("error" in doc for doc in cols["samples"])
+
+    app_h, _core, block_h, header_h, rows_h, cols_h = height_one("host")
+    app_h.close()
+    assert block_h.header.hash() == block.header.hash()
+    assert (header, rows, cols) == (header_h, rows_h, cols_h)
 
 
 # ---------------------------------------------------------------------------

@@ -246,7 +246,11 @@ def test_benchmark_metric_reads_a_name_the_block_produced(light_block,
                                                           metric):
     spec = cells.read_json(os.path.join(BENCH, "metrics", f"{metric}.json"))
     entry = {m["name"]: m for m in _manifest()["per_layer"]}[metric]
-    assert entry["workloads"] == [CELL]
+    # the light cell, and for what the signature batch fills the one other
+    # cell whose blocks reach it: 24 signatures a block on four chips
+    assert entry["workloads"][0] == CELL
+    assert set(entry["workloads"][1:]) <= (
+        {"bigblock-k256-mesh"} if metric.startswith("sig_") else set())
     assert entry["moves"] == "blob_throughput"
     delta, units = light_block["delta"], light_block["units"]
     reading = types.SimpleNamespace(counters=delta, units=units)
@@ -308,7 +312,7 @@ def test_the_cell_reports_what_the_produce_cells_report():
     assert light - full == set(NEW_METRICS)
     for m in manifest["end_to_end"]:
         if m["name"] in ("blob_throughput", "block_p90"):
-            assert m["workloads"][-1] == CELL
+            assert CELL in m["workloads"]
     cell = cells.load_cell(CELL)
     assert cell.chips == 1 and cell.mix["generator"] == "pfb_light_blocks"
     assert len(cell.per_layer) == len(light)

@@ -162,6 +162,11 @@ rng = np.random.default_rng(31)
 ods = rng.integers(0, 256, size=(k, k, 512), dtype=np.uint8)
 ods[..., :29] = 0
 eds = rs.extend_square_np(ods)
+# the square is the plain encode under the published 64-byte block, so
+# what the repairs below recover is recovered under that mapping
+import gf16_plain
+np.testing.assert_array_equal(eds, gf16_plain.extend(ods))
+assert not np.array_equal(eds[0, k:], gf16_plain.parity_adjacent_pairs(ods[0]))
 # 10 present positions (>= k), spanning data and parity halves
 present = (0, 1, 2, 3, 8, 9, 10, 11, 12, 13)
 damaged = eds.copy()
@@ -171,11 +176,20 @@ for j in range(2 * k):
 run = rs.repair_axes_fn(k, present)
 out = np.asarray(run(damaged))
 np.testing.assert_array_equal(out, eds)
+# the per-axis decoders (FWHT and matrix inversion) agree
+for r in (0, 5, 11, 15):
+    np.testing.assert_array_equal(
+        rs.repair_axis(damaged[r], list(present)), eds[r])
+    np.testing.assert_array_equal(
+        rs.repair_axis_matrix(damaged[r], list(present)), eds[r])
 print("GF16-BATCH-REPAIR-OK")
 """
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     env["CELESTIA_GF16_THRESHOLD"] = "4"
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.dirname(os.path.abspath(__file__)),
+         env.get("PYTHONPATH", "")]).rstrip(os.pathsep)
     r = subprocess.run([sys.executable, "-c", code], env=env,
                        capture_output=True, text=True, timeout=600)
     assert r.returncode == 0, r.stderr[-3000:]

@@ -1,10 +1,23 @@
 """Device RS extension vs numpy byte-domain reference; repair path."""
 
+import os
+import sys
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from celestia_app_tpu.ops import rs
+
+sys.path.insert(0, os.path.dirname(__file__))
+import gf16_plain  # noqa: E402
+
+PARENT_EXTEND_SHA256 = {   # rs.extend_square_np at e94e245, seed [36, k]
+    1: "fd51211d61a97fd7d8eadf85253506bb63ac503bf81ccaefcec3ab036c7369f0",
+    2: "753fdf0ccce9556d16be153bc284f7de440093f7f1ec6d60bd64483777af1343",
+    8: "0f6f2e200d2f1bbaecf89e1d02b64fe9b825243bdd400353b55d60a05ede389e",
+    128: "8a3c501ed8d09e6070711e89be3fc7a355fff44504d0f91f6ebe853e14a10438",
+}
 
 
 @pytest.mark.backend
@@ -89,6 +102,24 @@ def test_device_matches_numpy_in_the_16_bit_field(monkeypatch):
     assert not (eds_np == eds_8bit).all()      # another code, not a relabel
     eds_dev = np.asarray(jax.jit(rs.extend_square_fn(k))(jnp.asarray(ods)))
     assert (eds_np == eds_dev).all()
+    # and both are the plain encode under the published 64-byte block:
+    # every quadrant, never the adjacent-pairs bytes
+    assert (eds_dev == gf16_plain.extend(ods)).all()
+    assert not (eds_dev[0, k:] == gf16_plain.parity_adjacent_pairs(ods[0])
+                ).all()
+
+
+@pytest.mark.parametrize("k", [1, 2, 8, 128])
+def test_squares_up_to_128_keep_their_bytes(k):
+    """The 16-bit symbol mapping is no business of the 8-bit code: the
+    extension's digest at k <= 128 as pinned at the parent of PR 36
+    (`rs.extend_square_np`, ods from seed [36, k])."""
+    import hashlib
+
+    ods = np.random.default_rng([36, k]).integers(
+        0, 256, (k, k, 512), dtype=np.uint8)
+    assert hashlib.sha256(rs.extend_square_np(ods).tobytes()).hexdigest() \
+        == PARENT_EXTEND_SHA256[k]
 
 
 @pytest.mark.backend

@@ -119,6 +119,8 @@ from celestia_app_tpu.parallel import mesh as mesh_mod
 from celestia_app_tpu.parallel import sharded_eds
 
 assert leopard.uses_gf16(8), "threshold env not applied"
+GOLDEN_ROOT = ("759b1c253964d27dcc571fde2617aab4"
+               "261777c18e48873365d4b11f1f75ef62")
 k = 8
 rng = np.random.default_rng(99)
 ods = rng.integers(0, 256, size=(k, k, 512), dtype=np.uint8)
@@ -134,12 +136,20 @@ mesh = mesh_mod.make_mesh(8, k=k, devices=devs)
 run = sharded_eds.jitted_sharded_pipeline(mesh, k)
 eds_s, row_s, col_s, root_s = jax.tree.map(np.asarray, run(ods[None]))
 np.testing.assert_array_equal(eds_s[0], host_eds)
+# ... which is the plain encode under the published 64-byte block (the
+# chain's symbol mapping), not the adjacent-pairs bytes of before PR 36
+import gf16_plain
+np.testing.assert_array_equal(host_eds, gf16_plain.extend(ods))
+assert not np.array_equal(
+    host_eds[0, k:], gf16_plain.parity_adjacent_pairs(ods[0]))
 
 # and the single-device pipeline agrees on the roots
 single = eds_mod.jitted_pipeline(k)
 eds1, row1, col1, root1 = jax.tree.map(np.asarray, single(ods))
 np.testing.assert_array_equal(eds_s[0], eds1)
 np.testing.assert_array_equal(root_s[0], root1)
+# golden: the data root of this seeded square under the published mapping
+assert bytes(root1).hex() == GOLDEN_ROOT, bytes(root1).hex()
 print("GF16-MESH-OK")
 """
     import re
@@ -147,6 +157,9 @@ print("GF16-MESH-OK")
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     env["CELESTIA_GF16_THRESHOLD"] = "4"
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.dirname(os.path.abspath(__file__)),
+         env.get("PYTHONPATH", "")]).rstrip(os.pathsep)
     flags = re.sub(
         r"--xla_force_host_platform_device_count=\d+",
         "",

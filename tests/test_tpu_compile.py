@@ -13,8 +13,10 @@ k=64 / k=128 (full squares) and at k=8 / 16 / 32 (the small squares of
 tile, `ops/sha256.sha256` keeps to its jnp path, and the case checks that
 the program holds NO kernel, as the cases with `kernels=False` all do), the
 batched
-secp256k1 verifier, the namespace search, the blob commitment batch, and
-the four-chip sharded k=256 program.
+secp256k1 verifier, the namespace search, the blob commitment batch, the
+four-chip sharded k=256 program, and the level pass over the square that
+program leaves sharded (k=128 / 256, both orientations: the case the chips
+refused until PR 36, whose refusal is kept as a case too).
 
 Rules this file keeps (they are what lets it run under `pytest -n 6`):
 the topology is described ONLY inside the module fixture (one process at
@@ -197,3 +199,61 @@ def test_sharded_pipeline_k256_four_chips(topo):
     text = compiled.as_text()
     assert text.count("all-to-all") >= 2
     assert "all-gather" in text
+
+
+@pytest.fixture(scope="module")
+def seq_mesh(topo):
+    """The mesh a four-chip host runs big squares on, and the placement
+    the sharded pipeline leaves an entry's square in (`eds_dev[0]` of its
+    first output: rows over `seq`)."""
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from celestia_app_tpu.parallel import mesh as mesh_mod
+
+    mesh = mesh_mod.make_mesh(4, k=128, devices=topo.devices)
+    assert dict(mesh.shape) == {"data": 1, "seq": 4}
+    return mesh, NamedSharding(mesh, P(mesh_mod.SEQ_AXIS))
+
+
+@pytest.mark.parametrize("col", [False, True], ids=["rows", "cols"])
+@pytest.mark.parametrize("k", [128, 256])
+def test_level_pass_under_the_mesh_entrys_sharding(seq_mesh, k, col):
+    """DeviceEntry's level pass over a square the sharded pipeline left
+    split over four chips (da/proof_device._jitted_sharded_levels): the
+    Pallas kernels inside the shard_map, the column orientation riding
+    one all-to-all, every level left split by tree, each chip under its
+    16 GiB."""
+    from celestia_app_tpu.da import proof_device
+    from celestia_app_tpu.parallel.mesh import SEQ_AXIS
+
+    mesh, placed = seq_mesh
+    square = _u8((2 * k, 2 * k, 512), placed)
+    assert proof_device.rows_sharded_over(square) == (mesh, SEQ_AXIS)
+    program = proof_device._jitted_sharded_levels.__wrapped__(
+        mesh, SEQ_AXIS, k, col)
+    # __wrapped__: a fresh jit each time, outside the factory's lru_cache
+    compiled = _compile(program, square, in_shardings=placed)
+    text = compiled.as_text()
+    assert ("all-to-all" in text) == col
+    levels = jax.tree.leaves(compiled.output_shardings)
+    assert len(levels) == (2 * k).bit_length() * 3
+    assert all(s.spec[0] == SEQ_AXIS for s in levels)
+
+
+def test_plain_level_pass_is_refused_for_a_sharded_square(seq_mesh):
+    """Why the entry needs the shard_map: the one-chip level pass, handed
+    the same sharded square through a plain jit, is refused by the chip's
+    compiler in these words (every light round of a mesh-engine height
+    failed so on four chips: PERF.md, PR 35). The CPU backend has no
+    Pallas kernel and would pass."""
+    from celestia_app_tpu.da import proof_device
+
+    _mesh, placed = seq_mesh
+    k = 128
+    levels = proof_device._jitted_row_levels.__wrapped__(k)
+    with pytest.raises(NotImplementedError,
+                       match="Mosaic kernels cannot be automatically "
+                             "partitioned"):
+        jax.jit(lambda eds: levels(eds)).lower(
+            _u8((2 * k, 2 * k, 512), placed)).compile()
