@@ -53,8 +53,12 @@ protocols; this module is that amortization:
   level arrays stay on device, only the commitment (4k axis roots + the
   data root) crosses inside ``compute_entry``, the provers' level passes
   read the resident array, and host bytes materialize lazily — only
-  when a proof or serve path actually needs them — each materialization
-  counting ``edscache.host_crossings``. The single-device engine also
+  when a host prover or the square itself is asked for — each
+  materialization counting ``edscache.host_crossings``. A batch of
+  sampled cells is proved where the entry's bytes are
+  (``prove_cells``): from the host copy where one exists or was
+  started, else cut on the chip(s) by one program that brings only the
+  shares and their proof nodes down. The single-device engine also
   STARTS the square's host copy right after the run
   (``obs/xfer.HostFetch``): a served height needs those bytes, and
   started there they land in the shadow of process → commit instead of
@@ -248,6 +252,23 @@ class EdsCacheEntry:
                 self._col_prover = build_block_prover(eds_t, dah_t, engine)
             return self._col_prover
 
+    def proves_on_host(self, col: bool = False) -> bool:
+        """Whether `prove_cells` reads this orientation's proofs from host
+        arrays: always, for the host entry."""
+        return True
+
+    def prove_cells(self, cells, col: bool = False, engine: str = "auto"):
+        """[(share bytes, NmtRangeProof)] for a batch of EXTENDED-square
+        cells (row, col), each under its row root — or, with `col`, its
+        column root: cell (r, c) lives at (c, r) of the transpose, its
+        proof covers leaf range [r, r+1) under col_roots[c]. Index
+        arithmetic over the orientation's host prover."""
+        if col:
+            prover = self.get_col_prover(engine)
+            return [prover.prove_cell(c, r) for r, c in cells]
+        prover = self.get_prover(engine)
+        return [prover.prove_cell(r, c) for r, c in cells]
+
     @property
     def k(self) -> int:
         return self.eds.width // 2
@@ -284,9 +305,16 @@ class DeviceEntry(EdsCacheEntry):
       ``shard_map`` on that mesh (``_level_pass``), the level stacks
       left split by tree — a plain jit over it is refused by the TPU
       compiler, which partitions no Pallas kernel.
-    - ``.eds`` / the provers materialize host bytes lazily, only when a
-      proof or serve path actually needs them; every device->host array
-      fetch counts ``edscache.host_crossings``.
+    - ``.eds`` / the provers materialize host bytes lazily, only when
+      asked for (share-range and tx proofs, namespace reads, the pack
+      builders); every device->host array fetch counts
+      ``edscache.host_crossings``.
+    - ``prove_cells`` — a sampler's batch — looks at what the entry
+      holds: host bytes (a copy landed or started, a built prover) are
+      read as ever; an entry whose square lives only on the chip(s)
+      gathers each cell's share and sibling nodes there
+      (``gather_cells``: da/proof_device's gather, inside a
+      ``shard_map`` over a sharded square) and stays "device".
     - The single-device engine hands over ``eds_fetch``, the host copy
       of the square it STARTED right after the run (obs/xfer
       ``HostFetch``): ``.eds`` then waits for what is left of that
@@ -488,6 +516,78 @@ class DeviceEntry(EdsCacheEntry):
                 self._col_prover = proof_device.BlockProver(
                     eds_t, self._transposed_dah(), levels=levels)
             return self._col_prover
+
+    @property
+    def chips(self) -> int:
+        """Chips the square's rows are split over (1: one chip holds
+        it)."""
+        from celestia_app_tpu.da import proof_device
+
+        placed = proof_device.rows_sharded_over(self._eds_dev)
+        return 1 if placed is None else placed[0].shape[placed[1]]
+
+    def proves_on_host(self, col: bool = False) -> bool:
+        """True once host bytes exist to read a proof from: a copy of the
+        square that landed or that the engine started (every one-chip
+        device engine starts one), or this orientation's built host
+        prover. An entry whose square lives only on the chip(s) — the
+        mesh and batched engines start no copy — proves there
+        (`gather_cells`). Each look waits out a build in progress under
+        the same lock: what it was building is then there to read."""
+        with self._eds_lock:
+            if self._eds is not None or self._eds_fetch is not None:
+                return True
+        if col:
+            with self._col_lock:
+                return self._col_prover is not None
+        with self._row_lock:
+            return self._prover is not None
+
+    def prove_cells(self, cells, col: bool = False, engine: str = "auto"):
+        """A batch of cells proved where the entry's bytes are: from the
+        host copy or host prover where one exists (the base class's
+        loop), else cut on the chip(s) — the entry looks at what it
+        holds, as `_level_pass` looks at its array's sharding."""
+        if self.proves_on_host(col):
+            return super().prove_cells(cells, col, engine)
+        return self.gather_cells(cells, col)
+
+    def gather_cells(self, cells, col: bool = False):
+        """[(share bytes, NmtRangeProof)] cut out of the resident square
+        and the orientation's resident level stack by ONE program — each
+        cell's share and the log2(2k) sibling nodes of its path — of
+        which only the answer comes down (n x (512 + L x 90) B through
+        the ledger site `proof.gather`): nothing materializes, the entry
+        stays "device". The level stack is the warmer's (build-once
+        under its lock), or this call's if it arrives first. The bytes
+        are `BlockProver.prove_cell`'s for every cell."""
+        import jax
+
+        from celestia_app_tpu.da import proof_device
+
+        width = 2 * self.k
+        cells = [(int(r), int(c)) for r, c in cells]
+        for r, c in cells:
+            if not (0 <= r < width and 0 <= c < width):
+                raise ValueError(
+                    f"cell ({r}, {c}) outside the {width}x{width} square")
+        # below the roots: a path's top node is a child of the root
+        levels = list(self._device_levels(col))[:-1]
+        index = np.zeros((2, proof_device.gather_bucket(len(cells))),
+                         dtype=np.int32)
+        index[0, :len(cells)] = [r for r, _ in cells]
+        index[1, :len(cells)] = [c for _, c in cells]
+        program, placement = proof_device.sample_gather_program(
+            self._eds_dev, self.k, col)
+        index_dev = xfer.to_device(index, "proof.gather",
+                                   placement=placement)
+        with obs.span("proof.gather.run", k=self.k, cells=len(cells),
+                      col=col):
+            answer = jax.block_until_ready(
+                program(self._eds_dev, levels, index_dev))
+        shares, nodes = xfer.to_host(answer, "proof.gather")
+        return proof_device.gathered_proofs(cells, col, width, shares,
+                                            nodes)
 
 
 def compute_entry(ods: np.ndarray, engine: str = "auto",

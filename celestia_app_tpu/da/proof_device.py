@@ -109,6 +109,133 @@ def _jitted_sharded_levels(mesh, axis: str, k: int, col: bool):
                    in_shardings=NamedSharding(mesh, by_tree))
 
 
+# a gather's index vectors are padded to a power of two, and to no fewer
+# than a light node's round (celestia-node samples 16 cells a header):
+# one shape a height for every batch a sampler sends
+MIN_GATHER_BUCKET = 16
+
+
+def gather_bucket(n_cells: int) -> int:
+    """Cells a gather of `n_cells` is padded to."""
+    return max(MIN_GATHER_BUCKET, 1 << (n_cells - 1).bit_length())
+
+
+def _cells_held(eds: jax.Array, levels, cells: jax.Array, col: bool, first):
+    """Shares (n, 512) and sibling nodes (n, L, 90: min | max | hash) of
+    `cells` ((2, n) int32: rows, cols) out of `eds.shape[0]` rows of the
+    square and as many trees of the asked orientation's level stacks,
+    both starting at global index `first`; zeros for a cell or a tree
+    held elsewhere. Cell (r, c) is `eds[r, c]` whichever the axis; on the
+    row axis its tree is r and its leaf c, on the column axis c and r;
+    at level l its sibling on the path is node (leaf >> l) ^ 1."""
+    rows, cols = cells[0], cells[1]
+    tree, leaf = (cols, rows) if col else (rows, cols)
+    held = eds.shape[0]
+
+    def take(arr: jax.Array, major: jax.Array, minor: jax.Array):
+        local = major - first
+        mine = (local >= 0) & (local < held)
+        picked = arr[jnp.where(mine, local, 0), minor]
+        return jnp.where(mine[:, None], picked, 0)
+
+    shares = take(eds, rows, cols)
+    nodes = jnp.stack([
+        jnp.concatenate([take(part, tree, (leaf >> level) ^ 1)
+                         for part in triple], axis=-1)
+        for level, triple in enumerate(levels)], axis=1)
+    return shares, nodes
+
+
+@functools.lru_cache(maxsize=None)
+def _jitted_sample_gather(col: bool):
+    """Compiled: a resident (2k, 2k, 512) EDS one chip holds, one
+    orientation's level stack below the roots and (2, n) cells -> each
+    cell's share and the log2(2k) sibling nodes of its proof."""
+
+    # named for the trace: jit_sample_gather(...)
+    def sample_gather(eds: jax.Array, levels, cells: jax.Array):
+        return _cells_held(eds, levels, cells, col, 0)
+
+    return jax.jit(sample_gather)
+
+
+@functools.lru_cache(maxsize=None)
+def _jitted_sharded_sample_gather(mesh, axis: str, k: int, col: bool):
+    """Compiled: `_jitted_sample_gather` over a square whose rows, and
+    level stacks whose trees, are split over `axis` of `mesh` (what
+    `_jitted_sharded_levels` leaves resident), inside a shard_map on that
+    mesh: each chip answers the cells and the trees it holds and
+    contributes zeros for the rest, ONE all-reduce of the packed
+    (n, 512 + L * 90) answer hands every chip the result. Neither the
+    square nor a level stack is gathered anywhere."""
+    per_chip = 2 * k // mesh.shape[axis]
+
+    def gather_local(eds_local, levels_local, cells):
+        first = lax.axis_index(axis) * per_chip
+        shares, nodes = _cells_held(eds_local, levels_local, cells, col,
+                                    first)
+        n = shares.shape[0]
+        packed = jnp.concatenate([shares, nodes.reshape(n, -1)], axis=1)
+        # exactly one chip holds each byte: the sum is that byte
+        packed = lax.psum(packed.astype(jnp.uint32), axis).astype(jnp.uint8)
+        return (packed[:, :shares.shape[1]],
+                packed[:, shares.shape[1]:].reshape(nodes.shape))
+
+    by_tree = P(axis, None, None)
+    sharded = jax.shard_map(gather_local, mesh=mesh,
+                            in_specs=(by_tree, by_tree, P()),
+                            out_specs=P(), check_vma=False)
+
+    # named for the trace: jit_mesh_sample_gather(...)
+    def mesh_sample_gather(eds: jax.Array, levels, cells: jax.Array):
+        return sharded(eds, levels, cells)
+
+    split = NamedSharding(mesh, by_tree)
+    return jax.jit(mesh_sample_gather,
+                   in_shardings=(split, split, NamedSharding(mesh, P())))
+
+
+def sample_gather_program(eds, k: int, col: bool):
+    """The gather over whatever holds `eds`, and where its (2, n) cells
+    go: (program, placement of the index array — None for the one chip
+    a plain jit reads)."""
+    placed = rows_sharded_over(eds)
+    if placed is None:
+        return _jitted_sample_gather(col), None
+    mesh, axis = placed
+    return (_jitted_sharded_sample_gather(mesh, axis, k, col),
+            NamedSharding(mesh, P()))
+
+
+def single_leaf_path(total: int, leaf: int) -> list[int]:
+    """Levels of a one-leaf range proof's nodes in proof order — what
+    `BlockProver._range_proof(row, leaf, leaf + 1)` walks: the siblings
+    left of the leaf top-down, then those right of it bottom-up. The node
+    at level l is (l, (leaf >> l) ^ 1)."""
+    depth = total.bit_length() - 1
+    left = [lv for lv in reversed(range(depth)) if (leaf >> lv) & 1]
+    right = [lv for lv in range(depth) if not (leaf >> lv) & 1]
+    return left + right
+
+
+def gathered_proofs(cells, col: bool, total: int, shares: np.ndarray,
+                    nodes: np.ndarray):
+    """[(share bytes, NmtRangeProof)] from a gather's host arrays, one a
+    cell (the arrays' padding rows beyond `cells` are dropped): the
+    bytes `BlockProver.prove_cell` gives for the same cell."""
+    out = []
+    for i, (row, col_) in enumerate(cells):
+        leaf = row if col else col_
+        out.append((
+            shares[i].tobytes(),
+            nmt_host.NmtRangeProof(
+                start=leaf, end=leaf + 1, total=total,
+                nodes=[nodes[i, lv].tobytes()
+                       for lv in single_leaf_path(total, leaf)]),
+        ))
+    return out
+
+
 class BlockProver:
     """Per-block proof factory: one device pass, then index-only proofs."""
 

@@ -73,29 +73,34 @@ class PackError(ValueError):
     404 in the HTTP services."""
 
 
-def live_cell_doc(entry, cell, prover=None) -> dict:
-    """THE per-cell sample doc (FORMATS §7.1 / §16.3) — one builder
-    shared by the live serving path (das/server.SampleCore) and the pack
-    builder, so pack bytes ≡ live bytes by construction. ``prover`` lets
-    the live path pass its memoized row prover; the default resolves the
-    entry's own (engines are pinned bit-identical)."""
+def cell_doc(row: int, col: int, share: bytes, proof) -> dict:
+    """THE rs2d-nmt per-cell sample doc (FORMATS §7.1) from a proved
+    cell — whoever proved it: a host prover's index arithmetic or the
+    gather on the chip(s) (da/edscache `prove_cells`)."""
+    return {
+        "row": row,
+        "col": col,
+        "share": base64.b64encode(share).decode(),
+        "proof": {
+            "start": proof.start,
+            "end": proof.end,
+            "total": proof.total,
+            "nodes": [base64.b64encode(n).decode()
+                      for n in proof.nodes],
+        },
+    }
+
+
+def live_cell_doc(entry, cell) -> dict:
+    """One cell proved by the entry's host row prover and its doc built
+    (FORMATS §7.1 / §16.3): the pack builder's unit of work — a pack is
+    every cell of the square, so it asks for the prover outright. The
+    live serving path (das/server.SampleCore) proves a request's cells
+    in one `prove_cells` batch and builds each doc with the same
+    `cell_doc`, so pack bytes ≡ live bytes by construction."""
     if entry.scheme == codec_mod.RS2D_NAME:
         row, col = cell
-        if prover is None:
-            prover = entry.get_prover()
-        share, proof = prover.prove_cell(row, col)
-        return {
-            "row": row,
-            "col": col,
-            "share": base64.b64encode(share).decode(),
-            "proof": {
-                "start": proof.start,
-                "end": proof.end,
-                "total": proof.total,
-                "nodes": [base64.b64encode(n).decode()
-                          for n in proof.nodes],
-            },
-        }
+        return cell_doc(row, col, *entry.get_prover().prove_cell(row, col))
     # non-default schemes: the codec's own doc, with row/col aliases so
     # batched responses keep one shape across schemes (FORMATS §16.3)
     codec = codec_mod.get(entry.scheme)

@@ -6,7 +6,10 @@ the full-node side must answer them from *cached row trees*, never by
 rehashing per request. Every height's trees come from the one batched
 device pass `da/proof_device.BlockProver` already runs (ops/nmt.nmt_levels
 — vmapped SHA-256 on device engines, the bit-identical fast_host SIMD
-levels on host engines); each served proof is then pure index arithmetic.
+levels on host engines); each served proof is then pure index arithmetic
+— over host arrays where the height has them, or as one gather on the
+chip(s) for a height whose square lives only there (a mesh-engine
+height: `_Entry.prove_cells`, span `das.gather`).
 Entries sit behind a bounded LRU keyed by height — the same discipline as
 the DA service's square cache (service/da_service.DACore).
 
@@ -56,7 +59,8 @@ Block-plane integration (PR 8): heights are backed by the app's
 content-addressed EDS/DAH cache (da/edscache.py). `App.commit` hands each
 committed entry here via `seed_cache_entry` (registered on
 `app.da_seed_listeners`) from the warmer's background thread with its
-provers pre-built, so the first sample after a commit never rebuilds or
+provers (or, on a device engine, its level stacks) pre-built, so the
+first sample after a commit never rebuilds or
 re-extends; misses single-flight through `_entry` so concurrent samplers
 of a fresh height pay one build between them.
 """
@@ -89,11 +93,10 @@ class _Entry:
         self.height = height
         self.cache_entry = cache_entry
         self.engine = engine
-        # resolved-prover memo: per-cell proving must not pay the cache
-        # entry's lock per proof (benign race — get_prover is idempotent
-        # and returns the one entry-owned instance)
+        # resolved row prover (benign race — get_prover is idempotent
+        # and returns the one entry-owned instance): namespace reads
+        # take it from here, and its first touch is a span
         self._prover_view = None
-        self._col_prover_view = None
 
     @property
     def dah(self):
@@ -128,12 +131,27 @@ class _Entry:
                     self.cache_entry.get_prover(self.engine)
         return self._prover_view
 
-    @property
-    def col_prover(self):
-        if self._col_prover_view is None:
-            self._col_prover_view = \
-                self.cache_entry.get_col_prover(self.engine)
-        return self._col_prover_view
+    def prove_cells(self, cells: list[tuple[int, int]], col: bool):
+        """[(share, proof)] for one request's cells, proved where the
+        height's bytes are (da/edscache `prove_cells`). An entry with
+        host bytes serves as ever: the orientation's prover — the row
+        prover's first touch of a height under ``das.build_provers`` —
+        then index arithmetic.
+        An entry whose square lives only on the chip(s) cuts the batch
+        there in one program (``das.gather``) and builds no prover."""
+        entry = self.cache_entry
+        if entry.proves_on_host(col):
+            if not col:
+                _ = self.prover  # first touch, spanned
+            return entry.prove_cells(cells, col=col, engine=self.engine)
+        from celestia_app_tpu import obs
+
+        with obs.span("das.gather", height=self.height, cells=len(cells),
+                      col=col, chips=entry.chips):
+            proved = entry.gather_cells(cells, col=col)
+        telemetry.incr("das.gather_dispatches")
+        telemetry.incr("das.samples_gathered", len(cells))
+        return proved
 
 
 class _Build:
@@ -145,12 +163,6 @@ class _Build:
     def __init__(self):
         self.done = threading.Event()
         self.entry: _Entry | None = None
-
-
-def _b64(b: bytes) -> str:
-    import base64
-
-    return base64.b64encode(b).decode()
 
 
 class SampleCore:
@@ -308,13 +320,6 @@ class SampleCore:
                 self._cache.popitem(last=False)
                 telemetry.incr("das.entry_evictions")
 
-    def _col_prover(self, entry: _Entry):
-        """Column-axis prover (BEFP escalation serving) — owned by the
-        cache entry (da/edscache.EdsCacheEntry.get_col_prover), which
-        builds it at most once under its own lock; the commit warmer
-        usually pre-built it already."""
-        return entry.col_prover
-
     # -- fault injection (tests / adversarial simulation) ----------------
 
     def withhold(self, height: int, cells) -> None:
@@ -349,7 +354,10 @@ class SampleCore:
             doc["pack"] = pack
         return doc
 
-    def _one(self, entry: _Entry, row: int, col: int, axis: str) -> dict:
+    def _gate(self, entry: _Entry, row: int, col: int) -> None:
+        """The per-cell gates of every scheme and axis, in order: range,
+        withholding fixture, fault point. Raises SampleError for a cell
+        that is not to be served."""
         if entry.scheme == codec_mod.RS2D_NAME:
             width = len(entry.dah.row_roots)
             if not (0 <= row < width and 0 <= col < width):
@@ -372,34 +380,11 @@ class SampleCore:
                        row=row, col=col) in ("drop", "error"):
             self._note(entry, withheld=1)
             raise SampleError(f"cell ({row}, {col}) not served")
-        if entry.scheme != codec_mod.RS2D_NAME:
-            return self._one_codec(entry, row, col)
-        if axis == "row":
-            # the shared doc builder (das/packs.live_cell_doc): the pack
-            # builder runs the SAME function, so pack bytes ≡ live bytes
-            # by construction (pinned in tests/test_serving.py)
-            return packs_mod.live_cell_doc(
-                entry.cache_entry, (row, col), prover=entry.prover)
-        # transposed prover: cell (row, col) lives at (col, row) of
-        # the transpose; its proof hangs under col_roots[col] and
-        # covers leaf range [row, row+1)
-        share, proof = self._col_prover(entry).prove_cell(col, row)
-        return {
-            "row": row,
-            "col": col,
-            "share": _b64(share),
-            "proof": {
-                "start": proof.start,
-                "end": proof.end,
-                "total": proof.total,
-                "nodes": [_b64(n) for n in proof.nodes],
-            },
-        }
 
     def _one_codec(self, entry: _Entry, layer: int, index: int) -> dict:
         """Non-default-scheme cell: the wire (row, col) pair is the
         scheme's (layer, index) — FORMATS §16.3. The withholding fixture
-        and the das.serve_sample fault point already gated in _one."""
+        and the das.serve_sample fault point already gated in _gate."""
         try:
             return packs_mod.live_cell_doc(entry.cache_entry,
                                            (layer, index))
@@ -453,14 +438,31 @@ class SampleCore:
             ),
             height=height, cells=len(cells), axis=axis,
         ) as sp:
-            samples = []
+            # every cell through its gates, each reply in its cell's
+            # place; the rs2d cells that pass are proved in ONE batch
+            # (where the entry's bytes are) and every doc comes from the
+            # one builder the pack builder uses (das/packs.cell_doc), so
+            # pack bytes ≡ live bytes ≡ gathered bytes by construction
+            # (pinned in tests/test_serving.py, tests/test_mesh_plane.py)
+            samples: list[dict | None] = [None] * len(cells)
+            batch: list[int] = []
             served = 0
-            for r, c in cells:
+            for i, (r, c) in enumerate(cells):
                 try:
-                    samples.append(self._one(entry, r, c, axis))
-                    served += 1
+                    self._gate(entry, r, c)
+                    if entry.scheme == codec_mod.RS2D_NAME:
+                        batch.append(i)
+                    else:
+                        samples[i] = self._one_codec(entry, r, c)
+                        served += 1
                 except SampleError as e:
-                    samples.append({"row": r, "col": c, "error": str(e)})
+                    samples[i] = {"row": r, "col": c, "error": str(e)}
+            if batch:
+                proved = entry.prove_cells([cells[i] for i in batch],
+                                           col=axis == "col")
+                for i, (share, proof) in zip(batch, proved):
+                    samples[i] = packs_mod.cell_doc(*cells[i], share, proof)
+                served += len(batch)
             sp.set(served=served)
         # batches and their time are the span's own totals
         # (obs.span_n / obs.span_wall_us{name="das.serve_sample"})

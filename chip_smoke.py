@@ -23,8 +23,9 @@ call real:
 seeded k=256 ODS through compute_entry(ods, "mesh") on four chips, the
 one-chip program on chip 0 and the host engine; the three data roots
 equal, the resident entry spread over four devices, and 16 cells proved
-from the mesh entry's sharded level passes (row and column axis) equal
-to the host engine's proofs.
+on the chips from the resident square and the mesh entry's sharded level
+passes (row and column axis: one gather each) equal to the host engine's
+proofs, the entry still "device" and the square never brought down.
 
 Any failed check or exception ends the run with a traceback and a
 non-zero exit; no phase is skipped over. The LAST stdout line of a
@@ -453,15 +454,20 @@ def run_four_chips(args, sizes, counters: DeviceCounters) -> None:
         width = 2 * k
         cells = [(int(r), int(c)) for r, c in np.random.default_rng(
             args.seed).integers(0, width, size=(16, 2))]
-        rows_m, rows_h = entry.get_prover(), ref.get_prover("host")
-        cols_m, cols_h = entry.get_col_prover(), ref.get_col_prover("host")
-        for r, c in cells:
-            check(rows_m.prove_cell(r, c) == rows_h.prove_cell(r, c),
-                  f"k={k}: mesh and host row proofs of ({r}, {c}) differ")
-            check(cols_m.prove_cell(c, r) == cols_h.prove_cell(c, r),
-                  f"k={k}: mesh and host column proofs of ({r}, {c}) differ")
+        # the sixteen cells and their proof nodes cut on the chips: the
+        # mesh entry has no host copy, and none is made
+        for col in (False, True):
+            got = entry.prove_cells(cells, col=col)
+            want = ref.prove_cells(cells, col=col, engine="host")
+            for (r, c), g, w in zip(cells, got, want):
+                check(g == w, f"k={k}: gathered and host "
+                      f"{'column' if col else 'row'} proofs of ({r}, {c}) "
+                      "differ")
+        check(entry.residency() == "device",
+              "proving sixteen cells brought the mesh entry's square down")
         ph.checked.update(
             k=k, samples=len(cells), axes=["row", "col"],
+            residency=entry.residency(),
             level_devices=len(entry._levels_dev[0][0].sharding.device_set))
 
     with Phase("counters") as ph:
@@ -471,6 +477,11 @@ def run_four_chips(args, sizes, counters: DeviceCounters) -> None:
               "the mesh phase did not run exactly one extend")
         check(counters.get("mesh.sharded_level_passes") == 2,
               "the two level passes did not run sharded")
+        eds_down = counters.get('xfer.d2h_calls{site="edscache.eds"}')
+        check(eds_down == 0,
+              f"the square came down {eds_down} times (xfer.d2h:edscache.eds)")
+        check(counters.get('xfer.d2h_calls{site="proof.gather"}') == 2,
+              "the two gathers did not come down through proof.gather")
         ph.checked.update(fallbacks=fired,
                           da_extend_runs=counters.get("da.extend_runs"))
 

@@ -106,8 +106,14 @@ def test_mesh_engine_unshardable_square_degrades():
 
 
 def test_device_residency_and_host_crossings():
-    """The extend->commit->warm chain never crosses the host boundary;
-    the first proof materializes (counted), later proofs are free."""
+    """The extend->commit->warm chain never crosses the host boundary,
+    and neither does a sample of an entry that has no host copy: its
+    cells are cut on the chips and the entry stays "device". A host
+    prover asked for outright still materializes (counted), and later
+    proofs from it are free."""
+    from celestia_app_tpu.chain.app import App
+    from celestia_app_tpu.das.server import SampleCore
+
     k = 8
     entry = edscache.compute_entry(_random_ods(k, 42), "mesh")
     assert entry.residency() == "device"
@@ -122,7 +128,19 @@ def test_device_residency_and_host_crossings():
     assert _counter("edscache.host_crossings") == c0
     assert entry.residency() == "device"
 
-    # first proof: EDS + row levels materialize (2 counted crossings)
+    # a served sample, either axis: gathered, nothing materializes
+    app = App(chain_id="mesh-residency")
+    app.init_chain({"time_unix": 0})
+    core = SampleCore(app)
+    core.seed_cache_entry(3, entry)
+    core.sample(3, 0, 0)
+    core.sample(3, 5, 9, axis="col")
+    assert _counter("edscache.host_crossings") == c0
+    assert entry.residency() == "device"
+    assert core.availability(3)["residency"] == "device"
+
+    # a prover asked for outright: EDS + row levels materialize (2
+    # counted crossings)
     entry.get_prover().prove_cell(0, 0)
     after_first = _counter("edscache.host_crossings")
     assert after_first > c0
@@ -134,9 +152,12 @@ def test_device_residency_and_host_crossings():
 
 
 def test_device_entry_serves_das_with_crossings_pinned():
-    """A seeded device-resident entry serves /das/* — the first sample
-    pays the (counted) materialization, every later sample has a
-    host_crossings delta of exactly 0."""
+    """A seeded device-resident entry serves /das/* with a
+    host_crossings delta of exactly 0 from the first sample on — its
+    cells are gathered on the chips — and the availability record says
+    "device"; once a host prover has been asked for (one counted
+    materialization an orientation), samples come from it, still
+    crossing-free."""
     from celestia_app_tpu.chain.app import App
     from celestia_app_tpu.das.server import SampleCore
 
@@ -149,14 +170,24 @@ def test_device_entry_serves_das_with_crossings_pinned():
     core.seed_cache_entry(5, entry)
 
     host = edscache.compute_entry(_random_ods(k, 99), "host")
-    # first proof per orientation pays the (counted) materialization
+    c0 = _counter("edscache.host_crossings")
+    g0 = _counter("das.samples_gathered")
     first = core.sample(5, 0, 0)
     first_col = core.sample(5, 7, 1, axis="col")
-    c0 = _counter("edscache.host_crossings")
+    assert _counter("edscache.host_crossings") == c0, \
+        "a copy-less device entry must serve its samples crossing-free"
+    assert _counter("das.samples_gathered") - g0 == 2
+    assert core.availability(5)["residency"] == "device"
+    # a prover asked for outright materializes, counted, per orientation
+    entry.get_prover()
+    entry.get_col_prover()
+    c1 = _counter("edscache.host_crossings")
+    assert c1 > c0
     again = core.sample(5, 3, 4)
     col = core.sample(5, 2, 6, axis="col")
-    assert _counter("edscache.host_crossings") == c0, \
-        "a warmed device entry must serve later samples crossing-free"
+    assert _counter("edscache.host_crossings") == c1, \
+        "a materialized device entry must serve later samples crossing-free"
+    assert _counter("das.samples_gathered") - g0 == 2
     # and the served docs equal the host engine's byte for byte
     core_h = SampleCore(app)
     core_h.seed_cache_entry(5, host)
@@ -166,6 +197,131 @@ def test_device_entry_serves_das_with_crossings_pinned():
     assert col == core_h.sample(5, 2, 6, axis="col")
     # the availability record surfaces the residency
     assert core.availability(5)["residency"] == "device+host"
+
+
+# ---------------------------------------------------------------------------
+# a sample is cut where the square lives (PR 37)
+# ---------------------------------------------------------------------------
+
+
+def _seeded_cores(k: int, seed: int, kind: str):
+    """(entry under test, its SampleCore, the host engine's SampleCore)
+    over one random square at height 7. `kind`: "mesh" (rows split over
+    the 8 virtual devices) or "one-chip" (the single-device program's
+    resident square in an entry built without `eds_fetch`, as the
+    batched engine builds it)."""
+    from celestia_app_tpu.chain.app import App
+    from celestia_app_tpu.das.server import SampleCore
+
+    ods = _random_ods(k, seed)
+    if kind == "mesh":
+        entry = edscache.compute_entry(ods, "mesh")
+        assert entry.chips == 8
+    else:
+        started = edscache.compute_entry(ods, "device")
+        entry = edscache.DeviceEntry(started._eds_dev, started.dah,
+                                     started.data_root)
+        assert entry.chips == 1
+    app = App(chain_id=f"gather-{kind}-{k}")
+    app.init_chain({"time_unix": 0})
+    core, core_h = SampleCore(app), SampleCore(app)
+    core.seed_cache_entry(7, entry)
+    core_h.seed_cache_entry(7, edscache.compute_entry(ods, "host"))
+    return entry, core, core_h
+
+
+@pytest.mark.parametrize("axis", ["row", "col"])
+@pytest.mark.parametrize("k", [8, 16, 32])
+@pytest.mark.parametrize("kind", ["mesh", "one-chip"])
+def test_gathered_samples_equal_the_host_engines(kind, k, axis):
+    """Docs served from an entry with no host copy — its cells and proof
+    nodes gathered by one program where the square lives — equal the
+    host engine's byte for byte: the corners, a cell of each parity
+    quadrant, a repeated cell, a batch of 1 and of 17 (bucket padding),
+    and a batch whose out-of-range and withheld cells keep their place
+    as error docs. Nothing materializes."""
+    w = 2 * k
+    entry, core, core_h = _seeded_cores(k, 3700 + k, kind)
+    quadrants = [(1, 2), (3, k + 1), (k + 2, 5), (k + 3, k + 4)]
+    rng = np.random.default_rng(k)
+    seventeen = [(int(r), int(c))
+                 for r, c in rng.integers(0, w, size=(17, 2))]
+    batches = [
+        [(0, 0), (w - 1, w - 1)] + quadrants + [(3, k + 1)],
+        [(w - 1, 0)],
+        seventeen,
+        [(0, w - 1), (w, 0), (2, 2), (-1, 3), (5, 6), (k, k)],
+    ]
+    for c in (core, core_h):
+        c.withhold(7, [(5, 6)])
+    core_h.sample(7, 0, 0, axis=axis)  # the host core's first touch
+    c0 = _counter("edscache.host_crossings")
+    g0 = _counter("das.samples_gathered")
+    d0 = _counter("das.gather_dispatches")
+    n0 = _counter('obs.span_n{name="das.gather"}')
+    b0 = _counter('obs.span_n{name="das.build_provers"}')
+    want_gathered = 0
+    for cells in batches:
+        got = core.sample_many(7, cells, axis=axis)
+        assert got == core_h.sample_many(7, cells, axis=axis)
+        errors = [i for i, doc in enumerate(got["samples"])
+                  if "error" in doc]
+        want_gathered += len(cells) - len(errors)
+    assert errors == [1, 3, 4]  # the last batch: places kept
+    assert [(d["row"], d["col"]) for d in got["samples"]] == batches[-1]
+    assert _counter("das.samples_gathered") - g0 == want_gathered
+    assert _counter("das.gather_dispatches") - d0 == len(batches)
+    assert _counter('obs.span_n{name="das.gather"}') - n0 == len(batches)
+    assert _counter('obs.span_n{name="das.build_provers"}') == b0
+    assert _counter("edscache.host_crossings") == c0
+    assert entry.residency() == "device"
+    assert core.availability(7)["residency"] == "device"
+    assert core.availability(7)["samples_served"] == want_gathered
+    assert core.availability(7)["withheld_refusals"] == 1
+
+
+@pytest.mark.parametrize("holds", ["started-copy", "landed-copy",
+                                   "host-prover", "host-entry"])
+def test_an_entry_with_host_bytes_serves_from_them(holds):
+    """The gather is for an entry whose square lives only on the
+    chip(s): one whose engine started a host copy (every one-chip
+    device engine), whose copy landed, or whose host prover was built,
+    serves as before — `das.samples_gathered` does not move — and so
+    does the host engine's entry."""
+    from celestia_app_tpu.chain.app import App
+    from celestia_app_tpu.das.server import SampleCore
+
+    k = 8
+    ods = _random_ods(k, 3737)
+    if holds == "host-entry":
+        entry = edscache.compute_entry(ods, "host")
+    elif holds == "host-prover":
+        entry = edscache.compute_entry(ods, "mesh")
+        entry.get_prover()
+        entry.get_col_prover()
+    else:
+        entry = edscache.compute_entry(ods, "device")
+        assert entry._eds_fetch is not None
+        if holds == "landed-copy":
+            entry.eds
+            assert entry._eds_fetch is None
+    app = App(chain_id="gather-bypass")
+    app.init_chain({"time_unix": 0})
+    core, core_h = SampleCore(app), SampleCore(app)
+    core.seed_cache_entry(2, entry)
+    core_h.seed_cache_entry(2, edscache.compute_entry(ods, "host"))
+    g0 = _counter("das.samples_gathered")
+    d0 = _counter("das.gather_dispatches")
+    b0 = _counter('obs.span_n{name="das.build_provers"}')
+    cells = [(0, 0), (3, 12), (15, 15)]
+    for axis in ("row", "col"):
+        assert entry.proves_on_host(axis == "col")
+        assert core.sample_many(2, cells, axis=axis) == \
+            core_h.sample_many(2, cells, axis=axis)
+    assert _counter("das.samples_gathered") == g0
+    assert _counter("das.gather_dispatches") == d0
+    # the row prover's first touch of each served height, as before
+    assert _counter('obs.span_n{name="das.build_provers"}') - b0 == 2
 
 
 # ---------------------------------------------------------------------------
