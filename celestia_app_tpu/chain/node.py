@@ -68,6 +68,13 @@ class Node:
         self.mempool_ttl = mempool_ttl
         self.committed: dict[bytes, tuple[int, TxResult]] = {}  # tx hash -> (height, result)
         self.blocks: list[Block] = []
+        # every process that runs a node samples its interpreter's
+        # pressure (obs/gil.py; CELESTIA_OBS-gated): one sampler a
+        # process, so none here beside a service's that started first
+        from celestia_app_tpu.obs import gil
+
+        if not gil.running():
+            gil.start("node")
 
     # -- mempool -------------------------------------------------------
 

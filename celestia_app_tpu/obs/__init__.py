@@ -16,9 +16,11 @@ obs``):
   so bytes, calls, and latency are attributed per call-site label, and
   ``xfer.no_implicit_transfers()`` turns stray implicit copies into
   hard errors for tier-1 residency pins.
-- ``obs.gil`` — GIL-pressure oversleep samplers per HTTP service and
-  the ``process.peak_rss_bytes`` /metrics gauge (collector registers on
-  import of this package).
+- ``obs.gil`` — GIL-pressure oversleep samplers (one per HTTP service,
+  one for a process's first ``Node`` where none runs; the unlabelled
+  ``gil.samples`` / ``gil.oversleep_us`` counters are a window's mean
+  wait for the interpreter) and the ``process.peak_rss_bytes`` /metrics
+  gauge (collector registers on import of this package).
 
 Histograms/labels/Prometheus exposition live in utils/telemetry.py (the
 metric registry predates this package and everything already imports it).

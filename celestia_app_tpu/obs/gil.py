@@ -9,17 +9,29 @@ thread waking from `sleep()` must reacquire the GIL before it runs
 again, so the drift between requested and actual sleep is a direct
 sample of how long runnable threads in THIS process wait for the
 interpreter (plus OS scheduler noise, which is the same for every
-service and cancels in comparisons). Each service starts one sampler
-under its own label:
+service and cancels in comparisons). Each HTTP service starts one
+sampler under its own label, and `chain/node.Node` starts one labelled
+``"node"`` in a process that runs none yet — so the in-process node of
+the benchmark and the tests has one, and a service process whose
+service started first gets no second thread. Each wake publishes:
 
 - histogram ``gil.oversleep{service=…}`` — per-wake drift seconds
   (p50/p99 in /metrics via the registry's bucket ladder);
 - gauge ``gil.pressure{service=…}`` — EWMA of drift/interval (0 ≈
-  idle interpreter; 1.0 means wakes are delayed by a full interval).
+  idle interpreter; 1.0 means wakes are delayed by a full interval);
+- counters ``gil.samples`` and ``gil.oversleep_us``, WITHOUT the
+  label: a process has one interpreter, and a window's delta of the
+  second over the first is the mean time a thread that wanted the
+  interpreter in that window waited for it (two samplers in one
+  process both add, and the ratio is still that mean). A histogram and
+  a gauge cannot be read as a window's delta; these can (the
+  ``window`` line of every benchmark run carries both deltas). It is
+  a process-wide mean, not one span's wait: a span's own
+  ``obs.span_vcsw``, where the kernel counts, says how often it let go.
 
 Sampling is ``CELESTIA_OBS``-gated (the spans gate — `start` is a
 no-op when observability is off) and costs one mostly-sleeping thread
-per service: ~20 wakes/s of a few µs each (the interval sits well
+per label: ~20 wakes/s of a few µs each (the interval sits well
 above CPython's 5 ms switch interval on purpose — a probe at the
 switch interval competes for the GIL instead of observing it).
 
@@ -37,8 +49,10 @@ light round, a read — and nothing else in the tree sees them. One
 ``gc.callbacks`` entry (returns at once for the young generations)
 records each full collection as a span named ``gc.full`` in the span
 totals (obs/spans.py: ``obs.span_n{name="gc.full"}`` counts them,
-``obs.span_wall_us`` sums their pauses) and holds the ``gc.full``
-profiler annotation for as long. Same ``CELESTIA_OBS`` gate; no row
+``obs.span_wall_us`` sums their pauses, and the rest of a span's
+account — system time, faults, switches — rides along where the kernel
+keeps one) and holds the ``gc.full`` profiler annotation for as long.
+Same ``CELESTIA_OBS`` gate; no row
 per collection, and no lock: a collection starts inside any
 allocation, under any lock of the thread it interrupts.
 """
@@ -69,6 +83,14 @@ telemetry.set_help(
 telemetry.set_help(
     "gil.pressure",
     "EWMA of oversleep drift / requested interval (0=idle interpreter)",
+)
+telemetry.set_help(
+    "gil.samples", "wakes of this process's oversleep samplers"
+)
+telemetry.set_help(
+    "gil.oversleep_us",
+    "sum of the samplers' oversleep drift (microseconds); over "
+    "gil.samples = mean wait for the interpreter",
 )
 telemetry.set_help(
     "process.peak_rss_bytes", "peak resident set size of this process"
@@ -115,7 +137,7 @@ def _on_gc(phase: str, info: dict) -> None:
             _gc_open = spans.begin(GC_SPAN)
     elif _gc_open is not None:
         opened, _gc_open = _gc_open, None
-        spans.record_gc(*spans.close(opened))
+        spans.record_gc(spans.close(opened))
 
 
 gc.callbacks.append(_on_gc)
@@ -140,6 +162,9 @@ class _Sampler(threading.Thread):
             drift = (time.perf_counter() - t0) - INTERVAL_S  # lint: disable=det-wallclock — probe measurement, telemetry only
             drift = max(drift, 0.0)
             telemetry.observe("gil.oversleep", drift, labels=labels)
+            # the window's reading: unlabelled, one key a process
+            telemetry.incr("gil.samples")
+            telemetry.incr("gil.oversleep_us", round(drift * 1e6))
             self._ewma = 0.9 * self._ewma + 0.1 * (drift / INTERVAL_S)
             telemetry.gauge("gil.pressure", round(self._ewma, 6),
                             labels=labels)

@@ -27,16 +27,34 @@ node?".  Spans close that gap with three deliberate choices:
 Besides its row, every finished span lands in two more places, so that
 one instrument serves the operator, the benchmark and the profiler:
 
-- **Totals.** Per span name: occurrences, wall µs and thread-CPU µs
-  (wall − CPU is what the span spent waiting: the GIL, a lock, the
-  device). `record_total` keeps them in a table here (one lock
-  acquisition per finished span); a scrape-time collector publishes
-  them as the counters ``obs.span_n{name=…}`` /
-  ``obs.span_wall_us{name=…}`` / ``obs.span_cpu_us{name=…}``, so a
+- **Totals.** Per span name: occurrences, wall µs, thread-CPU µs
+  (``time.thread_time_ns``, exact to the nanosecond) and, WHERE THE
+  KERNEL KEEPS IT, the host's account of that thread while the span
+  was open — system µs (the kernel's half of the CPU time: page
+  faults, copies, fsync; sampled at the scheduler's tick, so one span's
+  is good to a tick and only a total is exact), minor and major page
+  faults, voluntary and involuntary context switches — from one
+  ``getrusage(RUSAGE_THREAD)`` at each end, read beside the CPU clock
+  (`begin` / `close`). wall − CPU is what the span spent off the
+  processor: every voluntary switch is one wait (the GIL, a lock, the
+  device, a file), and obs/gil.py's sampler says what getting the
+  interpreter back costs.
+  `record_total` keeps them in a table here (one lock acquisition per
+  finished span); a scrape-time collector publishes them as the
+  counters ``obs.span_n{name=…}`` / ``obs.span_wall_us`` /
+  ``obs.span_cpu_us`` / ``obs.span_sys_us`` / ``obs.span_minflt`` /
+  ``obs.span_majflt`` / ``obs.span_vcsw`` / ``obs.span_icsw``, so a
   window delta of ``telemetry.snapshot()["counters"]`` prices every
-  phase exactly. Transfers (obs/xfer.py, ``xfer.h2d:<site>``) feed the
+  phase exactly. A span's usage includes its children's (same thread,
+  nested reads). Transfers (obs/xfer.py, ``xfer.h2d:<site>``) feed the
   same table; full garbage collections (obs/gil.py, ``gc.full``) land
   beside it without a lock (`record_gc`) and are merged at the scrape.
+  The account is read only on a kernel that counts (`_host_account`:
+  Linux's ``RUSAGE_THREAD``, and a process whose own fault count is not
+  0). Elsewhere — non-Linux, and gVisor, which the benchmark's chip
+  host runs and which fills times in 10 ms ticks and no count — nothing
+  is read and the five families and row fields stay ABSENT: a 0 there
+  would say "no faults, no switches" of a host that cannot tell.
 - **The profiler's clock.** When ``jax`` is ALREADY loaded in the
   process (a host-engine process never imports it, and a span must not
   be what does) and a profiler session is recording (``POST
@@ -52,6 +70,7 @@ chip's host is in PERF.md §6 (PR 26).
 
 from __future__ import annotations
 
+import functools
 import hashlib
 import itertools
 import os
@@ -60,6 +79,25 @@ import threading
 import time
 
 from celestia_app_tpu.utils import telemetry
+
+
+def _host_account():
+    """The read of the calling thread's system time, page faults and
+    context switches (one system call), or None where the kernel keeps
+    no such account: non-Linux has no ``RUSAGE_THREAD``, and a sandboxed
+    kernel may fill the times and leave every count 0 (gVisor). No
+    interpreter starts without a page fault, so a process whose own
+    count reads 0 runs on a kernel that does not count."""
+    try:
+        from resource import RUSAGE_SELF, RUSAGE_THREAD, getrusage
+    except ImportError:
+        return None
+    if getrusage(RUSAGE_SELF).ru_minflt == 0:
+        return None
+    return functools.partial(getrusage, RUSAGE_THREAD)
+
+
+_thread_usage = _host_account()
 
 # the wire header every peer call carries while a span is active
 TRACE_HEADER = "X-Celestia-Trace"
@@ -93,16 +131,27 @@ def set_enabled(value: bool | None) -> None:
 
 # -- totals per span name (published as counters at scrape time) ------------
 
-_totals_lock = threading.Lock()
-# name -> [occurrences, wall ns, thread-CPU ns]
-_totals: dict[str, list[int]] = {}  # guarded-by: _totals_lock
-# name -> (occurrences, wall us, cpu us) already in the registry; only
-# the collector touches it, under its own lock (two concurrent scrapes
-# must not both publish one delta)
-_publish_lock = threading.Lock()
-_published: dict[str, tuple[int, int, int]] = {}  # guarded-by: _publish_lock
+# What `close` returns and the totals keep of one occurrence, in order:
+# wall ns, thread-CPU ns (user + system), then the host's account: system
+# ns, minor faults, major faults, voluntary and involuntary context
+# switches (all 0 where `_thread_usage` is None: published as absent).
+USAGE_FIELDS = ("wall_ns", "cpu_ns", "sys_ns", "minflt", "majflt", "vcsw",
+                "icsw")
+# occurrences, then one counter per usage field (times published in us)
+TOTAL_COUNTERS = ("obs.span_n", "obs.span_wall_us", "obs.span_cpu_us",
+                  "obs.span_sys_us", "obs.span_minflt", "obs.span_majflt",
+                  "obs.span_vcsw", "obs.span_icsw")
 
-TOTAL_COUNTERS = ("obs.span_n", "obs.span_wall_us", "obs.span_cpu_us")
+_totals_lock = threading.Lock()
+# name -> [occurrences, *USAGE_FIELDS]
+_totals: dict[str, list[int]] = {}  # guarded-by: _totals_lock
+# name -> the levels of TOTAL_COUNTERS already in the registry; only the
+# collector touches it, under its own lock (two concurrent scrapes must
+# not both publish one delta)
+_publish_lock = threading.Lock()
+_published: dict[str, tuple[int, ...]] = {}  # guarded-by: _publish_lock
+_NOTHING_PUBLISHED = (0,) * len(TOTAL_COUNTERS)
+_NO_ACCOUNT = (0,) * len(USAGE_FIELDS[2:])
 
 # Full collections (obs/gil.py's gc.callbacks hook) as one more name of
 # the totals, kept OUTSIDE the table: the collector runs wherever an
@@ -112,7 +161,7 @@ TOTAL_COUNTERS = ("obs.span_n", "obs.span_wall_us", "obs.span_cpu_us")
 # is the only writer; it swaps in a whole tuple (one store), which the
 # scrape reads in one load.
 GC_SPAN = "gc.full"
-_gc_total: tuple[int, int, int] = (0, 0, 0)  # (occurrences, wall ns, cpu ns)
+_gc_total: tuple[int, ...] = (0,) * len(TOTAL_COUNTERS)  # as a `_totals` row
 
 telemetry.set_help(
     "obs.span_n", "finished spans, transfers and full collections by name")
@@ -120,27 +169,51 @@ telemetry.set_help(
     "obs.span_wall_us", "wall microseconds spent inside spans of a name")
 telemetry.set_help(
     "obs.span_cpu_us",
-    "thread-CPU microseconds spent inside spans of a name "
-    "(wall - cpu = waiting: the GIL, a lock, the device)")
+    "thread-CPU microseconds (user + system) spent inside spans of a name "
+    "(wall - cpu = off the processor: the GIL, a lock, the device)")
+telemetry.set_help(
+    "obs.span_sys_us",
+    "system (kernel) microseconds of the thread inside spans of a name: "
+    "the part of obs.span_cpu_us spent faulting pages in, copying, syncing")
+telemetry.set_help(
+    "obs.span_minflt",
+    "minor page faults of the thread inside spans of a name "
+    "(a fresh 32 MiB buffer is 8,192 of 4 KiB)")
+telemetry.set_help(
+    "obs.span_majflt",
+    "major page faults (served from disk) inside spans of a name")
+telemetry.set_help(
+    "obs.span_vcsw",
+    "voluntary context switches inside spans of a name: each is one wait "
+    "(the GIL, a lock, the device, a file)")
+telemetry.set_help(
+    "obs.span_icsw",
+    "involuntary context switches inside spans of a name: the thread was "
+    "pre-empted while it could have run")
 
 
-def record_total(name: str, wall_ns: int, cpu_ns: int) -> None:
-    """One finished occurrence of `name` into the totals table."""
+def record_total(name: str, usage: tuple[int, ...]) -> None:
+    """One finished occurrence of `name`, as `close` returned it, into
+    the totals table."""
     with _totals_lock:
         t = _totals.get(name)
         if t is None:
-            _totals[name] = [1, wall_ns, cpu_ns]
+            _totals[name] = [1, *usage]
         else:
             t[0] += 1
-            t[1] += wall_ns
-            t[2] += cpu_ns
+            t[1] += usage[0]
+            t[2] += usage[1]
+            account = usage[2:]
+            if any(account):  # most spans fault, sleep and yield nothing
+                for i, v in enumerate(account, 3):
+                    t[i] += v
 
 
-def record_gc(wall_ns: int, cpu_ns: int) -> None:
+def record_gc(usage: tuple[int, ...]) -> None:
     """One finished full collection. Lock-free: see `_gc_total`."""
     global _gc_total
-    n, wall, cpu = _gc_total
-    _gc_total = (n + 1, wall + wall_ns, cpu + cpu_ns)
+    n, *levels = _gc_total
+    _gc_total = (n + 1, *map(int.__add__, levels, usage))
 
 
 def _publish_totals() -> None:
@@ -149,13 +222,15 @@ def _publish_totals() -> None:
     between scrapes costs nothing but the absolute level)."""
     with _publish_lock:
         with _totals_lock:
-            now = {name: (t[0], t[1] // 1000, t[2] // 1000)
-                   for name, t in _totals.items()}
-        n, wall_ns, cpu_ns = _gc_total
-        if n:
-            now[GC_SPAN] = (n, wall_ns // 1000, cpu_ns // 1000)
-        for name, levels in now.items():
-            before = _published.get(name, (0, 0, 0))
+            now = {name: tuple(t) for name, t in _totals.items()}
+        gc_total = _gc_total
+        if gc_total[0]:
+            now[GC_SPAN] = gc_total
+        for name, row in now.items():
+            n, wall_ns, cpu_ns, sys_ns, *counts = row
+            levels = (n, wall_ns // 1000, cpu_ns // 1000, sys_ns // 1000,
+                      *counts)
+            before = _published.get(name, _NOTHING_PUBLISHED)
             if levels == before:
                 continue
             labels = {"name": name}
@@ -202,34 +277,46 @@ def annotation(name: str):
 
 def begin(name: str):
     """Open one timed occurrence of `name` — a span's clocks without its
-    row: enters the profiler annotation and reads both clocks. Pair with
-    `end` (or `close`, in a `finally`, where the timed work can raise);
-    callers check `enabled()` first. Spans, transfers (obs/xfer.py) and
-    full collections (obs/gil.py) all time through it."""
+    row: enters the profiler annotation, reads both clocks and, where
+    the kernel keeps one, the thread's account. Pair with `end` (or
+    `close`, in a `finally`, where the timed work can raise); callers
+    check `enabled()` first. Spans, transfers (obs/xfer.py) and full
+    collections (obs/gil.py) all time through it."""
     ann = annotation(name)
     if ann is not None:
         ann.__enter__()
-    # the wall clock brackets the CPU clock, so cpu <= wall holds
-    return ann, time.perf_counter_ns(), time.thread_time_ns()
+    # the wall clock brackets the CPU clock, so cpu <= wall holds; the
+    # CPU clock brackets the account, so what it counts the span paid
+    usage = _thread_usage
+    return (ann, time.perf_counter_ns(), time.thread_time_ns(),
+            usage() if usage is not None else None)
 
 
-def close(opened) -> tuple[int, int]:
-    """Close what `begin` opened and record nothing: (wall ns, cpu ns).
+def close(opened) -> tuple[int, ...]:
+    """Close what `begin` opened and record nothing: its `USAGE_FIELDS`.
     Takes no lock (the collector's hook ends through it), and is how a
     caller whose timed work raised lets go of the annotation."""
-    ann, t0, cpu0 = opened
+    ann, t0, cpu0, u0 = opened
+    if u0 is None:  # no account on this host: the fields stay absent
+        account = _NO_ACCOUNT
+    else:
+        u1 = _thread_usage()
+        # system time arrives as float seconds of whole us
+        account = (round((u1.ru_stime - u0.ru_stime) * 1e6) * 1000,
+                   u1.ru_minflt - u0.ru_minflt, u1.ru_majflt - u0.ru_majflt,
+                   u1.ru_nvcsw - u0.ru_nvcsw, u1.ru_nivcsw - u0.ru_nivcsw)
     cpu_ns = time.thread_time_ns() - cpu0
     wall_ns = time.perf_counter_ns() - t0
     if ann is not None:
         ann.__exit__(None, None, None)
-    return wall_ns, cpu_ns
+    return (wall_ns, cpu_ns, *account)
 
 
-def end(name: str, opened) -> tuple[int, int]:
-    """Close what `begin` opened into the totals; (wall ns, cpu ns)."""
-    wall_ns, cpu_ns = close(opened)
-    record_total(name, wall_ns, cpu_ns)
-    return wall_ns, cpu_ns
+def end(name: str, opened) -> tuple[int, ...]:
+    """Close what `begin` opened into the totals; its `USAGE_FIELDS`."""
+    usage = close(opened)
+    record_total(name, usage)
+    return usage
 
 
 def trace_id_for(chain_id: str, height: int) -> str:
@@ -246,6 +333,10 @@ def _stack() -> list:
     if st is None:
         st = _tls.stack = []
     return st
+
+
+# the row's names for the four counts of `USAGE_FIELDS` (beside `sys_ms`)
+ROW_COUNTS = USAGE_FIELDS[3:]
 
 
 class Span:
@@ -275,7 +366,12 @@ class Span:
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
-        wall_ns, cpu_ns = end(self.name, self._opened)
+        wall_ns, cpu_ns, sys_ns, *counts = end(self.name, self._opened)
+        # the host's account rides the row only where it is non-zero: a
+        # quiet span's row is as long as it was
+        account = {"sys_ms": round(sys_ns / 1e6, 3)} if sys_ns > 0 else {}
+        if any(counts):
+            account.update((k, v) for k, v in zip(ROW_COUNTS, counts) if v)
         st = _stack()
         if st and st[-1] is self:
             st.pop()
@@ -293,6 +389,7 @@ class Span:
                 start_unix=round(self.start_unix, 6),
                 dur_ms=round(wall_ns / 1e6, 3),
                 cpu_ms=round(cpu_ns / 1e6, 3),
+                **account,
                 **self.attrs,
             )
         except Exception:

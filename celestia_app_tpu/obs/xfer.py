@@ -42,7 +42,10 @@ memory traffic first, then optimize:
   profiler annotation for its lifetime under the name
   ``xfer.h2d:<site>`` / ``xfer.d2h:<site>`` — so the benchmark prices
   the upload and the download of one site beside the spans around
-  them, and a profiler trace shows them on the device's clock.
+  them, and a profiler trace shows them on the device's clock. They
+  time through `spans.begin` / `end`, so the host's account of the
+  calling thread (system time, page faults, context switches: on a
+  kernel that keeps one) rides along under the same names.
 
 - **Copies started ahead of their need.** `HostFetch(value, site)`
   begins a device→host copy on a thread of its own; whoever needs the

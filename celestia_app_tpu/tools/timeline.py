@@ -148,9 +148,29 @@ def _depths(rows: list[dict]) -> dict[str, int]:
     return {sid: depth(row) for sid, row in by_id.items()}
 
 
+def _host_account(row: dict) -> str:
+    """`cpu … sys … flt … sw …` for a span row that carries any of the
+    host's account (obs/spans.py writes `sys_ms`, `minflt`, `majflt`,
+    `vcsw`, `icsw` only where non-zero): which span of which height
+    faulted pages in, let go of the processor or was pre-empted."""
+    flt, maj = row.get("minflt", 0), row.get("majflt", 0)
+    vol, inv = row.get("vcsw", 0), row.get("icsw", 0)
+    if not ("sys_ms" in row or flt or maj or vol or inv):
+        return ""
+    parts = [f"cpu {row.get('cpu_ms', 0.0):.1f}"]
+    if "sys_ms" in row:
+        parts.append(f"sys {row['sys_ms']:.1f}")
+    if flt or maj:
+        parts.append(f"flt {flt}" + (f"+{maj}maj" if maj else ""))
+    if vol or inv:
+        parts.append(f"sw {vol}v+{inv}i")
+    return "  (" + " ".join(parts) + ")"
+
+
 def render_waterfall(rows: list[dict], width: int = BAR_WIDTH) -> str:
     """One trace's rows -> a text waterfall: offset from the earliest
-    span, indentation by parent depth, a proportional bar, node label."""
+    span, indentation by parent depth, a proportional bar, the host's
+    account where a row carries one, node label."""
     if not rows:
         return "(no spans)"
     t0 = min(r.get("start_unix", 0.0) for r in rows)
@@ -186,7 +206,7 @@ def render_waterfall(rows: list[dict], width: int = BAR_WIDTH) -> str:
         node = row.get("node", "")
         lines.append(
             f"{off_s * 1e3:8.1f}ms {row.get('dur_ms', 0.0):8.2f}ms "
-            f"|{bar}| {indent}{name}"
+            f"|{bar}| {indent}{name}" + _host_account(row)
             + (f"  [{node}]" if node else "")
         )
     return "\n".join(lines)

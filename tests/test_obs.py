@@ -19,7 +19,9 @@ The ISSUE-4 acceptance stories:
 import json
 import os
 import re
+import resource
 import sys
+import threading
 import urllib.request
 
 import numpy as np
@@ -325,6 +327,14 @@ def test_das_sample_roundtrip_joins_the_block_trace(tmp_path):
 # ---------------------------------------------------------------------------
 
 TOTAL_FAMILIES = ("obs.span_n", "obs.span_wall_us", "obs.span_cpu_us")
+# the host's account of a span (ISSUE 38): one getrusage(RUSAGE_THREAD) at
+# each end beside the thread-CPU clock, on a kernel that keeps the account
+ACCOUNT_FAMILIES = ("obs.span_sys_us", "obs.span_minflt", "obs.span_majflt",
+                    "obs.span_vcsw", "obs.span_icsw")
+ACCOUNT_ROW_FIELDS = ("sys_ms", "minflt", "majflt", "vcsw", "icsw")
+# the kernel samples the user/system split at its tick (1-10 ms by its HZ),
+# so one span's sys_ms is good to a tick; cpu_ms is the exact clock's
+SYS_TICK_MS = 10.0
 # the benchmark's own span names (benchmark/generators): a program span of
 # one of these names would be added into the benchmark's row on the trace
 BENCHMARK_SPAN_NAMES = {"window", "produce_block", "broadcast_txs",
@@ -392,6 +402,39 @@ def test_full_collection_is_a_span_in_the_totals():
     assert _totals("gc.full")[0] == n1
 
 
+def _account(name: str) -> dict[str, int]:
+    counters = telemetry.snapshot()["counters"]
+    return {family: counters.get(f'{family}{{name="{name}"}}', 0)
+            for family in ACCOUNT_FAMILIES}
+
+
+def _fresh_32mib_faults() -> int:
+    """The least minor faults a first-touched 32 MiB buffer must book:
+    8,192 pages of 4 KiB — 16 of 2 MiB where transparent huge pages are
+    on for every mapping."""
+    try:
+        with open("/sys/kernel/mm/transparent_hugepage/enabled",
+                  encoding="ascii") as f:
+            always = "[always]" in f.read()
+    except OSError:
+        always = False
+    return 8 if always else 8_000
+
+
+FRESH_32MIB_FAULTS = _fresh_32mib_faults()
+
+
+class _FreshPagesWhenCollected:
+    """Garbage only a full collection finds, whose finalizer first-touches
+    a 32 MiB buffer: page faults INSIDE the collection."""
+
+    def __init__(self):
+        self.me = self
+
+    def __del__(self):
+        bytearray(32 << 20)
+
+
 def _run_with_deadline(fn, seconds=20.0):
     """`fn` on a thread of its own; False if it is still running at the
     deadline (a thread that waits for a lock it holds never returns)."""
@@ -416,16 +459,25 @@ def test_full_collection_under_a_held_lock_does_not_deadlock(lock_of):
     its lock held: the gc hook may take none of them."""
     import gc
 
+    from celestia_app_tpu.obs import spans
+
     lock = lock_of()
     n0 = _totals("gc.full")[0]
+    flt0 = _account("gc.full")["obs.span_minflt"]
 
     def collect_while_holding():
+        _FreshPagesWhenCollected()
         with lock:
             gc.collect()
 
     assert _run_with_deadline(collect_while_holding), \
         "the gc hook waited for a lock its own thread holds"
     assert _totals("gc.full")[0] == n0 + 1
+    # the wider tuple is still one store: the collection's own account
+    # (its finalizer's fresh 32 MiB) lands under gc.full with no lock —
+    # on a kernel that keeps one, and stays absent elsewhere
+    flt = _account("gc.full")["obs.span_minflt"] - flt0
+    assert flt >= FRESH_32MIB_FAULTS if spans._thread_usage else flt == 0
 
 
 def test_scrapes_and_spans_survive_collections_at_every_allocation():
@@ -525,6 +577,375 @@ def test_span_annotation_lands_in_a_profiler_trace(tmp_path):
         pytest.skip("this backend's profiler writes no host plane")
     names = {e.name for p in host for line in p.lines for e in line.events}
     assert "bench.annotated.probe" in names
+
+
+# -- the host's account of a span (ISSUE 38) ---------------------------------
+
+def _kernel_keeps_the_account() -> bool:
+    """The program's own probe (`spans._host_account`): Linux's
+    RUSAGE_THREAD, and a process whose own fault count is not 0 — gVisor
+    (the chips' hosts) has the attribute and counts nothing."""
+    from celestia_app_tpu.obs import spans
+
+    return spans._thread_usage is not None
+
+
+needs_host_account = pytest.mark.skipif(
+    not _kernel_keeps_the_account(),
+    reason="this kernel keeps no per-thread account (non-Linux, gVisor)")
+
+
+@pytest.fixture(scope="module")
+def accounted():
+    """One parent span over a child that first-touches 32 MiB and a
+    sibling that does nothing; then a span that waits while a second
+    thread's span first-touches 32 MiB of its own. Counter growth per
+    name and the rows."""
+    import time
+
+    names = ("acct.parent", "acct.touch", "acct.quiet", "acct.waiter",
+             "acct.other_thread", "acct.sleeps")
+    before = {n: _account(n) for n in names}
+    tt = telemetry.TraceTables()
+    with obs.span("acct.parent", traces=tt):
+        with obs.span("acct.touch", traces=tt):
+            bytearray(32 << 20)
+        with obs.span("acct.quiet", traces=tt):
+            pass
+
+    def other():
+        with obs.span("acct.other_thread", traces=tt):
+            bytearray(32 << 20)
+
+    t = threading.Thread(target=other)
+    with obs.span("acct.waiter", traces=tt):
+        t.start()
+        t.join(60)
+    assert not t.is_alive()
+    with obs.span("acct.sleeps", traces=tt):
+        for _ in range(3):
+            time.sleep(0.002)
+    grown = {n: {f: v - before[n][f] for f, v in _account(n).items()}
+             for n in names}
+    return grown, {r["name"]: r for r in tt.read("spans")}
+
+
+# (span, family, least, most) of the counters' growth; None = no bound
+ACCOUNT_CASES = [
+    # the faults and their system time land under the span that touched
+    ("acct.touch", "obs.span_minflt", FRESH_32MIB_FAULTS, None),
+    ("acct.touch", "obs.span_sys_us", 1, None),
+    # ... not under a sibling's
+    ("acct.quiet", "obs.span_minflt", 0, 0),
+    ("acct.quiet", "obs.span_sys_us", 0, 0),
+    # a span on a second thread books that thread's usage only
+    ("acct.other_thread", "obs.span_minflt", FRESH_32MIB_FAULTS, None),
+    ("acct.waiter", "obs.span_minflt", 0, 200),
+    # waiting for the other thread is (at least) one voluntary switch
+    ("acct.waiter", "obs.span_vcsw", 1, None),
+    ("acct.sleeps", "obs.span_vcsw", 3, None),
+]
+
+
+@needs_host_account
+@pytest.mark.parametrize(
+    "name,family,least,most", ACCOUNT_CASES,
+    ids=[f"{n}-{f.rsplit('_', 1)[1]}" for n, f, _l, _m in ACCOUNT_CASES])
+def test_span_account_lands_under_the_span_that_paid(
+        accounted, name, family, least, most):
+    grown = accounted[0][name][family]
+    assert grown >= least, (name, accounted[0][name])
+    if most is not None:
+        assert grown <= most, (name, accounted[0][name])
+
+
+@needs_host_account
+@pytest.mark.parametrize("family", ["obs.span_minflt", "obs.span_sys_us",
+                                    "obs.span_vcsw", "obs.span_icsw",
+                                    "obs.span_majflt"])
+def test_a_childs_account_is_inside_its_parents(accounted, family):
+    grown = accounted[0]
+    assert grown["acct.parent"][family] >= \
+        grown["acct.touch"][family] + grown["acct.quiet"][family]
+
+
+@needs_host_account
+@pytest.mark.parametrize("field", ACCOUNT_ROW_FIELDS)
+def test_row_carries_an_account_field_only_when_non_zero(accounted, field):
+    rows = accounted[1]
+    # a quiet span's row is as long as it was before the account existed
+    assert field not in rows["acct.quiet"], rows["acct.quiet"]
+    loud = {"sys_ms": "acct.touch", "minflt": "acct.touch",
+            "vcsw": "acct.sleeps"}.get(field)
+    if loud is not None:
+        assert rows[loud][field] > 0
+        # the row and the totals say the same of the one occurrence
+        family = ACCOUNT_FAMILIES[ACCOUNT_ROW_FIELDS.index(field)]
+        grown = accounted[0][loud][family]
+        assert rows[loud][field] == (
+            pytest.approx(grown / 1000, abs=0.002) if field == "sys_ms"
+            else grown)
+    for row in rows.values():
+        assert row.get(field, 1) != 0     # never written as a zero
+
+
+@needs_host_account
+def test_span_cpu_is_the_exact_clock_and_system_time_lies_inside_it():
+    """What `obs.span_cpu_us` means did not change, nor how finely it is
+    read: `time.thread_time_ns`, to the nanosecond (user + system of
+    RUSAGE_THREAD is the same run time only as of the scheduler's last
+    tick, so it does not stand in for the clock). The system half comes
+    from the account and is good to a tick."""
+    import time
+
+    from celestia_app_tpu.obs import spans
+
+    c0 = time.thread_time_ns()
+    opened = spans.begin("acct.cpu")
+    inner0 = time.thread_time_ns()
+    bytearray(32 << 20)                        # system time
+    t_end = time.perf_counter() + 0.05
+    while time.perf_counter() < t_end:         # user time
+        pass
+    inner = time.thread_time_ns() - inner0
+    wall_ns, cpu_ns, sys_ns, *_counts = spans.close(opened)
+    outer = time.thread_time_ns() - c0
+    assert inner <= cpu_ns <= outer            # no tick of slack
+    assert 0 < sys_ns <= cpu_ns + SYS_TICK_MS * 1e6
+    assert cpu_ns <= wall_ns
+
+
+@pytest.mark.parametrize("field", ("cpu",) + ACCOUNT_ROW_FIELDS)
+def test_where_the_kernel_keeps_no_account_the_fields_stay_absent(
+        field, monkeypatch):
+    """Non-Linux and gVisor: the thread-CPU clock as ever, and the five
+    new families and row fields absent — not 0."""
+    import time
+
+    from celestia_app_tpu.obs import spans
+
+    monkeypatch.setattr(spans, "_thread_usage", None)
+    name = f"acct.no_account.{field}"
+    tt = telemetry.TraceTables()
+    with obs.span(name, traces=tt):
+        bytearray(32 << 20)
+        t_end = time.perf_counter() + 0.005
+        while time.perf_counter() < t_end:
+            pass
+    row = tt.read("spans")[-1]
+    counters = telemetry.snapshot()["counters"]
+    if field == "cpu":
+        assert row["cpu_ms"] > 0
+        assert counters[f'obs.span_cpu_us{{name="{name}"}}'] > 0
+    else:
+        family = ACCOUNT_FAMILIES[ACCOUNT_ROW_FIELDS.index(field)]
+        assert field not in row
+        assert f'{family}{{name="{name}"}}' not in counters
+
+
+@pytest.mark.parametrize("host,keeps", [
+    ("linux", True), ("gvisor", False), ("no-rusage-thread", False)])
+def test_the_account_is_read_only_on_a_kernel_that_counts(
+        host, keeps, monkeypatch):
+    """The one-time probe: gVisor has RUSAGE_THREAD and fills times only,
+    so the attribute alone would publish false zeros there. No
+    interpreter starts without a page fault: a process whose own count
+    reads 0 runs on a kernel that does not count."""
+    from types import SimpleNamespace
+
+    from celestia_app_tpu.obs import spans
+
+    if host == "no-rusage-thread":
+        monkeypatch.delattr(resource, "RUSAGE_THREAD", raising=False)
+    else:
+        monkeypatch.setattr(resource, "RUSAGE_THREAD", 1, raising=False)
+        faults = 1079 if host == "linux" else 0
+        monkeypatch.setattr(
+            resource, "getrusage",
+            lambda who: SimpleNamespace(ru_minflt=faults, who=who))
+    usage = spans._host_account()
+    assert (usage is not None) is keeps
+    if keeps:  # and what it reads is the calling THREAD's
+        assert usage().who == resource.RUSAGE_THREAD
+
+
+@needs_host_account
+def test_begin_and_close_read_each_clock_and_the_account_once(monkeypatch):
+    """One rusage read and one thread-CPU clock read at each end."""
+    import time
+
+    from celestia_app_tpu.obs import spans
+
+    reads = []
+    usage, clock = spans._thread_usage, time.thread_time_ns
+    monkeypatch.setattr(spans, "_thread_usage",
+                        lambda: reads.append("usage") or usage())
+    monkeypatch.setattr(time, "thread_time_ns",
+                        lambda: reads.append("cpu") or clock())
+    with obs.span("acct.one_read", traces=telemetry.TraceTables()):
+        pass
+    # the CPU clock brackets the account, the wall clock both
+    assert reads == ["cpu", "usage", "usage", "cpu"]
+
+
+@pytest.mark.parametrize("row,says", [
+    ({"cpu_ms": 37.94, "sys_ms": 20.81, "minflt": 8193, "vcsw": 3,
+      "icsw": 1}, "(cpu 37.9 sys 20.8 flt 8193 sw 3v+1i)"),
+    ({"cpu_ms": 0.4, "majflt": 2}, "(cpu 0.4 flt 0+2maj)"),
+    ({"cpu_ms": 1.2, "vcsw": 5}, "(cpu 1.2 sw 5v+0i)"),
+    ({"cpu_ms": 1.2}, None),
+], ids=["loud", "major", "switches", "quiet"])
+def test_timeline_appends_the_hosts_account_to_a_row_that_carries_one(
+        row, says):
+    from celestia_app_tpu.tools import timeline
+
+    text = timeline.render_waterfall([{
+        "trace_id": "t", "span_id": "s1", "parent_id": None,
+        "name": "square.build", "start_unix": 1.0, "dur_ms": 40.0, **row}])
+    line = text.splitlines()[-1]
+    if says is None:
+        assert line.endswith("| square.build")
+    else:
+        assert line.endswith("| square.build  " + says)
+
+
+# -- the GIL sampler as a window counter (ISSUE 38) --------------------------
+
+
+def _no_sampler_running(gil, seconds=2.0):
+    import time
+
+    gil.stop_all()
+    deadline = time.time() + seconds
+    while time.time() < deadline and _sampler_threads():
+        time.sleep(0.01)
+
+
+def _sampler_threads() -> list[str]:
+    return sorted(t.name for t in threading.enumerate()
+                  if t.name.startswith("gil-sampler-") and t.is_alive())
+
+
+def _gil_window(seconds: float) -> tuple[int, int]:
+    """(samples, oversleep us) the samplers added in `seconds`."""
+    import time
+
+    def levels():
+        c = telemetry.snapshot()["counters"]
+        return c.get("gil.samples", 0), c.get("gil.oversleep_us", 0)
+
+    n0, us0 = levels()
+    time.sleep(seconds)
+    n1, us1 = levels()
+    return n1 - n0, us1 - us0
+
+
+@pytest.fixture
+def tmp_app(tmp_path):
+    from celestia_app_tpu.chain.app import App
+
+    app = App(chain_id="gil38", engine="host", data_dir=str(tmp_path / "d"))
+    try:
+        yield app
+    finally:
+        app.close()
+
+
+@pytest.mark.parametrize("first,built,running", [
+    (None, 3, ["node"]),      # however many nodes: one sampler
+    ("das", 2, ["das"]),      # none beside a service's that started first
+    ("node", 2, ["node"]),    # the node service's own label: the same one
+    (None, 0, []),            # no node, no sampler
+], ids=["nodes-only", "service-first", "node-service-first", "no-node"])
+def test_a_process_that_builds_nodes_runs_one_gil_sampler(
+        tmp_app, first, built, running):
+    from celestia_app_tpu.chain.node import Node
+    from celestia_app_tpu.obs import gil
+
+    _no_sampler_running(gil)
+    try:
+        if first is not None:
+            assert gil.start(first) is True
+        nodes = [Node(tmp_app) for _ in range(built)]
+        assert len(nodes) == built
+        assert gil.running() == running
+        assert _sampler_threads() == [f"gil-sampler-{s}" for s in running]
+    finally:
+        _no_sampler_running(gil)
+
+
+def test_node_starts_no_sampler_with_observability_off(tmp_app):
+    from celestia_app_tpu.chain.node import Node
+    from celestia_app_tpu.obs import gil
+
+    _no_sampler_running(gil)
+    obs.set_enabled(False)
+    try:
+        Node(tmp_app)
+        assert gil.running() == [] and _sampler_threads() == []
+    finally:
+        obs.set_enabled(None)
+
+
+@pytest.mark.parametrize("key", ["gil.samples", "gil.oversleep_us"])
+def test_sampler_publishes_unlabelled_window_counters(key):
+    """A window reads counters, and a metric file names ONE key whichever
+    service the process runs: no `service` label on these two."""
+    from celestia_app_tpu.obs import gil
+
+    _no_sampler_running(gil)
+    try:
+        assert gil.start("t-window") is True
+        samples, _us = _gil_window(gil.INTERVAL_S * 4)
+        assert samples >= 2
+        counters = telemetry.snapshot()["counters"]
+        assert key in counters
+        assert not [k for k in counters if k.startswith(key + "{")]
+        # the operator's histogram and gauge stay, labelled (FORMATS 22)
+        snap = telemetry.snapshot()
+        assert 'gil.oversleep{service="t-window"}' in snap["timers"]
+        assert 'gil.pressure{service="t-window"}' in snap["gauges"]
+    finally:
+        _no_sampler_running(gil)
+
+
+def test_window_mean_oversleep_rises_under_busy_threads():
+    """oversleep_us / samples over a window is the mean time a thread
+    that wanted the interpreter waited for it: four busy-looping threads
+    at CPython's 5 ms switch interval push it up by several ms."""
+    import time
+
+    from celestia_app_tpu.obs import gil
+
+    _no_sampler_running(gil)
+    stop = threading.Event()
+
+    def spin(deadline):
+        x = 0
+        while not stop.is_set() and time.perf_counter() < deadline:
+            x += 1
+
+    try:
+        assert gil.start("t-busy") is True
+        time.sleep(gil.INTERVAL_S)           # past the first, partial wake
+        n_idle, us_idle = _gil_window(0.6)
+        # their own time limit: the spinners stop whatever the test does
+        deadline = time.perf_counter() + 20.0
+        spinners = [threading.Thread(target=spin, args=(deadline,),
+                                     daemon=True) for _ in range(4)]
+        for t in spinners:
+            t.start()
+        n_busy, us_busy = _gil_window(1.5)
+        stop.set()
+        for t in spinners:
+            t.join(10)
+        assert not any(t.is_alive() for t in spinners)
+        assert n_idle >= 3 and n_busy >= 3, (n_idle, n_busy)
+        assert us_busy / n_busy - us_idle / n_idle >= 5_000, (
+            (n_idle, us_idle), (n_busy, us_busy))
+    finally:
+        stop.set()
+        _no_sampler_running(gil)
 
 
 def test_no_program_span_is_named_like_a_benchmark_span():
