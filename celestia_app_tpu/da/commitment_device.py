@@ -53,7 +53,7 @@ from celestia_app_tpu.da import commitment as commitment_mod
 from celestia_app_tpu.da import shares as shares_mod
 from celestia_app_tpu.da.blob import Blob
 from celestia_app_tpu.ops import nmt, pow2_bucket
-from celestia_app_tpu.utils import merkle_host, telemetry
+from celestia_app_tpu.utils import hostbuf, merkle_host, telemetry
 
 NS = appconsts.NAMESPACE_SIZE
 SHARE = appconsts.SHARE_SIZE
@@ -118,7 +118,7 @@ def _pack(blobs: list[Blob], subtree_root_threshold: int):
         starts.append(aligned_start(cursor, w))
         cursor = starts[-1] + n
     width = max(widths)
-    buf = np.zeros((max(pow2_bucket(cursor), width), SHARE), dtype=np.uint8)
+    buf = hostbuf.lease_zeroed(max(pow2_bucket(cursor), width), SHARE)
     offsets = level_offsets(buf.shape[0], width.bit_length())
     picks, per_blob = [], []
     for blob, start, n, w in zip(blobs, starts, counts, widths):

@@ -35,7 +35,7 @@ from celestia_app_tpu.da import shares as shares_mod
 from celestia_app_tpu.da.blob import Blob
 from celestia_app_tpu.da.commitment import round_up_pow2, subtree_width
 from celestia_app_tpu.da.shares import Share, uvarint
-from celestia_app_tpu.utils import telemetry
+from celestia_app_tpu.utils import hostbuf, telemetry
 
 
 def next_share_index(cursor: int, blob_share_count: int, subtree_root_threshold: int) -> int:
@@ -177,7 +177,7 @@ class _Layout:
 def _export(layout: _Layout, k: int) -> Square:
     """Write the shares of a computed layout into one (k*k, 512) array."""
     assert layout.total <= k * k, "layout exceeds its square"
-    out = np.zeros((k * k, appconsts.SHARE_SIZE), dtype=np.uint8)
+    out = hostbuf.lease_zeroed(k * k, appconsts.SHARE_SIZE)
     cursor = 0
     if layout.tx_shares:
         cursor += shares_mod.write_txs(out, 0, ns_mod.TX_NAMESPACE, layout.txs)

@@ -112,3 +112,35 @@ def test_commitment_equals_the_tree_definition(size):
         for threshold in (64, 8, 1):
             assert create_commitment(blob, threshold) \
                 == _reference_commitment(blob, threshold), (size, threshold)
+
+
+@pytest.mark.parametrize("threshold", [64, 8])
+def test_device_commitments_of_two_batches_over_one_held_buffer(
+        monkeypatch, threshold):
+    """With the pool's size constant patched down to the batches' pack
+    buffer: the second batch packs into the array the first one wrote, and
+    stale rows of the first never reach the second's roots."""
+    from celestia_app_tpu.da import commitment_device
+    from celestia_app_tpu.da.commitment import create_commitments
+    from celestia_app_tpu.utils import hostbuf, telemetry
+
+    rng = np.random.default_rng(threshold)
+
+    def blobs(sizes):
+        return [Blob(ns_mod.Namespace.v0(bytes([1 + i]) * 5),
+                     rng.integers(0, 256, s, dtype=np.uint8).tobytes())
+                for i, s in enumerate(sizes)]
+
+    # both batches pad to 64 rows; the second leaves rows the first wrote
+    first, second = blobs([9_000, 14_000, 700]), blobs([300, 20_000])
+    monkeypatch.setattr(hostbuf, "HELD_FROM_BYTES", 64 * 512)
+    monkeypatch.setattr(hostbuf, "_held", [])
+    c0 = telemetry.snapshot()["counters"]
+    for batch in (first, second, first):
+        assert commitment_device._pack(batch, threshold)[0].shape == (64, 512)
+        assert commitment_device.commitments_device(batch, threshold) \
+            == create_commitments(batch, threshold)
+    c1 = telemetry.snapshot()["counters"]
+    assert c1.get("hostbuf.leases", 0) - c0.get("hostbuf.leases", 0) == 6
+    assert c1.get("hostbuf.reuses", 0) - c0.get("hostbuf.reuses", 0) >= 4
+    assert len(hostbuf._held) <= 2

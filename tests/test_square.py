@@ -443,3 +443,58 @@ def test_construct_leaves_few_tracked_objects():
     used = max(sq.blob_start_indexes.values())
     assert used > 0.8 * 32 * 32
     assert left < 32 * 32 // 10, left
+
+
+def _two_blocks():
+    """Two different blocks of one square size (8x8), the second with
+    fewer shares than the first so stale rows would show as wrong padding."""
+    rng = np.random.default_rng(39)
+    first = ([b"tx-a" * 40], [PfbEntry(b"pfb-%d" % i,
+                                       (_blob(rng, 2 + i, 6_000),))
+                              for i in range(4)])
+    second = ([b"tx-b" * 9], [PfbEntry(b"pfb-x", (_blob(rng, 7, 16_000),))])
+    return first, second
+
+
+@pytest.mark.parametrize("first_square", ["kept", "dropped"])
+@pytest.mark.parametrize("lay_out", ["build", "construct"])
+def test_consecutive_squares_over_the_held_pool(monkeypatch, lay_out,
+                                                first_square):
+    """With the pool's size constant patched down to an 8x8 square: a
+    `Square` somebody holds is never rewritten by the next layout; a dropped
+    one hands its array on, and the next square equals the one an unpatched
+    run lays out."""
+    from celestia_app_tpu.utils import hostbuf
+
+    fn = getattr(square_mod, lay_out)
+    (txs1, pfbs1), (txs2, pfbs2) = _two_blocks()
+    want1 = fn(txs1, pfbs1, 64, THRESHOLD)
+    want2 = fn(txs2, pfbs2, 64, THRESHOLD)
+    assert want1.size == want2.size == 8
+
+    monkeypatch.setattr(hostbuf, "HELD_FROM_BYTES", 8 * 8 * 512)
+    monkeypatch.setattr(hostbuf, "_held", [])
+    c0 = telemetry.snapshot()["counters"]
+
+    def moved(name):
+        return telemetry.snapshot()["counters"].get(name, 0) - c0.get(name, 0)
+
+    sq1 = fn(txs1, pfbs1, 64, THRESHOLD)
+    assert np.array_equal(sq1.ods, want1.ods)
+    at = sq1.ods.ctypes.data
+    if first_square == "dropped":
+        del sq1
+    sq2 = fn(txs2, pfbs2, 64, THRESHOLD)
+    assert np.array_equal(sq2.ods, want2.ods)
+    assert not sq2.ods.flags.writeable
+    if first_square == "kept":
+        assert sq2.ods.ctypes.data != at
+        assert np.array_equal(sq1.ods, want1.ods)
+        assert not sq1.ods.flags.writeable
+        with pytest.raises(ValueError):
+            sq1.ods[0, 0, 0] = 1
+        assert moved("hostbuf.fresh") == 2 and moved("hostbuf.reuses") == 0
+    else:
+        assert sq2.ods.ctypes.data == at
+        assert moved("hostbuf.fresh") == 1 and moved("hostbuf.reuses") == 1
+    assert moved("hostbuf.leases") == 2
