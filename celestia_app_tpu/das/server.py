@@ -68,7 +68,9 @@ of a fresh height pay one build between them.
 from __future__ import annotations
 
 import collections
+import json
 import threading
+from urllib.parse import parse_qs, urlparse
 
 from celestia_app_tpu.da import codec as codec_mod
 from celestia_app_tpu.da import edscache as edscache_mod
@@ -342,16 +344,29 @@ class SampleCore:
         (row/col roots) under rs2d-nmt — now with a "scheme" member old
         clients ignore — or the CMT parameter/root-hash doc (FORMATS
         §16.2). Either binds to the certified data root."""
-        entry = self._entry(height)
-        codec = codec_mod.get(entry.scheme)
-        doc = {"height": height,
-               **codec.commitments_doc(entry.cache_entry)}
-        # pack advertisement (§17.2): zero-extra-round-trip discovery —
-        # a sampler that just fetched commitments knows whether (and how)
-        # this height is servable as static bytes. Old clients ignore it.
-        pack = self._pack_advert(entry)
-        if pack is not None:
-            doc["pack"] = pack
+        from celestia_app_tpu import obs
+
+        # the light node's first request of a height (4k roots: ≈ 184 KB
+        # of hex at k = 256 once encoded), in the height's trace like
+        # das.serve_sample
+        with obs.span(
+            "das.header",
+            traces=getattr(self.app, "traces", None),
+            trace_id=obs.trace_id_for(
+                getattr(self.app, "chain_id", ""), height),
+            height=height,
+        ):
+            entry = self._entry(height)
+            codec = codec_mod.get(entry.scheme)
+            doc = {"height": height,
+                   **codec.commitments_doc(entry.cache_entry)}
+            # pack advertisement (§17.2): zero-extra-round-trip discovery
+            # — a sampler that just fetched commitments knows whether
+            # (and how) this height is servable as static bytes. Old
+            # clients ignore it.
+            pack = self._pack_advert(entry)
+            if pack is not None:
+                doc["pack"] = pack
         return doc
 
     def _gate(self, entry: _Entry, row: int, col: int) -> None:
@@ -427,9 +442,10 @@ class SampleCore:
         from celestia_app_tpu import obs
 
         # serve-side span of the DAS round-trip: the height's
-        # deterministic trace id matches the sampling light node's, and
-        # the incoming X-Celestia-Trace header (begin_request) makes the
-        # sampler's fetch span this span's remote parent
+        # deterministic trace id matches the sampling light node's; over
+        # HTTP the incoming X-Celestia-Trace header (begin_request) makes
+        # the sampler's fetch span the remote parent of the front's
+        # das.http.request (serve_http), under which this span nests
         with obs.span(
             "das.serve_sample",
             traces=getattr(self.app, "traces", None),
@@ -754,6 +770,77 @@ def route_das(core: SampleCore, method: str, path: str,
     raise SampleError(f"no DAS route {method} {path}")
 
 
+def serve_http(handler, core: SampleCore, method: str) -> None:
+    """Answer one /das/* request on a ``BaseHTTPRequestHandler`` — THE
+    front both transports answer through (the node service's /das/
+    branch and the `SampleService` sidecar), so their bodies are
+    byte-identical and one account prices them (docs/FORMATS.md §10.1):
+
+      das.http.request   parsed request line -> last byte written; attrs
+                         method, route, status, bytes_in, bytes_out
+        das.http.decode  POST only: the body read and ``json.loads``
+        (the route's own spans: das.header, das.serve_sample -> ...)
+        das.http.encode  ``json.dumps`` of the reply (not for raw bytes)
+        das.http.write   status line, headers and body to the socket
+
+    Counters ``das.http_requests``, ``das.http_bytes_in`` (request
+    bodies), ``das.http_bytes_out`` (reply bodies) and ``das.http_errors``
+    (every reply that is not a 200). A malformed request is a 4xx
+    (`SampleError`); only a fault of the server is a 500."""
+    from celestia_app_tpu import obs
+
+    parsed = urlparse(handler.path)
+    bytes_in = 0
+    # beside the serving spans in the app's tables; a request that
+    # carries its height's X-Celestia-Trace (a light node's does) nests
+    # the route's spans under this one, rows and all
+    with obs.span("das.http.request",
+                  traces=getattr(core.app, "traces", None), method=method,
+                  route=parsed.path) as sp:
+        try:
+            payload = None
+            if method == "POST":
+                with obs.span("das.http.decode"):
+                    try:
+                        n = int(handler.headers.get("Content-Length", 0))
+                        raw = handler.rfile.read(n) if n > 0 else b""
+                        bytes_in = len(raw)
+                        payload = json.loads(raw or b"{}")
+                    except ValueError:
+                        raise SampleError("body must be JSON") from None
+                if not isinstance(payload, dict):
+                    raise SampleError("body must be a JSON object")
+            out = route_das(core, method, parsed.path,
+                            parse_qs(parsed.query), payload)
+            status = 200
+        except SampleError as e:
+            status = 404 if "not served" in str(e) else 400
+            out = {"error": str(e)}
+        except Exception as e:  # never kill the serving thread
+            telemetry.incr("das.server_errors")
+            telemetry.incr("http.500")
+            status, out = 500, {"error": f"{type(e).__name__}: {e}"}
+        if isinstance(out, bytes):
+            # /das/pack/chunk: raw static bytes (octet-stream, NOT base64)
+            body, ctype = out, "application/octet-stream"
+        else:
+            with obs.span("das.http.encode"):
+                body = json.dumps(out).encode()
+            ctype = "application/json"
+        with obs.span("das.http.write"):
+            handler.send_response(status)
+            handler.send_header("Content-Type", ctype)
+            handler.send_header("Content-Length", str(len(body)))
+            handler.end_headers()
+            handler.wfile.write(body)
+        sp.set(status=status, bytes_in=bytes_in, bytes_out=len(body))
+    telemetry.incr("das.http_requests")
+    telemetry.incr("das.http_bytes_in", bytes_in)
+    telemetry.incr("das.http_bytes_out", len(body))
+    if status != 200:
+        telemetry.incr("das.http_errors")
+
+
 class SampleService:
     """Standalone HTTP server for the DAS routes — the das-serve sidecar:
     point it at a full node's home and it answers samplers with no chain
@@ -761,12 +848,10 @@ class SampleService:
 
     def __init__(self, core: SampleCore, host: str = "127.0.0.1",
                  port: int = 26660):
-        import json
         from http.server import (
             BaseHTTPRequestHandler,
             ThreadingHTTPServer,
         )
-        from urllib.parse import parse_qs, urlparse
 
         service = self
         self.core = core
@@ -779,51 +864,11 @@ class SampleService:
             def log_message(self, fmt, *args):
                 pass
 
-            def _send(self, code: int, obj) -> None:
-                body = json.dumps(obj).encode()
-                self.send_response(code)
-                self.send_header("Content-Type", "application/json")
-                self.send_header("Content-Length", str(len(body)))
-                self.end_headers()
-                self.wfile.write(body)
-
-            def _send_raw(self, code: int, body: bytes) -> None:
-                # /das/pack/chunk serves raw bytes (octet-stream, NOT
-                # base64) — the static CDN-shaped path
-                self.send_response(code)
-                self.send_header("Content-Type",
-                                 "application/octet-stream")
-                self.send_header("Content-Length", str(len(body)))
-                self.end_headers()
-                self.wfile.write(body)
-
-            def _route(self, method: str, payload: dict | None) -> None:
-                parsed = urlparse(self.path)
-                try:
-                    out = route_das(service.core, method, parsed.path,
-                                    parse_qs(parsed.query), payload)
-                    if isinstance(out, bytes):
-                        self._send_raw(200, out)
-                    else:
-                        self._send(200, out)
-                except SampleError as e:
-                    self._send(404 if "not served" in str(e) else 400,
-                               {"error": str(e)})
-                except Exception as e:  # never kill the serving thread
-                    telemetry.incr("das.server_errors")
-                    self._send(500, {"error": f"{type(e).__name__}: {e}"})
-
             def do_GET(self):
-                self._route("GET", None)
+                serve_http(self, service.core, "GET")
 
             def do_POST(self):
-                try:
-                    n = int(self.headers.get("Content-Length", 0))
-                    payload = json.loads(self.rfile.read(n) or b"{}")
-                except ValueError:
-                    self._send(400, {"error": "body must be JSON"})
-                    return
-                self._route("POST", payload)
+                serve_http(self, service.core, "POST")
 
         class Server(ThreadingHTTPServer):
             # sampler fleets connect in bursts; the stdlib default

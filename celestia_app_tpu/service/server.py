@@ -41,6 +41,7 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
 from celestia_app_tpu import obs
 from celestia_app_tpu.chain.query import QueryError, QueryRouter
+from celestia_app_tpu.das.server import serve_http
 from celestia_app_tpu.utils import telemetry
 
 
@@ -169,27 +170,9 @@ class NodeService:
                         self._send(200, obs.route_trace(
                             service.node.app.traces, self.path))
                     elif self.path.startswith("/das/"):
-                        from urllib.parse import parse_qs, urlparse
-
-                        from celestia_app_tpu.das.server import (
-                            SampleError,
-                            route_das,
-                        )
-
-                        parsed = urlparse(self.path)
-                        try:
-                            out = route_das(
-                                service.das_core, "GET", parsed.path,
-                                parse_qs(parsed.query),
-                            )
-                            if isinstance(out, bytes):
-                                # /das/pack/chunk: raw static bytes
-                                self._send_raw(200, out)
-                            else:
-                                self._send(200, out)
-                        except SampleError as e:
-                            self._send(404 if "not served" in str(e)
-                                       else 400, {"error": str(e)})
+                        # the DAS front, spanned and counted: ONE helper
+                        # with the das-serve sidecar (das/server.py)
+                        serve_http(self, service.das_core, "GET")
                     elif self.path.startswith("/blob/"):
                         # the read plane (das/blob_server.py): namespace
                         # reads + blob-pack static serving; BlobError is
@@ -264,6 +247,10 @@ class NodeService:
                     self._send(500, {"error": f"{type(e).__name__}: {e}"})
 
             def _post(self):
+                if self.path.startswith("/das/"):
+                    # the DAS front reads its own body (das/server.py)
+                    serve_http(self, service.das_core, "POST")
+                    return
                 try:
                     n = int(self.headers.get("Content-Length", 0))
                     payload = json.loads(self.rfile.read(n) or b"{}")
@@ -316,22 +303,6 @@ class NodeService:
                                 self.path, payload))
                         except DAError as e:
                             self._send(400, {"error": str(e)})
-                    elif self.path.startswith("/das/"):
-                        from urllib.parse import urlparse
-
-                        from celestia_app_tpu.das.server import (
-                            SampleError,
-                            route_das,
-                        )
-
-                        try:
-                            self._send(200, route_das(
-                                service.das_core, "POST",
-                                urlparse(self.path).path, {}, payload,
-                            ))
-                        except SampleError as e:
-                            self._send(404 if "not served" in str(e)
-                                       else 400, {"error": str(e)})
                     elif self.path.startswith("/blob/"):
                         from urllib.parse import urlparse
 

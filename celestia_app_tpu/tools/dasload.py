@@ -21,7 +21,11 @@ concurrency:
 Output (and the ``run_load`` return value) is one JSON report:
 ``samples_per_sec``, ``requests_per_sec``, ``p50_ms``/``p99_ms`` per
 request, ``pack_hit_ratio``, error counts; docs/FORMATS.md §17.5 is the
-schema. No benchmark cell runs it: not measured on the chip.
+schema. The benchmark cell ``bigblock-k256-das-http`` (PR 40) times the
+same fleet model on the chip — 128 keep-alive samplers in 8 client
+processes against ``NodeService``'s port — with a client of its own
+(``benchmark/generators/http_sampler_client.py``), not this module, so
+that no change to the program can move the yardstick.
 
 Standalone use against any devnet:
 

@@ -309,11 +309,14 @@ def test_das_sample_roundtrip_joins_the_block_trace(tmp_path):
             by_name.setdefault(r["name"], []).append(r)
         assert "prepare_proposal" in by_name   # the proposer's side
         assert "das.sample_height" in by_name  # the light node's side
-        # header propagation: the serve span's remote parent is one of
-        # the light node's fetch spans
+        # header propagation: one of the light node's fetch spans is the
+        # remote parent of the serving node's HTTP request span
+        # (das/server.serve_http), under which the serve span nests
         fetch_ids = {r["span_id"] for r in by_name["das.fetch_cells"]}
+        request_ids = {r["span_id"] for r in by_name["das.http.request"]
+                       if r["parent_id"] in fetch_ids}
         serve_parents = {r["parent_id"] for r in by_name["das.serve_sample"]}
-        assert serve_parents & fetch_ids, (serve_parents, fetch_ids)
+        assert serve_parents & request_ids, (serve_parents, request_ids)
         # and the waterfall renders both processes in one timeline
         text = timeline.render_waterfall(trace)
         assert "das.serve_sample" in text and "das.sample_height" in text
@@ -1010,7 +1013,8 @@ BLOCK_PATH = [
     ("pool.recheck", "block.produce", 1),
     ("da.prover_warm", None, 1),
     ("proof.levels.run", "da.prover_warm", 2),       # row and column
-    ("das.entry_build", None, 1),
+    ("das.header", None, 1),                          # a light round's
+    ("das.entry_build", "das.header", 1),             # first request
     ("das.app_lock_wait", "das.entry_build", 1),
     ("query.rebuild_square", "das.entry_build", 1),
     ("storage.load_block", "query.rebuild_square", 1),
@@ -1064,7 +1068,7 @@ def test_block_path_stays_inside_the_span_budget(device_block):
     transfers = 4   # up and down: the extend, then each of two level passes
     assert len(under("block.produce")) + len(under("da.prover_warm")) \
         + transfers <= 40
-    for request in ("das.entry_build", "das.serve_sample",
+    for request in ("das.header", "das.serve_sample",
                     "blob.namespaces_many"):
         assert 1 <= len(under(request)) <= 6
     warm = [r for r in rows if r["name"] == "da.prover_warm"]
