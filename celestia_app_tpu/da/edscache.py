@@ -58,7 +58,8 @@ protocols; this module is that amortization:
   sampled cells is proved where the entry's bytes are
   (``prove_cells``): from the host copy where one exists or was
   started, else cut on the chip(s) by one program that brings only the
-  shares and their proof nodes down. The single-device engine also
+  shares and their proof nodes down — one program for every request of
+  an orientation that waits at the same time. The single-device engine also
   STARTS the square's host copy right after the run
   (``obs/xfer.HostFetch``): a served height needs those bytes, and
   started there they land in the shadow of process → commit instead of
@@ -79,6 +80,7 @@ docs/DESIGN.md "The block plane" and "The mesh plane".
 from __future__ import annotations
 
 import collections
+import functools
 import hashlib
 import os
 import threading
@@ -287,6 +289,115 @@ class EdsCacheEntry:
             return row_ready and self._col_prover is not None
 
 
+class _Waiter:
+    """One request's cells in a `GatherQueue`, and where its answer comes
+    back: its rows, the dispatch's exception, or the dispatcher's role."""
+
+    __slots__ = ("cells", "woken", "lead", "shares", "nodes", "error")
+
+    def __init__(self, cells):
+        self.cells = cells
+        self.woken = threading.Event()
+        self.lead = False
+        self.shares = self.nodes = self.error = None
+
+
+class GatherQueue:
+    """The gathers of ONE entry and orientation that wait together go
+    out as one dispatch (flat combining, as a group commit batches
+    writes).
+
+    A request's thread queues its cells. If no dispatch is in flight it
+    becomes the dispatcher at once, for everything queued, itself
+    included: no timer, no batching window, so a lone request goes out
+    immediately, alone. Requests that arrive while a dispatch is in
+    flight wait for the next one. When a dispatch ends, its dispatcher
+    hands each request of it its rows of the answer (or the dispatch's
+    exception), hands the dispatcher's role to the first request still
+    queued, and returns with its own rows. A dispatch carries whole
+    requests, at most `cap` cells of them — a request larger than that
+    goes alone — so a longer queue goes out in several.
+
+    Holds no reference to its entry: the dispatcher passes `dispatch`
+    with its call, and its frame keeps the entry alive while its batch
+    is in flight."""
+
+    def __init__(self, cap: int):
+        self.cap = cap
+        self._lock = threading.Lock()
+        self._queued: collections.deque[_Waiter] = \
+            collections.deque()  # guarded-by: _lock
+        self._dispatching = False  # guarded-by: _lock
+
+    def queued(self) -> int:
+        """Requests waiting for a dispatch (not those in flight)."""
+        with self._lock:
+            return len(self._queued)
+
+    def submit(self, cells, dispatch):
+        """`cells`' (shares, nodes) host rows, out of the dispatch that
+        carries them: `dispatch(requests)` takes the queued cell lists
+        and returns the rows of all of them in that order. Raises what
+        that dispatch raised."""
+        me = _Waiter(cells)
+        with self._lock:
+            self._queued.append(me)
+            lead = not self._dispatching
+            self._dispatching = True
+        if not lead:
+            me.woken.wait()
+            lead = me.lead
+        if lead:
+            self._lead(dispatch)
+        if me.error is not None:
+            raise me.error
+        return me.shares, me.nodes
+
+    def _take(self) -> list[_Waiter]:
+        """The requests at the head of the queue that one dispatch
+        carries: at least one, then while the cells fit `cap`."""
+        batch, cells = [], 0
+        with self._lock:
+            while self._queued and (
+                    not batch
+                    or cells + len(self._queued[0].cells) <= self.cap):
+                batch.append(self._queued.popleft())
+                cells += len(batch[-1].cells)
+        return batch
+
+    def _lead(self, dispatch) -> None:
+        """One dispatch for the head of the queue (the caller is its
+        first request), its rows to each request of it, and the role to
+        the next request queued — or the queue idle."""
+        batch = []
+        try:
+            batch = self._take()
+            try:
+                shares, nodes = dispatch([w.cells for w in batch])
+            except BaseException as e:
+                for w in batch:
+                    w.error = e
+                if not isinstance(e, Exception):
+                    raise
+            else:
+                at = 0
+                for w in batch:
+                    end = at + len(w.cells)
+                    w.shares, w.nodes = shares[at:end], nodes[at:end]
+                    at = end
+        finally:
+            with self._lock:
+                nxt = self._queued[0] if self._queued else None
+                if nxt is None:
+                    self._dispatching = False
+                else:
+                    nxt.lead = True
+            for w in batch:
+                w.woken.set()
+            if nxt is not None:
+                nxt.woken.set()
+
+
 class DeviceEntry(EdsCacheEntry):
     """The entry of every device-class engine: its big arrays live on
     device.
@@ -314,7 +425,9 @@ class DeviceEntry(EdsCacheEntry):
       read as ever; an entry whose square lives only on the chip(s)
       gathers each cell's share and sibling nodes there
       (``gather_cells``: da/proof_device's gather, inside a
-      ``shard_map`` over a sharded square) and stays "device".
+      ``shard_map`` over a sharded square) and stays "device"; the
+      requests for one orientation that wait at the same time go out
+      as one dispatch (``GatherQueue``).
     - The single-device engine hands over ``eds_fetch``, the host copy
       of the square it STARTED right after the run (obs/xfer
       ``HostFetch``): ``.eds`` then waits for what is left of that
@@ -345,6 +458,11 @@ class DeviceEntry(EdsCacheEntry):
         self._col_levels_lock = threading.Lock()
         self._levels_dev = None  # guarded-by: _levels_lock
         self._col_levels_dev = None  # guarded-by: _col_levels_lock
+        from celestia_app_tpu.da import proof_device
+
+        # the gathers waiting together, row orientation then column
+        self._gathers = tuple(GatherQueue(proof_device.MAX_GATHER_BUCKET)
+                              for _ in range(2))
 
     @classmethod
     def from_commitments(cls, eds_dev, rows, cols, root, eds_fetch=None):
@@ -554,15 +672,19 @@ class DeviceEntry(EdsCacheEntry):
 
     def gather_cells(self, cells, col: bool = False):
         """[(share bytes, NmtRangeProof)] cut out of the resident square
-        and the orientation's resident level stack by ONE program — each
-        cell's share and the log2(2k) sibling nodes of its path — of
-        which only the answer comes down (n x (512 + L x 90) B through
-        the ledger site `proof.gather`): nothing materializes, the entry
-        stays "device". The level stack is the warmer's (build-once
-        under its lock), or this call's if it arrives first. The bytes
-        are `BlockProver.prove_cell`'s for every cell."""
-        import jax
+        and the orientation's resident level stack — each cell's share
+        and the log2(2k) sibling nodes of its path — of which only the
+        answer comes down: nothing materializes, the entry stays
+        "device". The bytes are `BlockProver.prove_cell`'s for every
+        cell.
 
+        The cells go into the orientation's `GatherQueue` and out in ONE
+        program with every other request's that waits for the same
+        entry and orientation (`_gather_dispatch`); the span
+        ``das.gather_wait`` covers a request from its enqueue until its
+        rows are in hand, and the proofs are assembled from them here,
+        on the caller's own thread. A range error is raised before
+        anything is queued."""
         from celestia_app_tpu.da import proof_device
 
         width = 2 * self.k
@@ -571,23 +693,51 @@ class DeviceEntry(EdsCacheEntry):
             if not (0 <= r < width and 0 <= c < width):
                 raise ValueError(
                     f"cell ({r}, {c}) outside the {width}x{width} square")
-        # below the roots: a path's top node is a child of the root
-        levels = list(self._device_levels(col))[:-1]
-        index = np.zeros((2, proof_device.gather_bucket(len(cells))),
-                         dtype=np.int32)
-        index[0, :len(cells)] = [r for r, _ in cells]
-        index[1, :len(cells)] = [c for _, c in cells]
-        program, placement = proof_device.sample_gather_program(
-            self._eds_dev, self.k, col)
-        index_dev = xfer.to_device(index, "proof.gather",
-                                   placement=placement)
-        with obs.span("proof.gather.run", k=self.k, cells=len(cells),
+        with obs.span("das.gather_wait", k=self.k, cells=len(cells),
                       col=col):
-            answer = jax.block_until_ready(
-                program(self._eds_dev, levels, index_dev))
-        shares, nodes = xfer.to_host(answer, "proof.gather")
+            shares, nodes = self._gathers[col].submit(
+                cells, functools.partial(self._gather_dispatch, col))
         return proof_device.gathered_proofs(cells, col, width, shares,
                                             nodes)
+
+    def _gather_dispatch(self, col: bool, requests):
+        """ONE program for the cells of every request of `requests` (a
+        list of cell lists, in queue order): the orientation's level
+        stack (the warmer's, build-once under its lock, or this call's
+        if it arrives first), one (2, bucket) index up, the run, the
+        packed (n x (512 + L x 90) B) answer down through the ledger site
+        `proof.gather`. Returns the (shares, nodes) host rows in the
+        requests' order. The first dispatch of a (program, k) in the
+        process runs every bucket once first (`warm_gather_buckets`)."""
+        import jax
+
+        from celestia_app_tpu.da import proof_device
+
+        cells = [cell for request in requests for cell in request]
+        with obs.span("das.gather", k=self.k, cells=len(cells),
+                      requests=len(requests), col=col, chips=self.chips):
+            # below the roots: a path's top node is a child of the root
+            levels = list(self._device_levels(col))[:-1]
+            program, placement = proof_device.sample_gather_program(
+                self._eds_dev, self.k, col)
+            proof_device.warm_gather_buckets(program, placement,
+                                             self._eds_dev, levels, self.k)
+            index = np.zeros((2, proof_device.gather_bucket(len(cells))),
+                             dtype=np.int32)
+            index[0, :len(cells)] = [r for r, _ in cells]
+            index[1, :len(cells)] = [c for _, c in cells]
+            index_dev = xfer.to_device(index, "proof.gather",
+                                       placement=placement)
+            with obs.span("proof.gather.run", k=self.k, cells=len(cells),
+                          col=col):
+                answer = jax.block_until_ready(
+                    program(self._eds_dev, levels, index_dev))
+            shares, nodes = xfer.to_host(answer, "proof.gather")
+        telemetry.incr("das.gather_dispatches")
+        # every request of the batch but the dispatcher's own joined it
+        telemetry.incr("das.gather_joined", len(requests) - 1)
+        telemetry.incr("das.samples_gathered", len(cells))
+        return shares, nodes
 
 
 def compute_entry(ods: np.ndarray, engine: str = "auto",

@@ -19,6 +19,7 @@ machinery.
 from __future__ import annotations
 
 import functools
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -113,11 +114,45 @@ def _jitted_sharded_levels(mesh, axis: str, k: int, col: bool):
 # than a light node's round (celestia-node samples 16 cells a header):
 # one shape a height for every batch a sampler sends
 MIN_GATHER_BUCKET = 16
+# the most cells one dispatch carries when it combines the requests that
+# wait together (da/edscache.GatherQueue): 128 light rounds
+MAX_GATHER_BUCKET = 2048
 
 
 def gather_bucket(n_cells: int) -> int:
     """Cells a gather of `n_cells` is padded to."""
     return max(MIN_GATHER_BUCKET, 1 << (n_cells - 1).bit_length())
+
+
+def gather_buckets() -> list[int]:
+    """Every bucket a combined dispatch can choose, 16 … 2,048."""
+    return [1 << e for e in range(MIN_GATHER_BUCKET.bit_length() - 1,
+                                  MAX_GATHER_BUCKET.bit_length())]
+
+
+_warm_lock = threading.Lock()
+# (program, k) pairs whose every bucket has run once in this process
+_warmed_gathers: set = set()  # guarded-by: _warm_lock
+
+
+def warm_gather_buckets(program, placement, eds, levels, k: int) -> None:
+    """Run `program` once at every bucket of `gather_buckets()` over this
+    square and level stack (cell (0, 0) throughout), the first time a
+    (program, k) is asked for in the process — so whatever a later
+    dispatch combines, it compiles nothing. Concurrent first callers
+    wait for the one warming."""
+    from celestia_app_tpu.obs import xfer
+
+    # build-once serialization: the compiles ARE what a later caller
+    # must not start again
+    with _warm_lock:  # lint: disable=blocking-under-lock
+        if (program, k) in _warmed_gathers:
+            return
+        for bucket in gather_buckets():
+            index = xfer.to_device(np.zeros((2, bucket), dtype=np.int32),
+                                   "proof.gather", placement=placement)
+            jax.block_until_ready(program(eds, levels, index))
+        _warmed_gathers.add((program, k))
 
 
 def _cells_held(eds: jax.Array, levels, cells: jax.Array, col: bool, first):

@@ -9,7 +9,8 @@ device pass `da/proof_device.BlockProver` already runs (ops/nmt.nmt_levels
 levels on host engines); each served proof is then pure index arithmetic
 — over host arrays where the height has them, or as one gather on the
 chip(s) for a height whose square lives only there (a mesh-engine
-height: `_Entry.prove_cells`, span `das.gather`).
+height: `_Entry.prove_cells`; the requests that wait together go out in
+one dispatch, span `das.gather`).
 Entries sit behind a bounded LRU keyed by height — the same discipline as
 the DA service's square cache (service/da_service.DACore).
 
@@ -140,20 +141,15 @@ class _Entry:
         prover's first touch of a height under ``das.build_provers`` —
         then index arithmetic.
         An entry whose square lives only on the chip(s) cuts the batch
-        there in one program (``das.gather``) and builds no prover."""
+        there and builds no prover: in one program with every other
+        request for the entry and axis that waits at the same time
+        (``das.gather_wait``, and ``das.gather`` a dispatch)."""
         entry = self.cache_entry
         if entry.proves_on_host(col):
             if not col:
                 _ = self.prover  # first touch, spanned
             return entry.prove_cells(cells, col=col, engine=self.engine)
-        from celestia_app_tpu import obs
-
-        with obs.span("das.gather", height=self.height, cells=len(cells),
-                      col=col, chips=entry.chips):
-            proved = entry.gather_cells(cells, col=col)
-        telemetry.incr("das.gather_dispatches")
-        telemetry.incr("das.samples_gathered", len(cells))
-        return proved
+        return entry.gather_cells(cells, col=col)
 
 
 class _Build:

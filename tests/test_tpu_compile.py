@@ -19,7 +19,7 @@ program leaves sharded (k=128 / 256, both orientations: the case the chips
 refused until PR 36, whose refusal is kept as a case too), and the gather
 of a light round's cells and proof nodes out of that square and its level
 stacks (PR 37: four chips at k=128 / 256, both orientations; one chip at
-k=128).
+k=128), also at the largest bucket a combined dispatch carries.
 
 Rules this file keeps (they are what lets it run under `pytest -n 6`):
 the topology is described ONLY inside the module fixture (one process at
@@ -262,23 +262,26 @@ def test_plain_level_pass_is_refused_for_a_sharded_square(seq_mesh):
             _u8((2 * k, 2 * k, 512), placed)).compile()
 
 
-def _gather_shapes(k, placed, cells_at):
+def _gather_shapes(k, placed, cells_at, n_cells=16):
     """The gather's arguments at size k: the square, one orientation's
     level stack below the roots (2k trees of 2k >> l nodes: min, max,
-    hash) and a light round's sixteen cells."""
+    hash) and `n_cells` cells (a light round's sixteen by default)."""
     square = _u8((2 * k, 2 * k, 512), placed)
     levels = [tuple(_u8((2 * k, (2 * k) >> level, width), placed)
                     for width in (29, 29, 32))
               for level in range((2 * k).bit_length() - 1)]
-    cells = jax.ShapeDtypeStruct((2, 16), jnp.int32, sharding=cells_at)
+    cells = jax.ShapeDtypeStruct((2, n_cells), jnp.int32, sharding=cells_at)
     return square, levels, cells
 
 
+@pytest.mark.parametrize("cells", [16, 2048])
 @pytest.mark.parametrize("col", [False, True], ids=["rows", "cols"])
 @pytest.mark.parametrize("k", [128, 256])
-def test_sample_gather_under_the_mesh_entrys_sharding(seq_mesh, k, col):
-    """A light round's sixteen cells cut out of the square and the level
-    stack the four chips hold (da/proof_device.
+def test_sample_gather_under_the_mesh_entrys_sharding(seq_mesh, k, col,
+                                                      cells):
+    """A light round's sixteen cells — and the largest bucket a combined
+    dispatch carries, 2,048 cells of 128 rounds — cut out of the square
+    and the level stack the four chips hold (da/proof_device.
     _jitted_sharded_sample_gather): no kernel to partition, ONE
     all-reduce of the packed answer, and no gather of the square or of a
     level stack to anywhere."""
@@ -290,11 +293,14 @@ def test_sample_gather_under_the_mesh_entrys_sharding(seq_mesh, k, col):
     from celestia_app_tpu.da import proof_device
     from celestia_app_tpu.parallel.mesh import SEQ_AXIS
 
+    assert cells in (proof_device.MIN_GATHER_BUCKET,
+                     proof_device.MAX_GATHER_BUCKET)
     mesh, placed = seq_mesh
     everywhere = NamedSharding(mesh, P())
     program = proof_device._jitted_sharded_sample_gather.__wrapped__(
         mesh, SEQ_AXIS, k, col)
-    compiled = _compile(program, *_gather_shapes(k, placed, everywhere),
+    compiled = _compile(program,
+                        *_gather_shapes(k, placed, everywhere, cells),
                         kernels=False,
                         in_shardings=(placed, placed, everywhere))
     text = compiled.as_text()
@@ -304,7 +310,7 @@ def test_sample_gather_under_the_mesh_entrys_sharding(seq_mesh, k, col):
     assert shares.is_fully_replicated and nodes.is_fully_replicated
     depth = (2 * k).bit_length() - 1
     assert compiled.memory_analysis().output_size_in_bytes < \
-        2 * 16 * (512 + depth * 90)
+        2 * cells * (512 + depth * 90)
 
 
 def test_sample_gather_on_one_chip(one_chip):
