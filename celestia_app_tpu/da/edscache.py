@@ -271,6 +271,13 @@ class EdsCacheEntry:
         prover = self.get_prover(engine)
         return [prover.prove_cell(r, c) for r, c in cells]
 
+    def namespace_reader(self, engine: str = "auto"):
+        """What reads a namespace of this height (da/namespace_device):
+        the row prover, for the host entry."""
+        from celestia_app_tpu.da import namespace_device
+
+        return namespace_device.ProverReader(self.get_prover(engine), engine)
+
     @property
     def k(self) -> int:
         return self.eds.width // 2
@@ -417,8 +424,8 @@ class DeviceEntry(EdsCacheEntry):
       left split by tree — a plain jit over it is refused by the TPU
       compiler, which partitions no Pallas kernel.
     - ``.eds`` / the provers materialize host bytes lazily, only when
-      asked for (share-range and tx proofs, namespace reads, the pack
-      builders); every device->host array fetch counts
+      asked for (share-range and tx proofs, the pack builders); every
+      device->host array fetch counts
       ``edscache.host_crossings``.
     - ``prove_cells`` — a sampler's batch — looks at what the entry
       holds: host bytes (a copy landed or started, a built prover) are
@@ -428,6 +435,10 @@ class DeviceEntry(EdsCacheEntry):
       ``shard_map`` over a sharded square) and stays "device"; the
       requests for one orientation that wait at the same time go out
       as one dispatch (``GatherQueue``).
+    - ``namespace_reader`` — a rollup's namespace read — chooses the
+      same way: the host prover where host bytes exist, else a search
+      over the resident row level stack and one row gather a namespace
+      (``gather_shares``), the entry still "device" after.
     - The single-device engine hands over ``eds_fetch``, the host copy
       of the square it STARTED right after the run (obs/xfer
       ``HostFetch``): ``.eds`` then waits for what is left of that
@@ -458,6 +469,7 @@ class DeviceEntry(EdsCacheEntry):
         self._col_levels_lock = threading.Lock()
         self._levels_dev = None  # guarded-by: _levels_lock
         self._col_levels_dev = None  # guarded-by: _col_levels_lock
+        self._root_proofs = None  # every axis root's proof (root_proofs)
         from celestia_app_tpu.da import proof_device
 
         # the gathers waiting together, row orientation then column
@@ -738,6 +750,78 @@ class DeviceEntry(EdsCacheEntry):
         telemetry.incr("das.gather_joined", len(requests) - 1)
         telemetry.incr("das.samples_gathered", len(cells))
         return shares, nodes
+
+    def namespace_reader(self, engine: str = "auto"):
+        """What reads a namespace of this height, chosen by what the entry
+        holds, as `prove_cells` chooses: the row prover where host bytes
+        exist (`proves_on_host`), else the chips where the square lives
+        (`namespace_device.ResidentReader`: the search over the resident
+        row level stack, then `gather_shares` / `gather_cells`) — after
+        which the entry is still "device"."""
+        from celestia_app_tpu.da import namespace_device
+
+        if self.proves_on_host():
+            return super().namespace_reader(engine)
+        return namespace_device.ResidentReader(self)
+
+    def root_proofs(self):
+        """The Merkle proof of every axis root under the data root (rows,
+        then columns), as `BlockProver` holds them: computed from the
+        host roots at first need and kept (a benign race computes the
+        same list twice)."""
+        if self._root_proofs is None:
+            from celestia_app_tpu.utils import merkle_host
+
+            _, self._root_proofs = merkle_host.proofs_from_leaves(
+                list(self.dah.row_roots) + list(self.dah.col_roots))
+        return self._root_proofs
+
+    def gather_shares(self, start: int, end: int, namespace: bytes):
+        """`BlockProver.prove_shares(start, end, namespace)` cut out of the
+        resident square and row level stack: ONE program (span
+        ``blob.ns.gather``) brings down the original half of each row the
+        range touches — padded to a power of two of rows — and the sibling
+        nodes of the paths of each row's first and last column, through
+        the ledger site ``proof.ns_gather``; neither the square nor a
+        level stack comes down. The first gather of a (program, k) in the
+        process runs every row bucket once (`warm_namespace_buckets`)."""
+        import jax
+
+        from celestia_app_tpu.da import proof_device
+
+        k = self.k
+        if not (0 <= start < end <= k * k):
+            raise ValueError(f"invalid share range [{start}, {end})")
+        first_row, last_row = start // k, (end - 1) // k
+        rows = last_row - first_row + 1
+        bucket = proof_device.namespace_row_bucket(rows)
+        index = np.full((3, bucket), -1, dtype=np.int32)
+        index[0, :rows] = np.arange(first_row, last_row + 1)
+        index[1, :rows] = 0
+        index[2, :rows] = k - 1
+        index[1, 0] = start - first_row * k
+        index[2, rows - 1] = end - 1 - last_row * k
+        depth = (2 * k).bit_length() - 1
+        with obs.span("blob.ns.gather", k=k, rows=rows,
+                      nodes=2 * depth * bucket, chips=self.chips):
+            # below the roots: a path's top node is a child of the root
+            levels = list(self._device_levels(False))[:-1]
+            program, placement = proof_device.namespace_gather_program(
+                self._eds_dev, k)
+            proof_device.warm_namespace_buckets(program, placement,
+                                                self._eds_dev, levels, k)
+            index_dev = xfer.to_device(index, "proof.ns_gather",
+                                       placement=placement)
+            with obs.span("proof.ns_gather.run", k=k, rows=rows):
+                answer = jax.block_until_ready(
+                    program(self._eds_dev, levels, index_dev))
+            words, nodes = xfer.to_host(answer, "proof.ns_gather")
+        telemetry.incr("blob.ns_gathers")
+        telemetry.incr("blob.rows_gathered", rows)
+        shares = words.view(np.uint8).reshape(bucket, k, -1)
+        return proof_device.gathered_share_proof(
+            start, end, k, shares, nodes, self.dah, self.root_proofs(),
+            namespace)
 
 
 def compute_entry(ods: np.ndarray, engine: str = "auto",

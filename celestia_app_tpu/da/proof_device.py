@@ -131,8 +131,10 @@ def gather_buckets() -> list[int]:
 
 
 _warm_lock = threading.Lock()
-# (program, k) pairs whose every bucket has run once in this process
+# (program, k) pairs whose every bucket has run once in this process —
+# the sample gathers', and the namespace row gathers'
 _warmed_gathers: set = set()  # guarded-by: _warm_lock
+_warmed_namespace_gathers: set = set()  # guarded-by: _warm_lock
 
 
 def warm_gather_buckets(program, placement, eds, levels, k: int) -> None:
@@ -242,14 +244,182 @@ def sample_gather_program(eds, k: int, col: bool):
             NamedSharding(mesh, P()))
 
 
+def namespace_row_buckets(k: int) -> list[int]:
+    """Every row count a namespace gather is padded to: the powers of
+    two from 1 to k (a namespace's range covers at most the k rows of
+    the original square)."""
+    return [1 << e for e in range(k.bit_length())]
+
+
+def namespace_row_bucket(rows: int) -> int:
+    """Rows a namespace gather of `rows` touched rows is padded to."""
+    return 1 << (rows - 1).bit_length()
+
+
+def _rows_held(eds: jax.Array, levels, index: jax.Array, k: int, first):
+    """Shares (R, k, 512) of the original half of each asked row, and
+    nodes (R, 2, L, 90) — the sibling at every level of the path of the
+    row's first asked column, then of its last — out of `eds.shape[0]`
+    rows of the square and as many trees of the row level stack, both
+    starting at global index `first`. `index` is (3, R) int32: row,
+    first column, last column; a row held elsewhere (or -1, padding)
+    reads zeros. A range proof over a row's columns [lo, hi] takes the
+    left siblings of lo's path and the right siblings of hi's
+    (`range_path_nodes`)."""
+    rows, lo, hi = index[0], index[1], index[2]
+    held = eds.shape[0]
+    local = rows - first
+    mine = (local >= 0) & (local < held)
+    at = jnp.where(mine, local, 0)
+    shares = jnp.where(mine[:, None, None], eds[at, :k], 0)
+
+    def siblings(level: int, triple, leaf):
+        return jnp.concatenate([part[at, (leaf >> level) ^ 1]
+                                for part in triple], axis=-1)
+
+    nodes = jnp.stack([
+        jnp.stack([siblings(level, triple, lo), siblings(level, triple, hi)],
+                  axis=1)
+        for level, triple in enumerate(levels)], axis=2)
+    nodes = jnp.where(mine[:, None, None, None], nodes, 0)
+    return shares, nodes
+
+
+def _as_words(shares: jax.Array) -> jax.Array:
+    """(R, k, 512) u8 -> (R, k, 128) u32, the same bytes: a minor
+    dimension of one tile row, which comes down to the host ~3 x faster
+    than 512 u8 (`.view(np.uint8)` there restores the bytes)."""
+    r, k, width = shares.shape
+    return lax.bitcast_convert_type(
+        shares.reshape(r, k, width // 4, 4), jnp.uint32)
+
+
+@functools.lru_cache(maxsize=None)
+def _jitted_namespace_gather(k: int):
+    """Compiled: a resident (2k, 2k, 512) EDS one chip holds, its row
+    level stack below the roots and a (3, R) row index -> the rows'
+    shares (as u32 words) and proof nodes."""
+
+    # named for the trace: jit_namespace_gather(...)
+    def namespace_gather(eds: jax.Array, levels, index: jax.Array):
+        shares, nodes = _rows_held(eds, levels, index, k, 0)
+        return _as_words(shares), nodes
+
+    return jax.jit(namespace_gather)
+
+
+@functools.lru_cache(maxsize=None)
+def _jitted_sharded_namespace_gather(mesh, axis: str, k: int):
+    """Compiled: `_jitted_namespace_gather` over a square whose rows, and
+    a row level stack whose trees, are split over `axis` of `mesh`,
+    inside a shard_map on that mesh. Each chip answers the rows it holds
+    and zeros for the rest; one all-reduce hands every chip the answer.
+    The shares are summed as u32 words — four bytes a word, exact since
+    one chip holds each byte and every other adds zero — so the
+    all-reduce moves the answer's own bytes, not four times them."""
+    per_chip = 2 * k // mesh.shape[axis]
+
+    def gather_local(eds_local, levels_local, index):
+        first = lax.axis_index(axis) * per_chip
+        shares, nodes = _rows_held(eds_local, levels_local, index, k, first)
+        words = lax.psum(_as_words(shares), axis)
+        nodes = lax.psum(nodes.astype(jnp.uint32), axis).astype(jnp.uint8)
+        return words, nodes
+
+    by_tree = P(axis, None, None)
+    sharded = jax.shard_map(gather_local, mesh=mesh,
+                            in_specs=(by_tree, by_tree, P()),
+                            out_specs=P(), check_vma=False)
+
+    # named for the trace: jit_mesh_namespace_gather(...)
+    def mesh_namespace_gather(eds: jax.Array, levels, index: jax.Array):
+        return sharded(eds, levels, index)
+
+    split = NamedSharding(mesh, by_tree)
+    return jax.jit(mesh_namespace_gather,
+                   in_shardings=(split, split, NamedSharding(mesh, P())))
+
+
+def namespace_gather_program(eds, k: int):
+    """The row gather over whatever holds `eds`, and where its (3, R)
+    index goes (None: the one chip a plain jit reads)."""
+    placed = rows_sharded_over(eds)
+    if placed is None:
+        return _jitted_namespace_gather(k), None
+    mesh, axis = placed
+    return (_jitted_sharded_namespace_gather(mesh, axis, k),
+            NamedSharding(mesh, P()))
+
+
+def warm_namespace_buckets(program, placement, eds, levels, k: int) -> None:
+    """Run the row gather once at every bucket of
+    `namespace_row_buckets(k)` (every row padding), the first time a
+    (program, k) is asked for in the process — so no later read compiles.
+    Concurrent first callers wait for the one warming."""
+    from celestia_app_tpu.obs import xfer
+
+    # build-once serialization: the compiles ARE what a later caller
+    # must not start again
+    with _warm_lock:  # lint: disable=blocking-under-lock
+        if (program, k) in _warmed_namespace_gathers:
+            return
+        for bucket in namespace_row_buckets(k):
+            index = xfer.to_device(np.full((3, bucket), -1, dtype=np.int32),
+                                   "proof.ns_gather", placement=placement)
+            jax.block_until_ready(program(eds, levels, index))
+        _warmed_namespace_gathers.add((program, k))
+
+
+def range_path_nodes(total: int, lo: int, hi: int) -> tuple[list, list]:
+    """Levels of the nodes of the range proof over leaves [lo, hi] (both
+    included) in proof order, as (left, right): the left siblings of lo's
+    path top-down — node (l, (lo >> l) ^ 1) — then the right siblings of
+    hi's path bottom-up — node (l, (hi >> l) ^ 1). What
+    `BlockProver._range_proof(row, lo, hi + 1)` walks."""
+    depth = total.bit_length() - 1
+    left = [lv for lv in reversed(range(depth)) if (lo >> lv) & 1]
+    right = [lv for lv in range(depth) if not (hi >> lv) & 1]
+    return left, right
+
+
+def gathered_share_proof(start: int, end: int, k: int, shares: np.ndarray,
+                         nodes: np.ndarray, dah: DataAvailabilityHeader,
+                         root_proofs, namespace: bytes) -> ShareProof:
+    """`BlockProver.prove_shares(start, end, namespace)` from a row
+    gather's host arrays: `shares` (R, k, 512) and `nodes` (R, 2, L, 90),
+    row i of them the range's (start // k + i)-th row."""
+    start_row, end_row = start // k, (end - 1) // k
+    total = 2 * k
+    data: list[bytes] = []
+    nmt_proofs: list[nmt_host.NmtRangeProof] = []
+    for i, row in enumerate(range(start_row, end_row + 1)):
+        col_start = start - row * k if row == start_row else 0
+        col_end = end - row * k if row == end_row else k
+        raw = shares[i, col_start:col_end].tobytes()
+        data += [raw[j:j + appconsts.SHARE_SIZE]
+                 for j in range(0, len(raw), appconsts.SHARE_SIZE)]
+        left, right = range_path_nodes(total, col_start, col_end - 1)
+        nmt_proofs.append(nmt_host.NmtRangeProof(
+            start=col_start, end=col_end, total=total,
+            nodes=[nodes[i, 0, lv].tobytes() for lv in left]
+            + [nodes[i, 1, lv].tobytes() for lv in right]))
+    row_proof = RowProof(
+        row_roots=[dah.row_roots[r] for r in range(start_row, end_row + 1)],
+        proofs=[root_proofs[r] for r in range(start_row, end_row + 1)],
+        start_row=start_row,
+        end_row=end_row,
+    )
+    return ShareProof(data=data, share_proofs=nmt_proofs,
+                      namespace=namespace, row_proof=row_proof,
+                      start_share=start, end_share=end)
+
+
 def single_leaf_path(total: int, leaf: int) -> list[int]:
     """Levels of a one-leaf range proof's nodes in proof order — what
     `BlockProver._range_proof(row, leaf, leaf + 1)` walks: the siblings
     left of the leaf top-down, then those right of it bottom-up. The node
     at level l is (l, (leaf >> l) ^ 1)."""
-    depth = total.bit_length() - 1
-    left = [lv for lv in reversed(range(depth)) if (leaf >> lv) & 1]
-    right = [lv for lv in range(depth) if not (leaf >> lv) & 1]
+    left, right = range_path_nodes(total, leaf, leaf)
     return left + right
 
 

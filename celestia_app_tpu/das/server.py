@@ -151,6 +151,15 @@ class _Entry:
             return entry.prove_cells(cells, col=col, engine=self.engine)
         return entry.gather_cells(cells, col=col)
 
+    def namespace_reader(self):
+        """What reads a namespace of this height (da/edscache
+        `namespace_reader`): the row prover — its first touch of a height
+        under ``das.build_provers`` — where the entry holds host bytes,
+        else the chips where its square lives, with no prover built."""
+        if self.cache_entry.proves_on_host():
+            _ = self.prover  # first touch, spanned
+        return self.cache_entry.namespace_reader(self.engine)
+
 
 class _Build:
     """One height's build in progress: its waiters take `entry` once
@@ -766,23 +775,27 @@ def route_das(core: SampleCore, method: str, path: str,
     raise SampleError(f"no DAS route {method} {path}")
 
 
-def serve_http(handler, core: SampleCore, method: str) -> None:
-    """Answer one /das/* request on a ``BaseHTTPRequestHandler`` — THE
-    front both transports answer through (the node service's /das/
-    branch and the `SampleService` sidecar), so their bodies are
+def serve_http(handler, core, method: str, route=route_das) -> None:
+    """Answer one /das/* request — or, with `route` das/blob_server's
+    `route_blob` and `core` a `BlobCore`, one /blob/* request — on a
+    ``BaseHTTPRequestHandler``: THE front every transport answers
+    through (the node service's /das/ and /blob/ branches, the
+    `SampleService` and `BlobService` sidecars), so their bodies are
     byte-identical and one account prices them (docs/FORMATS.md §10.1):
 
       das.http.request   parsed request line -> last byte written; attrs
                          method, route, status, bytes_in, bytes_out
         das.http.decode  POST only: the body read and ``json.loads``
-        (the route's own spans: das.header, das.serve_sample -> ...)
+        (the route's own spans: das.header, das.serve_sample,
+         blob.namespaces_many -> ...)
         das.http.encode  ``json.dumps`` of the reply (not for raw bytes)
         das.http.write   status line, headers and body to the socket
 
     Counters ``das.http_requests``, ``das.http_bytes_in`` (request
     bodies), ``das.http_bytes_out`` (reply bodies) and ``das.http_errors``
     (every reply that is not a 200). A malformed request is a 4xx
-    (`SampleError`); only a fault of the server is a 500."""
+    (`SampleError`, which `BlobError` is); only a fault of the server is
+    a 500."""
     from celestia_app_tpu import obs
 
     parsed = urlparse(handler.path)
@@ -806,8 +819,8 @@ def serve_http(handler, core: SampleCore, method: str) -> None:
                         raise SampleError("body must be JSON") from None
                 if not isinstance(payload, dict):
                     raise SampleError("body must be a JSON object")
-            out = route_das(core, method, parsed.path,
-                            parse_qs(parsed.query), payload)
+            out = route(core, method, parsed.path, parse_qs(parsed.query),
+                        payload)
             status = 200
         except SampleError as e:
             status = 404 if "not served" in str(e) else 400

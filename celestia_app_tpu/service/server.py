@@ -19,6 +19,10 @@ Endpoints:
   POST /da/prove_shares  {...}         share-range proof (§7.1.7 shim)
   GET  /das/head | /das/header | /das/sample | /das/availability
   POST /das/samples                    DAS sample serving (das/server.py)
+  GET  /blob/get | /blob/pack | /blob/pack/chunk
+  POST /blob/namespaces                namespace reads (das/blob_server.py);
+                                       /das/ and /blob/ answer through
+                                       das/server.serve_http
   GET  /sync/snapshots                 state-sync manifests, newest first
   GET  /sync/chunk?height=&index=      raw snapshot chunk bytes (§15)
   GET  /faults                         fault-plane admin (armed + fired)
@@ -41,6 +45,7 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
 from celestia_app_tpu import obs
 from celestia_app_tpu.chain.query import QueryError, QueryRouter
+from celestia_app_tpu.das.blob_server import route_blob
 from celestia_app_tpu.das.server import serve_http
 from celestia_app_tpu.utils import telemetry
 
@@ -175,29 +180,10 @@ class NodeService:
                         serve_http(self, service.das_core, "GET")
                     elif self.path.startswith("/blob/"):
                         # the read plane (das/blob_server.py): namespace
-                        # reads + blob-pack static serving; BlobError is
-                        # a SampleError, so one handler covers both
-                        from urllib.parse import parse_qs, urlparse
-
-                        from celestia_app_tpu.das.server import SampleError
-                        from celestia_app_tpu.das.blob_server import (
-                            route_blob,
-                        )
-
-                        parsed = urlparse(self.path)
-                        try:
-                            out = route_blob(
-                                service.blob_core, "GET", parsed.path,
-                                parse_qs(parsed.query),
-                            )
-                            if isinstance(out, bytes):
-                                # /blob/pack/chunk: raw static bytes
-                                self._send_raw(200, out)
-                            else:
-                                self._send(200, out)
-                        except SampleError as e:
-                            self._send(404 if "not served" in str(e)
-                                       else 400, {"error": str(e)})
+                        # reads + blob-pack static serving, through the
+                        # same front as /das/
+                        serve_http(self, service.blob_core, "GET",
+                                   route=route_blob)
                     elif self.path.startswith("/sync/"):
                         # chunked state-sync serving (chain/sync.py):
                         # manifests + raw chunks from disk, lock-free
@@ -251,6 +237,10 @@ class NodeService:
                     # the DAS front reads its own body (das/server.py)
                     serve_http(self, service.das_core, "POST")
                     return
+                if self.path.startswith("/blob/"):
+                    serve_http(self, service.blob_core, "POST",
+                               route=route_blob)
+                    return
                 try:
                     n = int(self.headers.get("Content-Length", 0))
                     payload = json.loads(self.rfile.read(n) or b"{}")
@@ -303,24 +293,6 @@ class NodeService:
                                 self.path, payload))
                         except DAError as e:
                             self._send(400, {"error": str(e)})
-                    elif self.path.startswith("/blob/"):
-                        from urllib.parse import urlparse
-
-                        from celestia_app_tpu.das.server import (
-                            SampleError,
-                        )
-                        from celestia_app_tpu.das.blob_server import (
-                            route_blob,
-                        )
-
-                        try:
-                            self._send(200, route_blob(
-                                service.blob_core, "POST",
-                                urlparse(self.path).path, {}, payload,
-                            ))
-                        except SampleError as e:
-                            self._send(404 if "not served" in str(e)
-                                       else 400, {"error": str(e)})
                     elif self.path.startswith("/faults/"):
                         # arm/disarm/reset fault points on a LIVE node —
                         # the chaos harness's runtime switchboard

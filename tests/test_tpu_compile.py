@@ -19,7 +19,9 @@ program leaves sharded (k=128 / 256, both orientations: the case the chips
 refused until PR 36, whose refusal is kept as a case too), and the gather
 of a light round's cells and proof nodes out of that square and its level
 stacks (PR 37: four chips at k=128 / 256, both orientations; one chip at
-k=128), also at the largest bucket a combined dispatch carries.
+k=128), also at the largest bucket a combined dispatch carries, and a
+namespace read's search and row gather over that square and its row
+level stack (four chips at k = 128 / 256; one chip at k = 128).
 
 Rules this file keeps (they are what lets it run under `pytest -n 6`):
 the topology is described ONLY inside the module fixture (one process at
@@ -323,3 +325,81 @@ def test_sample_gather_on_one_chip(one_chip):
                         kernels=False)
     assert "all-reduce" not in compiled.as_text()
 
+
+
+@pytest.mark.parametrize("k,rows", [(128, 1), (128, 128), (256, 1),
+                                    (256, 128), (256, 256)])
+def test_namespace_gather_under_the_mesh_entrys_sharding(seq_mesh, k, rows):
+    """A namespace read's rows — one, the largest namespace of the 32 MB
+    blocks' bucket (83 rows -> 128), and every row of the original square
+    — with their proof nodes, cut out of the square and the row level
+    stack the four chips hold (da/proof_device.
+    _jitted_sharded_namespace_gather): no kernel to partition, the
+    answer combined by all-reduce as u32 words, and no gather of the
+    square or of a level stack to anywhere."""
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from celestia_app_tpu.da import proof_device
+    from celestia_app_tpu.parallel.mesh import SEQ_AXIS
+
+    assert rows in proof_device.namespace_row_buckets(k)
+    mesh, placed = seq_mesh
+    everywhere = NamedSharding(mesh, P())
+    program = proof_device._jitted_sharded_namespace_gather.__wrapped__(
+        mesh, SEQ_AXIS, k)
+    square, levels, _ = _gather_shapes(k, placed, everywhere)
+    index = jax.ShapeDtypeStruct((3, rows), jnp.int32, sharding=everywhere)
+    compiled = _compile(program, square, levels, index, kernels=False,
+                        in_shardings=(placed, placed, everywhere))
+    text = compiled.as_text()
+    assert "all-reduce" in text
+    assert "all-gather" not in text and "all-to-all" not in text
+    words, nodes = compiled.output_shardings
+    assert words.is_fully_replicated and nodes.is_fully_replicated
+    depth = (2 * k).bit_length() - 1
+    # the answer's own bytes, its nodes padded to the device's tiles
+    out = compiled.memory_analysis().output_size_in_bytes
+    assert rows * k * 512 + rows * 2 * depth * 90 <= out < \
+        rows * k * 512 + rows * 2 * depth * 128 + 4096
+
+
+def test_namespace_search_over_the_mesh_entrys_level_stack(seq_mesh):
+    """The search of one query over the level-0 mins of a k = 256 row
+    level stack split over four chips (da/namespace_device.
+    _jitted_sharded_search): four ints a query combined, nothing of the
+    stack moved."""
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from celestia_app_tpu.da import namespace_device
+    from celestia_app_tpu.parallel.mesh import SEQ_AXIS
+
+    k = 256
+    mesh, placed = seq_mesh
+    everywhere = NamedSharding(mesh, P())
+    program = namespace_device._jitted_sharded_search.__wrapped__(
+        mesh, SEQ_AXIS, k)
+    compiled = _compile(program, _u8((2 * k, 2 * k, 29), placed),
+                        _u8((1, 29), everywhere), kernels=False,
+                        in_shardings=(placed, everywhere))
+    text = compiled.as_text()
+    assert "all-gather" not in text and "all-to-all" not in text
+    assert compiled.output_shardings.is_fully_replicated
+
+
+def test_namespace_read_on_one_chip(one_chip):
+    """The search and the row gather over a k = 128 square one chip holds
+    (a batched engine's entry): plain jits, no collective."""
+    from celestia_app_tpu.da import namespace_device, proof_device
+
+    k = 128
+    search = namespace_device._jitted_resident_search.__wrapped__(k)
+    compiled = _compile(search, _u8((2 * k, 2 * k, 29), one_chip),
+                        _u8((1, 29), one_chip), kernels=False)
+    assert "all-reduce" not in compiled.as_text()
+    gather = proof_device._jitted_namespace_gather.__wrapped__(k)
+    square, levels, _ = _gather_shapes(k, one_chip, one_chip)
+    index = jax.ShapeDtypeStruct((3, 64), jnp.int32, sharding=one_chip)
+    compiled = _compile(gather, square, levels, index, kernels=False)
+    assert "all-reduce" not in compiled.as_text()
