@@ -313,11 +313,14 @@ class QueryRouter:
         }
 
 
-def _share_proof_json(pf) -> dict:
+def _share_proof_json(pf, data=None) -> dict:
     """Serialize a ShareProof for transport; verifiable via
-    proof.share_proof_from_json."""
+    proof.share_proof_from_json. ``data`` stands for the encoded share
+    list where the caller has encoded ``pf.data`` already
+    (das/blob_packs.namespace_member)."""
     return {
-        "data": [base64.b64encode(d).decode() for d in pf.data],
+        "data": ([base64.b64encode(d).decode() for d in pf.data]
+                 if data is None else data),
         "namespace": pf.namespace.hex(),
         "start_share": pf.start_share,
         "end_share": pf.end_share,

@@ -35,13 +35,16 @@ plane".
 
 from __future__ import annotations
 
-import base64
+import binascii
 import hashlib
 import json
 import os
 import shutil
 import threading
 
+import numpy as np
+
+from celestia_app_tpu.appconsts import SHARE_SIZE
 from celestia_app_tpu.da import codec as codec_mod
 from celestia_app_tpu.das.packs import PackError, decode_chunk, encode_chunk
 from celestia_app_tpu.utils import telemetry
@@ -64,20 +67,75 @@ MANIFEST_FIELDS = (
 
 __all__ = [
     "BLOB_PACK_DIRNAME", "MANIFEST_FIELDS", "PackError", "encode_chunk",
-    "decode_chunk", "live_namespace_doc", "blob_namespaces",
-    "build_blob_pack", "advertised", "BlobPackStore",
+    "decode_chunk", "EncodedShares", "NamespaceReply", "namespace_member",
+    "live_namespace_doc", "blob_namespaces", "build_blob_pack",
+    "advertised", "BlobPackStore",
 ]
 
+# one share in base64: 171 groups of 3 bytes, the last of them short by one
+SHARE_B64 = (SHARE_SIZE + 2) // 3 * 4
+# one share as a member of a JSON list: '"' + its base64 + '", '
+_FRAMED = SHARE_B64 + 4
 
-def live_namespace_doc(entry, namespace: bytes, prover=None,
-                       nd=None) -> dict:
-    """THE per-namespace read doc (FORMATS §21.1) — one builder shared
-    by the live serving path (das/blob_server.BlobCore) and the pack
-    builder, so pack bytes ≡ live bytes by construction. ``prover``
-    lets callers pass a resolved prover; ``nd`` lets the batched route
-    pass an already-resolved `NamespaceData` (batched resolution is
-    pinned byte-identical to the host reference, so the doc bytes are
-    unchanged)."""
+
+class EncodedShares:
+    """A list of shares in base64, made in one pass over all of them.
+
+    ``json`` is the list as JSON text — byte for byte what ``json.dumps``
+    gives for the list of each share's ``base64.b64encode`` — and
+    ``strings()`` the list itself. The pass: the shares joined with a
+    zero byte after each (``SHARE_SIZE + 1`` bytes a row, a multiple of
+    3, so every row starts a base64 group), ONE ``binascii.b2a_base64``
+    over the rows, and one numpy pass that turns each row's last
+    character — the zero byte's — into the ``=`` a 512-byte share ends
+    with, and frames the rows as ``["…", "…"]``."""
+
+    __slots__ = ("n", "json", "_strings")
+
+    def __init__(self, shares: list[bytes]):
+        n = self.n = len(shares)
+        self._strings = None
+        if not n:
+            self.json = b"[]"
+            return
+        rows = b"\0".join([*shares, b""])
+        b64 = np.frombuffer(binascii.b2a_base64(rows, newline=False),
+                            np.uint8).reshape(n, SHARE_B64)
+        # '[' then n framed rows; the last row's '", ' becomes '"]' and
+        # its spare byte is cut off with the final slice
+        out = np.empty(1 + n * _FRAMED, np.uint8)
+        out[0] = ord("[")
+        framed = out[1:].reshape(n, _FRAMED)
+        framed[:, 0] = ord('"')
+        framed[:, 1:SHARE_B64] = b64[:, :-1]
+        framed[:, SHARE_B64] = ord("=")
+        framed[:, SHARE_B64 + 1:] = np.frombuffer(b'", ', np.uint8)
+        framed[-1, SHARE_B64 + 2] = ord("]")
+        self.json = out[:-1].tobytes()
+
+    def strings(self) -> list[str]:
+        """The list of base64 strings: a new list on every call, of the
+        same ``str`` objects, sliced once out of one decoded string."""
+        if self._strings is None:
+            text = self.json.decode("ascii")
+            self._strings = [text[i + 2:i + 2 + SHARE_B64]
+                             for i in range(0, self.n * _FRAMED, _FRAMED)]
+        return list(self._strings)
+
+
+def namespace_member(entry, namespace: bytes, prover=None,
+                     nd=None) -> dict:
+    """THE per-namespace read doc (FORMATS §21.1) with its two share
+    lists still encoded: ``shares`` and ``proof["data"]`` hold
+    `EncodedShares` — ONE for both where the namespace is present (the
+    proof's data is its share range), the successor leaf's alone where
+    it is absent. One builder shared by the live serving path
+    (das/blob_server.BlobCore) and the pack builder, so pack bytes ≡
+    live bytes by construction; `member_doc` makes it the dict,
+    `member_json` the bytes. ``prover`` lets callers pass a resolved
+    prover; ``nd`` lets the batched route pass an already-resolved
+    `NamespaceData` (batched resolution is pinned byte-identical to the
+    host reference, so the doc bytes are unchanged)."""
     from celestia_app_tpu.chain.query import _share_proof_json
     from celestia_app_tpu.da import namespace_data as nsd_mod
 
@@ -85,13 +143,94 @@ def live_namespace_doc(entry, namespace: bytes, prover=None,
         if prover is None:
             prover = entry.get_prover()
         nd = nsd_mod.get_namespace_data(prover, namespace)
+    shares = EncodedShares(nd.shares)
+    proof = None
+    if nd.proof:
+        data = shares if nd.shares else EncodedShares(nd.proof.data)
+        proof = _share_proof_json(nd.proof, data=data)
     return {
         "namespace": namespace.hex(),
         "present": bool(nd.shares),
-        "shares": [base64.b64encode(s).decode() for s in nd.shares],
-        "proof": _share_proof_json(nd.proof) if nd.proof else None,
+        "shares": shares,
+        "proof": proof,
         "data_root": entry.data_root.hex(),
     }
+
+
+def member_doc(member: dict) -> dict:
+    """A `namespace_member` (or an error member, returned as it is) as
+    the §21.1 dict: each share list a list object of its own."""
+    shares = member.get("shares")
+    if not isinstance(shares, EncodedShares):
+        return member
+    doc = {**member, "shares": shares.strings()}
+    if member["proof"] is not None:
+        doc["proof"] = {**member["proof"],
+                        "data": member["proof"]["data"].strings()}
+    return doc
+
+
+def member_json(member: dict) -> list[bytes]:
+    """A `namespace_member` as the pieces of ``json.dumps(member_doc(
+    member)).encode()``: the doc with both share lists empty goes
+    through ``json.dumps``, and the lists' JSON is spliced in where the
+    empty ones stand. ``"shares": []`` is found as the first, and
+    ``"data": []`` as the first after it: the keys before them hold a
+    number, hex and a boolean, and a quote inside a JSON string is
+    always escaped."""
+    shares = member.get("shares")
+    if not isinstance(shares, EncodedShares):
+        return [json.dumps(member).encode()]
+    proof = member["proof"]
+    flat = {**member, "shares": []}
+    if proof is not None:
+        flat["proof"] = {**proof, "data": []}
+    text = json.dumps(flat).encode()
+    i = text.index(b'"shares": []') + len(b'"shares": ')
+    if proof is None:
+        return [text[:i], shares.json, text[i + 2:]]
+    j = text.index(b'"data": []', i) + len(b'"data": ')
+    return [text[:i], shares.json, text[i + 2:j], proof["data"].json,
+            text[j + 2:]]
+
+
+class NamespaceReply:
+    """A namespace read's reply — one member (``GET /blob/get``) or
+    ``{"queries": [members]}`` (``POST /blob/namespaces``) — held as its
+    members with their share lists still encoded: a transport takes it
+    as bytes (`render`), an in-process caller as the dict (`doc`); both
+    are the FORMATS §21.1 reply to the byte."""
+
+    __slots__ = ("members", "batched")
+
+    def __init__(self, members: list[dict], batched: bool):
+        self.members = members
+        self.batched = batched
+
+    def doc(self) -> dict:
+        docs = [member_doc(m) for m in self.members]
+        return {"queries": docs} if self.batched else docs[0]
+
+    def render(self) -> bytes:
+        """``json.dumps(self.doc()).encode()``, with each share list
+        written from its one encoding; counted ``blob.rendered_replies``."""
+        pieces: list[bytes] = []
+        for i, member in enumerate(self.members):
+            if self.batched:
+                pieces.append(b", " if i else b'{"queries": [')
+            pieces += member_json(member)
+        if self.batched:
+            pieces.append(b"]}")
+        telemetry.incr("blob.rendered_replies")
+        return b"".join(pieces)
+
+
+def live_namespace_doc(entry, namespace: bytes, prover=None,
+                       nd=None) -> dict:
+    """The §21.1 dict of `namespace_member` — what the pack builder and
+    in-process readers take."""
+    return member_doc(namespace_member(entry, namespace, prover=prover,
+                                       nd=nd))
 
 
 def blob_namespaces(entry, prover=None) -> list[bytes]:

@@ -77,6 +77,7 @@ from celestia_app_tpu.da import codec as codec_mod
 from celestia_app_tpu.da import edscache as edscache_mod
 from celestia_app_tpu.da.dah import DataAvailabilityHeader, ExtendedDataSquare
 from celestia_app_tpu.das import packs as packs_mod
+from celestia_app_tpu.das.blob_packs import NamespaceReply
 from celestia_app_tpu.utils import telemetry
 
 
@@ -788,7 +789,9 @@ def serve_http(handler, core, method: str, route=route_das) -> None:
         das.http.decode  POST only: the body read and ``json.loads``
         (the route's own spans: das.header, das.serve_sample,
          blob.namespaces_many -> ...)
-        das.http.encode  ``json.dumps`` of the reply (not for raw bytes)
+        das.http.encode  ``json.dumps`` of the reply, or a namespace
+                         read's `NamespaceReply.render` (not for raw
+                         bytes)
         das.http.write   status line, headers and body to the socket
 
     Counters ``das.http_requests``, ``das.http_bytes_in`` (request
@@ -834,7 +837,8 @@ def serve_http(handler, core, method: str, route=route_das) -> None:
             body, ctype = out, "application/octet-stream"
         else:
             with obs.span("das.http.encode"):
-                body = json.dumps(out).encode()
+                body = (out.render() if isinstance(out, NamespaceReply)
+                        else json.dumps(out).encode())
             ctype = "application/json"
         with obs.span("das.http.write"):
             handler.send_response(status)

@@ -74,6 +74,7 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
 from celestia_app_tpu import obs
 from celestia_app_tpu.chain import consensus as c
+from celestia_app_tpu.das.blob_packs import NamespaceReply
 from celestia_app_tpu.utils import telemetry
 
 
@@ -116,7 +117,9 @@ class ValidatorService:
                 pass
 
             def _send(self, code: int, obj) -> None:
-                body = json.dumps(obj).encode()
+                # a namespace read's reply comes rendered (FORMATS §21.1)
+                body = (obj.render() if isinstance(obj, NamespaceReply)
+                        else json.dumps(obj).encode())
                 self.send_response(code)
                 self.send_header("Content-Type", "application/json")
                 self.send_header("Content-Length", str(len(body)))

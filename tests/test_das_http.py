@@ -10,7 +10,9 @@ verifies against the header; every cell is cut on the devices
 comes down; both transports give the same bodies; `gather_cells` from
 many threads gives the serial bytes; the front's spans (`das.http.*`)
 close their request; a malformed request is one `das.http_errors` and a
-4xx, never a 5xx.
+4xx, never a 5xx. A namespace read's reply (`/blob/`) is rendered inside
+the one `das.http.encode` and counted `blob.rendered_replies`; the
+`/das/` replies and every error body stay `json.dumps` of their dict.
 """
 
 import base64
@@ -24,6 +26,7 @@ import pytest
 from celestia_app_tpu import obs
 from celestia_app_tpu.da import edscache, sampling
 from celestia_app_tpu.da.dah import DataAvailabilityHeader
+from celestia_app_tpu.das.server import SampleError
 from celestia_app_tpu.utils import nmt_host, telemetry
 
 HEIGHTS = (7, 8)
@@ -312,3 +315,103 @@ def test_a_malformed_request_is_one_error_and_never_a_5xx(
     assert _counter("das.http_errors") - e0 == 1
     assert _counter("das.http_requests") - r0 == 2
     assert _counter("das.server_errors") == s0
+
+
+class _BlobFront:
+    """One height of a laid-out square (namespaces in order, as the
+    read plane needs) as a copy-less mesh entry behind the node service."""
+
+    HEIGHT = 5
+
+    def __init__(self):
+        from celestia_app_tpu.chain.app import App
+        from celestia_app_tpu.chain.node import Node
+        from celestia_app_tpu.da import dah as dah_mod
+        from celestia_app_tpu.da import square as square_mod
+        from celestia_app_tpu.da.blob import Blob
+        from celestia_app_tpu.da.namespace import Namespace
+        from celestia_app_tpu.da.square import PfbEntry
+        from celestia_app_tpu.service.server import NodeService
+
+        rng = np.random.default_rng(4500)
+        blobs = [Blob(Namespace.v0(bytes([0x20 + i]) * 5),
+                      rng.integers(0, 256, n, dtype=np.uint8).tobytes())
+                 for i, n in enumerate([478 * 9, 478 * 8, 900])]
+        sq = square_mod.build([b"a-tx"], [PfbEntry(b"a-pfb", tuple(blobs))],
+                              8, 64)
+        self.app = App(chain_id="blob-http")
+        self.app.init_chain({"time_unix": 0})
+        self.svc = NodeService(Node(self.app), port=0)
+        entry = edscache.compute_entry(
+            dah_mod.shares_to_ods(sq.share_bytes()), "mesh")
+        assert entry.residency() == "device"
+        self.svc.das_core.seed_cache_entry(self.HEIGHT, entry)
+        self.namespaces = [b.namespace.raw.hex() for b in blobs]
+        self.svc.serve_background()
+
+    def close(self):
+        self.svc.shutdown()
+        self.app.close()
+
+
+@pytest.fixture(scope="module")
+def blob_front():
+    f = _BlobFront()
+    yield f
+    f.close()
+
+
+def test_a_namespace_reply_is_rendered_inside_the_one_encode(blob_front):
+    """Every `/blob/` reply: one `das.http.encode` under its request, one
+    `blob.rendered_replies`, and the in-process core's doc to the byte;
+    a `POST /blob/namespaces`'s children close it to within 0.5 ms (`GET
+    /blob/get` opens no span of its route); `/das/` replies and error
+    bodies move no such counter and are ``json.dumps`` of their dict."""
+    h = blob_front.HEIGHT
+    app, blob = blob_front.app, blob_front.svc.blob_core
+    conn = http.client.HTTPConnection("127.0.0.1", blob_front.svc.port,
+                                      timeout=60)
+    since = app.traces.read("spans", 0, 10**9)
+    since = since[-1]["_index"] + 1 if since else 0
+    c0 = _counter("blob.rendered_replies")
+    for i in range(12):
+        ns = blob_front.namespaces[i % len(blob_front.namespaces)]
+        if i % 2:
+            status, raw = _ask(conn, "GET",
+                               f"/blob/get?height={h}&namespace={ns}")
+            want = blob.get(h, ns)
+        else:
+            one = [{"height": h, "namespace": ns}]
+            status, raw = _ask(conn, "POST", "/blob/namespaces",
+                               {"queries": one})
+            want = blob.namespaces_many(one)
+        assert (status, raw) == (200, json.dumps(want).encode())
+    assert _counter("blob.rendered_replies") - c0 == 12
+    rows = app.traces.read("spans", since, 10**9)
+    by_parent: dict[str, list] = {}
+    for r in rows:
+        by_parent.setdefault(r["parent_id"], []).append(r)
+    gaps, encodes = [], 0
+    for r in rows:
+        if r["name"] != "das.http.request":
+            continue
+        children = by_parent.get(r["span_id"], [])
+        assert [c["name"] for c in children].count("das.http.encode") == 1
+        encodes += 1
+        if r["route"] == "/blob/namespaces":
+            gaps.append(r["dur_ms"] - sum(c["dur_ms"] for c in children))
+    assert encodes == 12 and len(gaps) == 6
+    assert min(gaps) >= -0.01
+    assert sorted(gaps)[len(gaps) // 2] <= 0.5, gaps
+    assert sum(g <= 0.5 for g in gaps) >= 5, gaps
+    with pytest.raises(SampleError) as missing:
+        blob.get(99, blob_front.namespaces[0])
+    status, raw = _ask(conn, "GET", f"/blob/get?height=99&namespace="
+                                    f"{blob_front.namespaces[0]}")
+    assert (status, raw) == (400, json.dumps(
+        {"error": str(missing.value)}).encode())
+    status, raw = _ask(conn, "GET", f"/das/header?height={h}")
+    conn.close()
+    assert (status, raw) == (200, json.dumps(
+        blob_front.svc.das_core.header(h)).encode())
+    assert _counter("blob.rendered_replies") - c0 == 12

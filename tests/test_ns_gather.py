@@ -12,8 +12,12 @@ built, the entry stays "device" and later samples still gather; entries
 with host bytes read as before. Through `NodeService` and the `BlobService`
 sidecar the /blob/ routes answer through the node's HTTP front
 (`das/server.serve_http`): spanned, counted, 4xx for a malformed request.
+A read's reply is encoded in one base64 pass a namespace
+(`das/blob_packs.EncodedShares`): the rendered body and the in-process
+dict are the per-share encoder's FORMATS §21.1 doc to the byte.
 """
 
+import base64
 import http.client
 import json
 
@@ -28,8 +32,10 @@ from celestia_app_tpu.da import namespace_device as nsdev
 from celestia_app_tpu.da import square as square_mod
 from celestia_app_tpu.da.blob import Blob
 from celestia_app_tpu.da.namespace import Namespace
+from celestia_app_tpu.chain.query import _share_proof_json
 from celestia_app_tpu.da.square import PfbEntry
 from celestia_app_tpu.das import blob_packs
+from celestia_app_tpu.das.server import SampleError
 from celestia_app_tpu.utils import telemetry
 
 CHIPS = 4
@@ -37,7 +43,8 @@ HEIGHTS = (7, 8)
 # blob sizes (bytes) a square of each size is laid out from: 4 namespaces,
 # ranges that cross a device's rows, and tail padding over whole rows
 SIZES = {8: [478 * 9, 478 * 8, 900, 478 * 3],
-         16: [478 * 40, 478 * 16, 478 * 30, 2000]}
+         16: [478 * 40, 478 * 16, 478 * 30, 2000],
+         64: [478 * 700, 478 * 400, 900, 478 * 300]}
 
 
 def _counter(name: str) -> int:
@@ -360,3 +367,161 @@ def test_a_malformed_read_is_a_4xx_never_a_5xx(front, service, method, path,
     assert "error" in json.loads(raw)
     assert _counter("das.http_errors") - e0 == 1
     assert _counter("das.server_errors") == s0
+
+
+# ---------------------------------------------------------------------------
+# a read's reply, encoded once: the per-share encoder's bytes and dict
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("n", [0, 1, 2, 3, 1000])
+def test_one_pass_encodes_what_base64_does_share_by_share(n):
+    shares = [np.random.default_rng([n, i]).integers(
+        0, 256, 512, dtype=np.uint8).tobytes() for i in range(n)]
+    want = [base64.b64encode(s).decode() for s in shares]
+    encoded = blob_packs.EncodedShares(shares)
+    assert encoded.json == json.dumps(want).encode()
+    first, second = encoded.strings(), encoded.strings()
+    assert first == second == want
+    assert first is not second
+    assert all(a is b for a, b in zip(first, second))
+
+
+def _parents_member(height: int, root: bytes, namespace: bytes, nd) -> dict:
+    """A §21.1 member as the per-share encoder wrote it: one
+    ``b64encode`` a share for ``shares`` and again for ``proof.data``."""
+    return {
+        "height": height,
+        "namespace": namespace.hex(),
+        "present": bool(nd.shares),
+        "shares": [base64.b64encode(s).decode() for s in nd.shares],
+        "proof": _share_proof_json(nd.proof) if nd.proof else None,
+        "data_root": root.hex(),
+    }
+
+
+class _Reads:
+    """Two heights of one entry kind behind an in-process `BlobCore`, and
+    the host reference's `NamespaceData` of every namespace of each."""
+
+    def __init__(self, kind: str):
+        from celestia_app_tpu.chain.app import App
+        from celestia_app_tpu.das.blob_server import BlobCore
+        from celestia_app_tpu.das.server import SampleCore
+
+        engine, k = kind.split("-k")
+        k = int(k)
+        self.app = App(chain_id=f"ns-render-{kind}")
+        self.app.init_chain({"time_unix": 0})
+        self.blob = BlobCore(SampleCore(self.app))
+        self.roots, self.refs = {}, {}
+        for h in HEIGHTS:
+            ods = _ods(k, 4600 + 10 * k + h)
+            entry = edscache.compute_entry(ods, engine)
+            assert entry.residency() == engine.replace("mesh", "device")
+            self.blob.core.seed_cache_entry(h, entry)
+            prover = edscache.compute_entry(ods, "host").get_prover("host")
+            self.roots[h] = entry.data_root
+            self.refs[h] = {ns: nsd.get_namespace_data(prover, ns)
+                            for ns in _namespaces(ods)}
+
+    def pick(self, height: int, form: str) -> bytes:
+        """The first namespace of the height whose read has the form."""
+        def rows(nd):
+            return nd.proof.row_proof.end_row - nd.proof.row_proof.start_row
+
+        test = {"many rows": lambda nd: nd.shares and rows(nd) > 0,
+                "one row": lambda nd: nd.shares and rows(nd) == 0,
+                "successor": lambda nd: not nd.shares and nd.proof,
+                "uncovered": lambda nd: nd.proof is None}[form]
+        return next(ns for ns, nd in self.refs[height].items() if test(nd))
+
+    def parents_member(self, height: int, namespace: bytes) -> dict:
+        return _parents_member(height, self.roots[height], namespace,
+                               self.refs[height][namespace])
+
+
+@pytest.fixture(scope="module")
+def reads():
+    mp = pytest.MonkeyPatch()
+    mp.setenv("CELESTIA_MESH_DEVICES", str(CHIPS))
+    made: dict[str, _Reads] = {}
+
+    def get(kind: str) -> _Reads:
+        if kind not in made:
+            made[kind] = _Reads(kind)
+        return made[kind]
+
+    yield get
+    for r in made.values():
+        r.app.close()
+    mp.undo()
+
+
+MISSING = 99  # a height no core serves: an error member
+READS = {
+    "present over many rows": [(HEIGHTS[0], "many rows")],
+    "present in one row": [(HEIGHTS[1], "one row")],
+    "absent, a successor leaf": [(HEIGHTS[0], "successor")],
+    "absent, no covering row": [(HEIGHTS[1], "uncovered")],
+    "an error member": [(HEIGHTS[0], "many rows"), (MISSING, "many rows"),
+                        (HEIGHTS[0], "successor")],
+    "mixed heights": [(HEIGHTS[0], "one row"), (HEIGHTS[1], "many rows"),
+                      (HEIGHTS[1], "successor"), (HEIGHTS[0], "many rows"),
+                      (HEIGHTS[0], "one row")],
+}
+
+
+@pytest.mark.parametrize("case", list(READS))
+@pytest.mark.parametrize("kind", ["host-k8", "host-k64", "mesh-k8"])
+def test_a_reply_is_the_per_share_encoders_doc(reads, kind, case):
+    """`POST /blob/namespaces` and `GET /blob/get` as the route renders
+    them equal ``json.dumps`` of the per-share encoder's doc byte for byte,
+    and the in-process dict equals that doc, its two share lists separate
+    list objects of the same strings — on host-prover entries and on a
+    copy-less mesh entry."""
+    r = reads(kind)
+    picked = [(h, r.pick(HEIGHTS[0] if h == MISSING else h, form))
+              for h, form in READS[case]]
+    queries = [{"height": h, "namespace": ns.hex()} for h, ns in picked]
+    with pytest.raises(SampleError) as missing:
+        r.blob.get(MISSING, bytes(29).hex())
+    want = {"queries": [
+        {"height": h, "namespace": ns.hex(), "error": str(missing.value)}
+        if h == MISSING else r.parents_member(h, ns) for h, ns in picked]}
+    c0 = _counter("blob.rendered_replies")
+    assert r.blob.namespaces_reply(queries).render() == \
+        json.dumps(want).encode()
+    assert _counter("blob.rendered_replies") - c0 == 1
+    got = r.blob.namespaces_many(queries)
+    assert got == want
+    for member in got["queries"]:
+        if member.get("proof") and member["shares"]:
+            assert member["shares"] is not member["proof"]["data"]
+            assert all(a is b for a, b in zip(member["shares"],
+                                              member["proof"]["data"]))
+    if MISSING in dict(picked):
+        return
+    for (h, ns), member in zip(picked, want["queries"]):
+        assert r.blob.get_reply(h, ns.hex()).render() == \
+            json.dumps(member).encode()
+        assert r.blob.get(h, ns.hex()) == member
+
+
+@pytest.mark.parametrize("k", [8, 64])
+def test_pack_chunks_are_the_per_share_encoders_bytes(k):
+    """The pack builder's chunks, from the same encoding, are the chunks
+    of the per-share encoder's docs."""
+    ods = _ods(k, 4700 + k)
+    entry = edscache.compute_entry(ods, "host")
+    prover = entry.get_prover("host")
+    _manifest, chunks = blob_packs.build_blob_pack(entry, 5,
+                                                   chunk_namespaces=2)
+    docs = []
+    for ns in blob_packs.blob_namespaces(entry):
+        member = _parents_member(0, entry.data_root, ns,
+                                 nsd.get_namespace_data(prover, ns))
+        del member["height"]
+        docs.append(member)
+    assert chunks == [blob_packs.encode_chunk(docs[i:i + 2])
+                      for i in range(0, len(docs), 2)]
