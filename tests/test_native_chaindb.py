@@ -15,6 +15,8 @@ durable byte plane under chain/storage.ChainDB. These tests pin:
 from __future__ import annotations
 
 import os
+import struct
+import zlib
 
 import pytest
 
@@ -143,6 +145,109 @@ def test_sealed_segment_corruption_is_loud(tmp_path):
             _log(tmp_path)
     finally:
         del os.environ["CELESTIA_CDB_SEGBYTES"]
+
+
+# The engine's frame (docs/FORMATS.md §23.1): magic, kind, stream, height,
+# len, then a CRC-32 over header[4:24] + payload — zlib.crc32's value.
+_MAGIC = 0xCE1E57DA
+_PUT, _TOMB_AT, _TOMB_ABOVE = 0, 1, 2
+_HDR = struct.Struct("<IIIQII")
+# every residue of the eight-byte step, both sides of a word and of a
+# cache line, and sizes that span many steps
+_LENGTHS = [0, 1, 7, 8, 9, 63, 64, 65, 4_099, 100_003, (1 << 20) + 5]
+
+
+def _payload(n: int, salt: int = 0) -> bytes:
+    cycle = bytes((i * 131 + salt) & 0xFF for i in range(251))  # odd period
+    return (cycle * (n // 251 + 1))[:n]
+
+
+def _frame(kind, stream, height, payload=b""):
+    head = _HDR.pack(_MAGIC, kind, stream, height, len(payload), 0)
+    crc = zlib.crc32(head[4:24] + payload)
+    return head[:24] + struct.pack("<I", crc) + payload
+
+
+def _frames(data: bytes):
+    """Parse a segment: (kind, stream, height, payload, crc field, frame
+    header) per record, asserting the file holds whole records only."""
+    out, off = [], 0
+    while off < len(data):
+        magic, kind, stream, height, n, crc = _HDR.unpack_from(data, off)
+        assert magic == _MAGIC
+        head = data[off:off + _HDR.size]
+        payload = data[off + _HDR.size:off + _HDR.size + n]
+        assert len(payload) == n
+        out.append((kind, stream, height, payload, crc, head))
+        off += _HDR.size + n
+    return out
+
+
+@pytest.mark.parametrize("n", _LENGTHS)
+def test_frame_on_disk_is_zlib_crc32(tmp_path, n):
+    """Every frame the engine writes — PUT, TOMB_AT, TOMB_ABOVE — carries
+    zlib.crc32(header[4:24] + payload), and every payload reads back (before
+    and after a replay, which checks the same CRC)."""
+    a, b = _payload(n), _payload(n, salt=7)
+    log = _log(tmp_path)
+    log.put(0, 1, a)
+    log.put(1, 2, b)
+    log.tomb_at(1, 2)
+    log.put(2, 9, b)
+    log.tomb_above(5)
+    log.put(0, 3, a)
+    log.sync()
+    for reopened in (False, True):
+        if reopened:
+            log.close()
+            log = _log(tmp_path)
+        assert log.get(0, 1) == a and log.get(0, 3) == a
+        assert log.get(1, 2) is None and log.get(2, 9) is None
+    log.close()
+    frames = _frames((tmp_path / "db" / "seg-00000000.log").read_bytes())
+    assert [(k, s, h) for k, s, h, *_ in frames] == [
+        (_PUT, 0, 1), (_PUT, 1, 2), (_TOMB_AT, 1, 2), (_PUT, 2, 9),
+        (_TOMB_ABOVE, 0, 5), (_PUT, 0, 3)]
+    assert [p for *_, p, _, _ in frames] == [a, b, b"", b, b"", a]
+    for kind, stream, height, payload, crc, head in frames:
+        assert crc == zlib.crc32(head[4:24] + payload)
+
+
+@pytest.mark.parametrize("n", _LENGTHS)
+def test_home_framed_by_zlib_crc32_still_opens(tmp_path, n):
+    """A home framed in Python with zlib.crc32 — as earlier builds of the
+    engine wrote it — opens: every key reads back, a later put lands after
+    the old records, and a last-segment record with one payload bit flipped
+    is cut off as a torn tail (a CRC that disagrees with zlib at any length
+    or alignment would lose the good records instead)."""
+    home = tmp_path / "db"
+    home.mkdir()
+    a, b = _payload(n), _payload(n, salt=3)
+    sealed = (_frame(_PUT, 2, 1, b"odd")  # shifts what follows off 8 B
+              + _frame(_PUT, 0, 1, a) + _frame(_TOMB_AT, 2, 1))
+    bad = bytearray(_frame(_PUT, 0, 4, b or b"x"))
+    bad[-1] ^= 0x01  # one payload bit; the CRC field still says the old one
+    good = (_frame(_PUT, 1, 2, b) + _frame(_PUT, 2, 7, b"odd")
+            + _frame(_TOMB_ABOVE, 0, 6) + _frame(_PUT, 0, 3, a))
+    (home / "seg-00000000.log").write_bytes(sealed)
+    (home / "seg-00000001.log").write_bytes(good + bytes(bad))
+
+    log = _log(tmp_path)
+    assert log.get(0, 1) == a and log.get(1, 2) == b and log.get(0, 3) == a
+    assert log.get(2, 1) is None and log.get(2, 7) is None  # tombstoned
+    assert log.get(0, 4) is None  # the flipped record was dropped ...
+    tail = home / "seg-00000001.log"
+    assert tail.read_bytes() == good  # ... and truncated off the tail
+    c = _payload(n, salt=11)
+    log.put(0, 5, c)
+    log.sync()
+    log.close()
+    assert tail.read_bytes()[:len(good)] == good  # appended after them
+    assert _frames(tail.read_bytes())[-1][:4] == (_PUT, 0, 5, c)
+    log = _log(tmp_path)
+    assert log.get(0, 5) == c and log.get(0, 1) == a
+    assert log.heights(0) == [1, 3, 5]
+    log.close()
 
 
 def test_rotation_and_dead_segment_gc(tmp_path):
