@@ -334,7 +334,7 @@ def prevalidate_commitments(app, raws, btxs=None) -> int:
         # engines open admission.commit_pack / _dispatch / _fold under it)
         with obs.span("admission.commitments", n_blobs=len(pending)):
             commitments = blob_validation.batch_commitments(
-                pending, threshold, engine=getattr(app, "engine", "host"))
+                pending, threshold, engine=app.engine)
     except Exception as e:
         # the per-blob host path in validate_blob_tx stays authoritative
         telemetry.incr("admission.prevalidate_errors")
@@ -426,7 +426,7 @@ def _prevalidate(app, raws, check_state: bool, commitments: bool) -> int:
     cache = getattr(app, "sig_cache", None)
     if cache is None:
         return 0
-    if getattr(app, "engine", "host") == "host":
+    if app.engine == "host":
         # the signature batch is a JAX dispatch like the extend: a
         # host-engine process must not initialise an accelerator backend
         # it does not own (N validator processes cannot share one chip),

@@ -94,7 +94,9 @@ class App:
         self,
         chain_id: str = "celestia-tpu-1",
         app_version: int = 1,
-        engine: str = "auto",  # "device" | "host" | "auto" | "mesh"
+        # "host" | "device" | "auto"; "mesh" is a spelling of "device"
+        # (da/edscache.ENGINES), anything else raises
+        engine: str = "auto",
         min_gas_price: float = appconsts.DEFAULT_MIN_GAS_PRICE,
         v2_upgrade_height: int | None = None,
         upgrade_height_delay: int | None = None,
@@ -116,7 +118,7 @@ class App:
         self.absent_validators: set[bytes] = set()
         self.chain_id = chain_id
         self.app_version = app_version
-        self.engine = engine
+        self.engine = edscache_mod.resolve_engine(engine)
         # the codec plane: which construction data_hash commits under —
         # a consensus parameter (every validator of a chain must run the
         # same scheme; ProcessProposal rejects mismatched headers)
@@ -1254,7 +1256,7 @@ class App:
         if entry is not None:
             self.da_warmer.schedule(
                 self.height, entry, self.da_seed_listeners,
-                engine=self.engine, traces=self.traces,
+                traces=self.traces,
                 chain_id=self.chain_id, pack_store=self.pack_store,
                 blob_pack_store=self.blob_pack_store,
             )

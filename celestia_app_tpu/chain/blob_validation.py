@@ -32,10 +32,11 @@ def batch_commitments(blobs: list, subtree_root_threshold: int,
     otherwise. `engine` is the owning App's compute engine — a host-engine
     validator must NEVER touch the jax backend here: it would initialise
     an accelerator backend the process does not own (one process per
-    chip) the first time a block carries >= 4 blobs."""
-    # "mesh" is device-class: the mesh plane shards the EDS pipeline,
-    # and its commitment batches take the same single-dispatch path
-    if engine in ("device", "auto", "mesh") and len(blobs) >= 4:
+    chip) the first time a block carries >= 4 blobs. Under "auto" a
+    failed dispatch degrades to the host, counted
+    ``app.device_path_fallback`` as compute_entry's does; under "device"
+    it raises."""
+    if engine != "host" and len(blobs) >= 4:
         try:
             from celestia_app_tpu.da import commitment_device
 
@@ -45,6 +46,7 @@ def batch_commitments(blobs: list, subtree_root_threshold: int,
         except Exception:
             if engine == "device":
                 raise
+            telemetry.incr("app.device_path_fallback")
     return commitment_mod.create_commitments(blobs, subtree_root_threshold)
 
 

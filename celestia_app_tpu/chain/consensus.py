@@ -350,9 +350,8 @@ class ValidatorNode:
         # device-engine validators are constructible now that the block
         # plane's EDS cache (da/edscache.py) is populated bit-identically
         # by both engines — a TPU proposer and a host follower land on
-        # the same content-addressed entries and the same roots; a mesh
-        # validator (engine="mesh") additionally keeps its entries
-        # device-resident. max_square_size is the mesh plane's
+        # the same content-addressed entries and the same roots.
+        # max_square_size is the mesh plane's
         # consensus-critical k=256/512 admission override (chain/app.py).
         self.app = App(chain_id=chain_id, engine=engine, data_dir=data_dir,
                        v2_upgrade_height=v2_upgrade_height,
@@ -452,22 +451,6 @@ class ValidatorNode:
         the order FilterTxs receives candidates in (mempool v1 semantics;
         see mempool.pool.priority_order for the nonce-safety rationale)."""
         return self.pool.reap(self.app.height)
-
-    def prewarm_proposals(self, n_blocks: int) -> int:
-        """Mesh-plane produce prefetch (reactor ``produce_batch`` knob):
-        speculatively plan the next ``n_blocks`` proposal squares from
-        the current reap and batch-extend them in one dispatch
-        (chain/producer.py), seeding the app's EDS cache with
-        device-resident entries so the upcoming propose (and, while the
-        pool holds, the following heights this node proposes) hit a warm
-        entry instead of dispatching per block. Purely a cache warm:
-        proposal bytes are unchanged whether or not it ran. Returns how
-        many entries were inserted."""
-        from celestia_app_tpu.chain import producer
-
-        plans = producer.plan_block_squares(
-            self.app, self.reap_mempool(), n_blocks)
-        return producer.warm_block_batch(self.app, plans)
 
     # -- consensus steps -------------------------------------------------
     # Two-phase Tendermint vote flow with lock-on-polka: prevote after

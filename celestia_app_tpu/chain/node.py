@@ -171,48 +171,6 @@ class Node:
         record_committed(self.committed, prop.block, results)
         return prop.block, results
 
-    def produce_blocks_batched(self, n_blocks: int, t: float | None = None,
-                               t_step: int = 1):
-        """Produce ``n_blocks`` consecutive blocks with the extends
-        batched: the mempool reap is speculatively partitioned into
-        per-block squares (chain/producer.plan_block_squares — the same
-        deterministic greedy accounting prepare_proposal runs) and every
-        planned square is extended in ONE batched device dispatch,
-        seeding the EDS cache with device-resident entries. Each block
-        then goes through the UNCHANGED produce_block round — identical
-        block/app hashes to per-block production by construction; a plan
-        the ante disagreed with just pays its own extend (counted
-        ``producer.plan_misses``). Returns the list of (block, results)
-        pairs."""
-        from celestia_app_tpu.chain import producer
-        from celestia_app_tpu.utils import telemetry
-
-        try:
-            plans = producer.plan_block_squares(self.app, self._reap(),
-                                                n_blocks)
-            producer.warm_block_batch(self.app, plans)
-        except Exception as e:
-            # the prefetch is never fatal (producer.py contract): a
-            # failed batch dispatch degrades to per-block extends, the
-            # same way plain produce_block would
-            telemetry.incr("producer.prewarm_errors")
-            from celestia_app_tpu import obs
-
-            obs.get_logger("chain.node").warning(
-                "batched produce prewarm failed; falling back to "
-                "per-block extends", err=e)
-        out = []
-        for i in range(n_blocks):
-            c0 = telemetry.snapshot()["counters"].get("da.extend_runs", 0)
-            out.append(self.produce_block(
-                t=None if t is None else t + i * t_step))
-            c1 = telemetry.snapshot()["counters"].get("da.extend_runs", 0)
-            if c1 > c0:
-                # this height's square was not (or no longer) resident —
-                # the round paid a normal per-block extend
-                telemetry.incr("producer.plan_misses")
-        return out
-
     def confirm_tx(self, raw: bytes):
         """ConfirmTx: drive blocks until the tx commits (tx_client.go:412)."""
         import hashlib

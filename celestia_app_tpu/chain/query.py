@@ -75,7 +75,7 @@ def build_prover_entry(app, height: int):
     accelerator backend they do not own) otherwise."""
     block, square = rebuild_square(app, height)
     cache = getattr(app, "eds_cache", None)
-    engine = getattr(app, "engine", "auto")
+    engine = app.engine
     codec = getattr(app, "codec", None)
     scheme = codec.name if codec is not None else "rs2d-nmt"
     if cache is not None:
@@ -102,7 +102,7 @@ def build_prover(app, height: int):
         raise QueryError(
             f"share proofs are not defined under DA scheme "
             f"{entry.scheme!r}")
-    prover = entry.get_prover(getattr(app, "engine", "auto"))
+    prover = entry.get_prover()
     return block, square, prover, entry.data_root
 
 

@@ -127,14 +127,6 @@ class ReactorConfig:
     net_retries: int = 1
     breaker_failures: int = 3
     breaker_reset: float = 2.5
-    # the mesh plane's produce→commit batching (chain/producer.py): a
-    # proposer with produce_batch > 1 speculatively plans that many
-    # upcoming proposal squares from its mempool and batch-extends them
-    # in ONE device dispatch BEFORE taking the service lock to propose,
-    # seeding the EDS cache with device-resident entries. Consensus
-    # bytes are unchanged (the batch is a prefetch); fed from the home
-    # config `produce_batch` key (cli.py). 1 = off.
-    produce_batch: int = 1
 
 
 class ConsensusReactor:
@@ -202,9 +194,6 @@ class ConsensusReactor:
         self._msg_lock = threading.Lock()
         self._proposals: dict[tuple[int, int], c.Proposal] = {}
         self._votes: dict[tuple[int, int, str], dict[bytes, c.Vote]] = {}
-        # next height at which the proposer re-plans a produce batch
-        # (mesh plane; one plan per produce_batch window)
-        self._prewarm_after = 0
         self._pending_commits: list[dict] = []
         self._vote_pool: list[c.Vote] = []  # precommits, for evidence
         self._recent: dict[int, dict] = {}  # height -> gossiped commit doc
@@ -1307,25 +1296,6 @@ class ConsensusReactor:
         # a proposer that lacks the height-1 cert (it state-synced into
         # this height) cannot author valid commit info; it stays silent
         # and the round rotates past it
-        if i_am_proposer and self.cfg.produce_batch > 1 \
-                and (height == 1 or my_last_cert is not None) \
-                and height >= self._prewarm_after:
-            # mesh-plane produce prefetch: batch-extend the next
-            # produce_batch speculative squares OUTSIDE the service lock
-            # (the dispatch — first-call jit compile included — must
-            # never stall the round) so propose() below hits a warm
-            # device-resident entry. One plan per BATCH WINDOW, not per
-            # round (planning B squares every proposal would multiply
-            # the greedy layout work by B). Failures are counted, never
-            # fatal: the propose path extends per block exactly as
-            # without the knob.
-            self._prewarm_after = height + self.cfg.produce_batch
-            try:
-                self.vnode.prewarm_proposals(self.cfg.produce_batch)
-            except Exception as e:
-                telemetry.incr("reactor.prewarm_errors")
-                log.warning("produce prewarm failed", height=height,
-                            err=e)
         if i_am_proposer and (height == 1 or my_last_cert is not None):
             with self._msg_lock:
                 pool = [list(self._vote_pool)]

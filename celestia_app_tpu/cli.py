@@ -399,11 +399,8 @@ def _write_config(home: str, chain_id: str, engine: str = "auto") -> None:
                 "da_scheme": "rs2d-nmt",
                 # mesh plane (docs/FORMATS.md §18.1): max_square_size
                 # raises the CONSENSUS square cap to admit k=256/512
-                # (null = reference 128; every validator must match);
-                # produce_batch > 1 batch-extends that many planned
-                # blocks per device dispatch on the produce path
+                # (null = reference 128; every validator must match)
                 "max_square_size": None,
-                "produce_batch": 1,
                 # serving plane (das/packs.py): newest-N proof packs
                 # kept under <home>/packs (0 = keep all, null = off)
                 "pack_keep": 4,
@@ -495,29 +492,10 @@ def cmd_start(args) -> int:
     )
     snap_keep = cfg.get("snapshot_keep_recent", appconsts.SNAPSHOT_KEEP_RECENT)
     snap_root = os.path.join(args.home, "snapshots")
-    # mesh plane: produce_batch > 1 plans that many blocks from the
-    # mempool and batch-extends them in ONE device dispatch before the
-    # per-block rounds run (chain/producer.py; FORMATS §18.1). The
-    # planning+extend runs OUTSIDE the service lock — only the per-block
-    # consensus round holds it, exactly as with batching off.
-    produce_batch = max(1, int(cfg.get("produce_batch", 1)))
     produced = 0
     try:
         while args.blocks is None or produced < args.blocks:
             time.sleep(args.block_time)
-            # one plan+warm per BATCH WINDOW (planning B squares per
-            # produced block would multiply the greedy layout work by
-            # B); a mid-window mempool change just means a per-block
-            # extend for the affected heights
-            if produce_batch > 1 and produced % produce_batch == 0:
-                from celestia_app_tpu.chain import producer
-
-                try:
-                    plans = producer.plan_block_squares(
-                        app, node._reap(), produce_batch)
-                    producer.warm_block_batch(app, plans)
-                except Exception as e:
-                    print(f"produce prewarm failed: {e}", file=sys.stderr)
             with svc.lock:
                 blk, results = node.produce_block()
             produced += 1
@@ -822,7 +800,7 @@ def cmd_das_serve(args) -> int:
     svc = SampleService(core, port=args.listen)
     packs_on = core.pack_store is not None
     print(f"das-serve: http on :{svc.port} (height {app.height}, "
-          f"engine={getattr(app, 'engine', 'host')}, "
+          f"engine={app.engine}, "
           f"packs={'on' if packs_on else 'off'})", flush=True)
     try:
         svc.serve_forever()
@@ -848,7 +826,7 @@ def cmd_blob_serve(args) -> int:
     svc = BlobService(blob_core, port=args.listen)
     packs_on = blob_core.pack_store is not None
     print(f"blob-serve: http on :{svc.port} (height {app.height}, "
-          f"engine={getattr(app, 'engine', 'host')}, "
+          f"engine={app.engine}, "
           f"packs={'on' if packs_on else 'off'})", flush=True)
     try:
         svc.serve_forever()
@@ -1158,8 +1136,7 @@ def cmd_validator_serve(args) -> int:
         # one machine cannot share one chip, and a host-engine process
         # must not initialise an accelerator backend it does not own —
         # _ensure_home_config writes "host"); a home explicitly
-        # provisioned with "mesh"/"device"/"auto" opts in, which is how
-        # a mesh validator (and its produce_batch prewarm) is deployed
+        # provisioned with "device"/"auto" opts in
         engine=home_cfg.get("engine", "host"),
     )
     # fault plane (chaos provisioning): <home>/faults.json arms named
@@ -1230,29 +1207,7 @@ def cmd_validator_serve(args) -> int:
                 return
             with open(peers_path) as f:
                 peers = json.load(f)
-            from celestia_app_tpu.chain.reactor import ReactorConfig
-
-            cfg_doc = {}
-            cfg_path = os.path.join(args.home, "reactor.json")
-            if os.path.exists(cfg_path):
-                with open(cfg_path) as f:
-                    cfg_doc = json.load(f)
-            # sync plane: the home config's snapshot knobs (the same keys
-            # cmd_start reads) feed the reactor's interval-snapshot hook;
-            # an explicit reactor.json entry wins
-            if "snapshot_interval" not in cfg_doc and \
-                    "snapshot_interval_blocks" in home_cfg:
-                cfg_doc["snapshot_interval"] = \
-                    home_cfg["snapshot_interval_blocks"]
-            if "snapshot_keep" not in cfg_doc and \
-                    "snapshot_keep_recent" in home_cfg:
-                cfg_doc["snapshot_keep"] = home_cfg["snapshot_keep_recent"]
-            # mesh plane: the produce→commit batching knob rides the
-            # same home-config feed (an explicit reactor.json wins)
-            if "produce_batch" not in cfg_doc and \
-                    "produce_batch" in home_cfg:
-                cfg_doc["produce_batch"] = home_cfg["produce_batch"]
-            cfg = ReactorConfig(**cfg_doc)
+            cfg = _reactor_config(args.home, home_cfg)
             svc.attach_reactor([u for u in peers if u !=
                                 f"http://127.0.0.1:{svc.port}"], cfg)
             print(f"{vnode.name}: autonomous reactor up "
@@ -1269,6 +1224,38 @@ def cmd_validator_serve(args) -> int:
         if http_service is not None:
             http_service.shutdown()
     return 0
+
+
+# keys an older version wrote into reactor.json for options since removed
+_RETIRED_REACTOR_KEYS = ("produce_batch",)
+
+
+def _reactor_config(home: str, home_cfg: dict):
+    """The validator's ReactorConfig: <home>/reactor.json, with the home
+    config's snapshot knobs where reactor.json does not set them. A key
+    of a removed option is dropped with one warning line."""
+    from celestia_app_tpu.chain.reactor import ReactorConfig
+
+    cfg_doc = {}
+    cfg_path = os.path.join(home, "reactor.json")
+    if os.path.exists(cfg_path):
+        with open(cfg_path) as f:
+            cfg_doc = json.load(f)
+    # sync plane: the home config's snapshot knobs (the same keys
+    # cmd_start reads) feed the reactor's interval-snapshot hook; an
+    # explicit reactor.json entry wins
+    if "snapshot_interval" not in cfg_doc and \
+            "snapshot_interval_blocks" in home_cfg:
+        cfg_doc["snapshot_interval"] = home_cfg["snapshot_interval_blocks"]
+    if "snapshot_keep" not in cfg_doc and \
+            "snapshot_keep_recent" in home_cfg:
+        cfg_doc["snapshot_keep"] = home_cfg["snapshot_keep_recent"]
+    for key in _RETIRED_REACTOR_KEYS:
+        if key in cfg_doc:
+            del cfg_doc[key]
+            print(f"reactor.json: ignoring {key!r}, an option this "
+                  "version no longer has", file=sys.stderr, flush=True)
+    return ReactorConfig(**cfg_doc)
 
 
 def _spawn_validator_processes(args, genesis, extra_flags=(),

@@ -180,7 +180,7 @@ class CmtEntry:
         return self.layers[0][: k * k].reshape(
             k, k, appconsts.SHARE_SIZE)
 
-    def warm(self, engine: str = "auto") -> None:
+    def warm(self) -> None:
         """Proof machinery is the hash lists, already built at encode —
         nothing to pre-build (the warmer calls this for every scheme)."""
 

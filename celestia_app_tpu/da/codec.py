@@ -75,7 +75,7 @@ class Codec:
     ``.scheme`` (name), ``.data_root`` (32 bytes), ``.dah`` (the
     commitments object: a DataAvailabilityHeader for rs2d-nmt, a
     CmtCommitments for cmt-ldpc — both with ``.hash() == data_root``),
-    ``.k`` (ODS width) and ``.warm(engine)`` (pre-build proof machinery
+    ``.k`` (ODS width) and ``.warm()`` (pre-build proof machinery
     off the hot path)."""
 
     scheme_id: int

@@ -93,25 +93,9 @@ def jitted_pipeline(k: int):
                                   jax.jit(pipeline_fn(k)))
 
 
-@functools.lru_cache(maxsize=None)
-def jitted_pipeline_batched(k: int):
-    """Compiled (B, k, k, 512) -> batched (eds, row_roots, col_roots,
-    data_roots): one dispatch covers B blocks, amortizing launch overhead
-    and keeping the MXU fed when single squares underfill it (the
-    one-chip analog of the sharded pipeline's `data` axis; BASELINE cfg 5
-    throughput). vmap of the single-square program — bit-identical per
-    block (tests/test_sharded_eds.py)."""
-    from celestia_app_tpu.obs import jax_profile
-
-    jax_profile.note_compile("eds.pipeline_batched", k)
-    return jax_profile.instrument(f"eds.pipeline_batched[{k}]",
-                                  jax.jit(jax.vmap(pipeline_fn(k))))
-
-
 # live jit-cache-size accounting (obs/jax_profile collect_gauges): the
 # gauge reads cache_info().currsize, so a cache_clear() keeps it honest
 from celestia_app_tpu.obs import jax_profile as _jax_profile  # noqa: E402
 
-for _factory in (jitted_pipeline, jitted_pipeline_batched):
-    _jax_profile.register_cache(_factory)
-del _factory, _jax_profile
+_jax_profile.register_cache(jitted_pipeline)
+del _jax_profile

@@ -167,7 +167,7 @@ class PcmtEntry:
         k = self.commitments.k
         return self._ods.reshape(k, k, appconsts.SHARE_SIZE)
 
-    def warm(self, engine: str = "auto") -> None:
+    def warm(self) -> None:
         """Proof machinery (hash lists + subtrees) is built at encode —
         nothing to pre-build."""
 

@@ -32,9 +32,9 @@ sweep, tests/test_repair.py):
   (ops/rs.repair_axis) plus a host NmtTree per axis — kept as the
   independent differential reference.
 
-Mesh sharding (the mesh plane, PR 13): when
+Mesh sharding (the mesh plane): when the sharding rule
 `parallel/mesh_engine.mesh_active_for(k)` holds — k at or above
-CELESTIA_MESH_MIN_K with two or more devices — the batched engine's two
+`MESH_MIN_K` with two or more devices — the batched engine's two
 device programs run sharded over the flat device list: the per-pattern
 fused decode matmul (ops/rs._RepairAxesRunner) and the per-sweep NMT
 root reduction (ops/nmt.eds_axis_roots) both split their pow2-padded

@@ -380,8 +380,7 @@ class GrpcTxServer:
             from celestia_app_tpu.service.da_service import DACore
 
             da_core = DACore(
-                engine="device" if getattr(node.app, "engine", "host")
-                in ("device", "mesh") else "host"
+                engine="device" if node.app.engine == "device" else "host"
             )
         self.da = DAGrpcService(da_core)
         q = self.queries

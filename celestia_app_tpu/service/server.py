@@ -62,8 +62,7 @@ class NodeService:
         from celestia_app_tpu.service.da_service import DACore
 
         self.da_core = DACore(
-            engine="device" if getattr(node.app, "engine", "host")
-            in ("device", "mesh") else "host"
+            engine="device" if node.app.engine == "device" else "host"
         )
         # the DAS sample-serving plane (das/server.py): committed blocks
         # answered cell-by-cell with NMT proofs from cached row trees.

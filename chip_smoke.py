@@ -20,7 +20,8 @@ call real:
   counters    no fallback fired on the device-engine phases.
 
 `--chips 4` runs ONLY the sharded path and what it is compared with: a
-seeded k=256 ODS through compute_entry(ods, "mesh") on four chips, the
+seeded k=256 ODS through compute_entry(ods, "device") on four chips (the
+sharding rule takes k >= 256 there: parallel/mesh_engine.py), the
 one-chip program on chip 0 and the host engine; the three data roots
 equal, the resident entry spread over four devices, and 16 cells proved
 on the chips from the resident square and the mesh entry's sharded level
@@ -359,7 +360,7 @@ def run_one_chip(args, sizes, counters: DeviceCounters) -> None:
                   and entry.data_root == ref_entry.data_root,
                   f"k={k}: data root differs between device and host")
             with counters.section():
-                prover = entry.get_prover("device")
+                prover = entry.get_prover()
             row, col = k + 3, 2 * k - 5  # a parity-quadrant cell
             share, proof = prover.prove_cell(row, col)
             check(sampling.verify_sample(entry.dah, row, col, share, proof),
@@ -402,21 +403,25 @@ def run_four_chips(args, sizes, counters: DeviceCounters) -> None:
 
     from celestia_app_tpu.da import edscache
     from celestia_app_tpu.da import eds as eds_mod
+    from celestia_app_tpu.parallel import mesh_engine
 
     k = sizes["mesh_k"]
     ods = seeded_ods(k, args.seed)
     n_dev = len(jax.devices())
+    if args.rehearse_cpu:
+        # the rehearsal's small square shards as k=256 does on the chips
+        mesh_engine.MESH_MIN_K = k
 
     with Phase("mesh") as ph, counters.section():
-        entry = edscache.compute_entry(ods, "mesh")
+        entry = edscache.compute_entry(ods, "device")
         ph.first_result()
         check(isinstance(entry, edscache.DeviceEntry),
-              "the mesh engine returned a host entry")
+              "the device engine returned a host entry")
         devices = entry._eds_dev.sharding.device_set
         check(len(devices) == n_dev,
               f"the resident EDS sits on {len(devices)} of {n_dev} devices")
         ph.checked.update(
-            k=k, engine="mesh", data_root=entry.data_root.hex(),
+            k=k, engine="device", data_root=entry.data_root.hex(),
             eds_shape=list(entry._eds_dev.shape), eds_devices=len(devices),
             shard_shape=list(
                 entry._eds_dev.addressable_shards[0].data.shape),
@@ -458,7 +463,7 @@ def run_four_chips(args, sizes, counters: DeviceCounters) -> None:
         # mesh entry has no host copy, and none is made
         for col in (False, True):
             got = entry.prove_cells(cells, col=col)
-            want = ref.prove_cells(cells, col=col, engine="host")
+            want = ref.prove_cells(cells, col=col)
             for (r, c), g, w in zip(cells, got, want):
                 check(g == w, f"k={k}: gathered and host "
                       f"{'column' if col else 'row'} proofs of ({r}, {c}) "

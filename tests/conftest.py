@@ -75,3 +75,27 @@ def racecheck_guard():
         "lock-order inversions: "
         + "; ".join(v["message"] for v in vios)
     )
+
+
+@pytest.fixture(scope="session")
+def shard_small():
+    """`with shard_small(): ...` — inside, the device engine splits
+    squares from k=2 up over the virtual devices (or the first `chips`
+    of them), as it splits k >= 256 squares over real chips: the one
+    sharding rule's threshold (parallel/mesh_engine.MESH_MIN_K) lowered.
+    Session-scoped, so module fixtures can build sharded entries too;
+    everything is restored on leaving the block."""
+    import contextlib
+
+    from celestia_app_tpu.parallel import mesh_engine
+
+    @contextlib.contextmanager
+    def lowered(min_k: int = 2, chips: int | None = None):
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(mesh_engine, "MESH_MIN_K", min_k)
+            if chips is not None:
+                mp.setattr(mesh_engine, "_usable_device_count",
+                           lambda: chips)
+            yield
+
+    return lowered

@@ -61,14 +61,17 @@ def _ods(k: int = 4, seed: int = 0) -> np.ndarray:
 
 
 @pytest.mark.backend
-def test_ledger_counts_match_host_crossings():
-    """Each lazy materialization site of a DeviceEntry (host square, row
-    levels, col levels) is one ledger d2h call AND one host_crossing —
-    the old narrow counter and the universal ledger agree."""
+def test_ledger_counts_match_host_crossings(shard_small):
+    """Each lazy materialization site of a sharded DeviceEntry (host
+    square, row levels, col levels) is one ledger d2h call AND one
+    host_crossing — the old narrow counter and the universal ledger
+    agree."""
     from celestia_app_tpu.da import edscache
 
-    entry = edscache.compute_entry(_ods(seed=11), "mesh")
-    assert isinstance(entry, edscache.DeviceEntry)
+    with shard_small():
+        entry = edscache.compute_entry(_ods(seed=11), "device")
+    assert isinstance(entry, edscache.DeviceEntry) and entry.chips > 1
+    passes = _counter("mesh.sharded_level_passes")
 
     before_cross = _counter("edscache.host_crossings")
     # the host square is the entry's own site; both level stacks come
@@ -78,9 +81,10 @@ def test_ledger_counts_match_host_crossings():
     bytes_before = xfer.totals()["d2h_bytes"]
 
     _ = entry.eds                       # host square
-    entry.get_prover("auto")            # row levels -> host
-    entry.get_col_prover("auto")        # col levels -> host
+    entry.get_prover()                  # row levels -> host
+    entry.get_col_prover()              # col levels -> host
 
+    assert _counter("mesh.sharded_level_passes") - passes == 2
     for site, calls in sites.items():
         assert _counter("xfer.d2h_calls", site=site) - before[site] == calls, \
             site
@@ -90,23 +94,26 @@ def test_ledger_counts_match_host_crossings():
     # the second read of every site is cached: no further crossings
     snap2 = {site: _counter("xfer.d2h_calls", site=site) for site in before}
     _ = entry.eds
-    entry.get_prover("auto")
-    entry.get_col_prover("auto")
+    entry.get_prover()
+    entry.get_col_prover()
     for site in before:
         assert _counter("xfer.d2h_calls", site=site) == snap2[site]
 
 
 @pytest.mark.backend
-def test_no_implicit_transfers_pins_warmed_produce_path():
-    """The acceptance-criterion residency pin: a warmed DeviceEntry's
-    produce-side work stays on device inside `no_implicit_transfers()`,
-    ledger-mediated fetches stay legal, and a stray np.asarray of the
-    device value raises."""
+def test_no_implicit_transfers_pins_warmed_produce_path(shard_small):
+    """The acceptance-criterion residency pin: a warmed sharded
+    DeviceEntry's produce-side work stays on device inside
+    `no_implicit_transfers()`, ledger-mediated fetches stay legal, and a
+    stray np.asarray of the device value raises."""
     from celestia_app_tpu.da import edscache
 
-    entry = edscache.compute_entry(_ods(seed=12), "mesh")
-    assert isinstance(entry, edscache.DeviceEntry)
+    with shard_small():
+        entry = edscache.compute_entry(_ods(seed=12), "device")
+    assert isinstance(entry, edscache.DeviceEntry) and entry.chips > 1
+    passes = _counter("mesh.sharded_level_passes")
     entry.warm()
+    assert _counter("mesh.sharded_level_passes") - passes == 2
 
     with no_implicit_transfers():
         # the warmed path: device levels exist, nothing crosses

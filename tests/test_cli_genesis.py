@@ -10,6 +10,8 @@ import hashlib
 import json
 import os
 
+import pytest
+
 from celestia_app_tpu import cli
 from celestia_app_tpu.chain.crypto import PrivateKey
 
@@ -175,3 +177,26 @@ def test_store_trace_records_commits(tmp_path):
     # exactly blocks 1 and 2 (no off-by-one attribution to N-1)
     heights = {ln["height"] for ln in lines}
     assert heights == {1, 2}, heights
+
+
+@pytest.mark.parametrize("doc,home_cfg,want,warned", [
+    ({"produce_batch": 4}, {}, {}, True),
+    ({"produce_batch": 1, "poll": 0.05}, {"snapshot_interval_blocks": 7},
+     {"poll": 0.05, "snapshot_interval": 7}, True),
+    ({"poll": 0.05}, {"snapshot_keep_recent": 3},
+     {"poll": 0.05, "snapshot_keep": 3}, False),
+])
+def test_an_older_reactor_json_still_starts(tmp_path, capsys, doc, home_cfg,
+                                            want, warned):
+    """A reactor.json written by an older version may name an option
+    since removed: the validator's reactor config drops it with one
+    warning line, keeps every other key, and fills the snapshot knobs
+    from the home config."""
+    home = str(tmp_path)
+    with open(os.path.join(home, "reactor.json"), "w") as f:
+        json.dump(doc, f)
+    cfg = cli._reactor_config(home, home_cfg)
+    for key, value in want.items():
+        assert getattr(cfg, key) == value
+    err = capsys.readouterr().err
+    assert err.count("ignoring") == int(warned)

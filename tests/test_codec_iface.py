@@ -116,7 +116,7 @@ def test_rs2d_byte_identity_vs_frozen_vectors(k, engine):
     assert entry.data_root.hex() == FROZEN_RS2D_ROOT[k]
     assert entry.dah.hash().hex() == FROZEN_RS2D_ROOT[k]
     assert entry.dah.row_roots[0].hex().startswith(FROZEN_RS2D_ROW0)
-    share, proof = entry.get_prover(engine).prove_cell(1, 2)
+    share, proof = entry.get_prover().prove_cell(1, 2)
     digest = hashlib.sha256(b"".join([share] + proof.nodes)).hexdigest()
     want_digest, start, end, total, n_nodes = FROZEN_RS2D_PROOF[k]
     assert digest == want_digest
@@ -638,7 +638,7 @@ def test_edscache_keys_are_scheme_disjoint():
     assert cache.lookup_root(cm.data_root) is cm
     # cmt entries satisfy the block-plane entry contract
     assert cm.k == 4 and cm.dah.hash() == cm.data_root
-    cm.warm("host")  # no-op, must not raise
+    cm.warm()  # no-op, must not raise
 
 
 def test_snapshot_manifest_carries_scheme_and_bootstrap_refuses():

@@ -49,7 +49,7 @@ class _Front:
     """Two served mesh heights behind the node service and the sidecar,
     and the host engine's in-process core over the same squares."""
 
-    def __init__(self, k: int):
+    def __init__(self, k: int, shard_small):
         from celestia_app_tpu.chain.app import App
         from celestia_app_tpu.chain.node import Node
         from celestia_app_tpu.das.server import SampleCore, SampleService
@@ -64,8 +64,12 @@ class _Front:
         self.entries = {}
         for h in HEIGHTS:
             ods = _random_ods(k, 4000 + 10 * k + h)
-            entry = edscache.compute_entry(ods, "mesh")
+            with shard_small():
+                entry = edscache.compute_entry(ods, "device")
             assert entry.chips == 8 and entry.residency() == "device"
+            p0 = _counter("mesh.sharded_level_passes")
+            entry.warm()
+            assert _counter("mesh.sharded_level_passes") - p0 == 2
             self.entries[h] = entry
             self.node_svc.das_core.seed_cache_entry(h, entry)
             self.side_core.seed_cache_entry(h, entry)
@@ -85,8 +89,8 @@ class _Front:
 
 
 @pytest.fixture(scope="module", params=[8, 16], ids=["k8", "k16"])
-def front(request):
-    f = _Front(request.param)
+def front(request, shard_small):
+    f = _Front(request.param, shard_small)
     yield f
     f.close()
 
@@ -323,7 +327,7 @@ class _BlobFront:
 
     HEIGHT = 5
 
-    def __init__(self):
+    def __init__(self, shard_small):
         from celestia_app_tpu.chain.app import App
         from celestia_app_tpu.chain.node import Node
         from celestia_app_tpu.da import dah as dah_mod
@@ -342,9 +346,13 @@ class _BlobFront:
         self.app = App(chain_id="blob-http")
         self.app.init_chain({"time_unix": 0})
         self.svc = NodeService(Node(self.app), port=0)
-        entry = edscache.compute_entry(
-            dah_mod.shares_to_ods(sq.share_bytes()), "mesh")
-        assert entry.residency() == "device"
+        with shard_small():
+            entry = edscache.compute_entry(
+                dah_mod.shares_to_ods(sq.share_bytes()), "device")
+        assert entry.residency() == "device" and entry.chips == 8
+        p0 = _counter("mesh.sharded_level_passes")
+        entry.warm()
+        assert _counter("mesh.sharded_level_passes") - p0 == 2
         self.svc.das_core.seed_cache_entry(self.HEIGHT, entry)
         self.namespaces = [b.namespace.raw.hex() for b in blobs]
         self.svc.serve_background()
@@ -355,8 +363,8 @@ class _BlobFront:
 
 
 @pytest.fixture(scope="module")
-def blob_front():
-    f = _BlobFront()
+def blob_front(shard_small):
+    f = _BlobFront(shard_small)
     yield f
     f.close()
 

@@ -19,7 +19,7 @@ def _read(*parts: str) -> str:
 def test_every_environment_name_the_library_reads_is_documented():
     """The `CELESTIA_*` literals under celestia_app_tpu/ equal the first
     column of DESIGN.md's "Environment names" table, each row names a file
-    that holds the literal, and there are 23 of them: a 24th is an option
+    that holds the literal, and there are 20 of them: a 21st is an option
     someone has to argue for (ROADMAP, Design aim)."""
     in_code: dict[str, set[str]] = {}
     for path in glob.glob(os.path.join(PACKAGE, "**", "*.py"), recursive=True):
@@ -32,7 +32,7 @@ def test_every_environment_name_the_library_reads_is_documented():
     rows = re.findall(r"^\| `(CELESTIA_[A-Z0-9_]+)` \| ([^|]+) \|", section,
                       flags=re.M)
     assert sorted(name for name, _ in rows) == sorted(in_code)
-    assert len(rows) == 23
+    assert len(rows) == 20
     for name, modules in rows:
         for module in re.findall(r"`([^`]+)`", modules):
             assert module in in_code[name], (name, module)

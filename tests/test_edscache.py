@@ -173,8 +173,8 @@ def test_cached_cold_and_cross_engine_byte_identical():
     _entries_equal(warm, dev_cold)
 
     # proofs: host-levels prover vs jitted-levels prover, byte for byte
-    ph = host_cold.get_prover("host")
-    pd = dev_cold.get_prover("auto")
+    ph = host_cold.get_prover()
+    pd = dev_cold.get_prover()
     for (r, c) in [(0, 0), (3, 7), (7, 2), (5, 5)]:
         sh, prh = ph.prove_cell(r, c)
         sd, prd = pd.prove_cell(r, c)
@@ -183,8 +183,8 @@ def test_cached_cold_and_cross_engine_byte_identical():
         assert (prh.start, prh.end, prh.total) == (prd.start, prd.end,
                                                    prd.total)
     # col provers too (the BEFP escalation surface)
-    ch = host_cold.get_col_prover("host")
-    cd = dev_cold.get_col_prover("auto")
+    ch = host_cold.get_col_prover()
+    cd = dev_cold.get_col_prover()
     s1, p1 = ch.prove_cell(2, 6)
     s2, p2 = cd.prove_cell(2, 6)
     assert s1 == s2 and p1.nodes == p2.nodes
@@ -380,8 +380,8 @@ def test_device_engine_entry_is_resident_and_byte_identical(k):
     assert not isinstance(host, edscache.DeviceEntry)
     assert len(dev.dah.row_roots) == len(dev.dah.col_roots) == 2 * k
     _entries_equal(host, dev)
-    row_h, row_d = host.get_prover("host"), dev.get_prover("device")
-    col_h, col_d = host.get_col_prover("host"), dev.get_col_prover("device")
+    row_h, row_d = host.get_prover(), dev.get_prover()
+    col_h, col_d = host.get_col_prover(), dev.get_col_prover()
     for r, c in _quadrant_cells(k):
         for ph, pd, cell in ((row_h, row_d, (r, c)), (col_h, col_d, (c, r))):
             sh, prh = ph.prove_cell(*cell)
@@ -432,8 +432,8 @@ def test_only_the_roots_cross_inside_compute_entry(k, monkeypatch):
     assert entry.eds.squares.nbytes == square
     assert _site("xfer.d2h_bytes", site) - d0 == 4 * k * 90 + 32 + square
     assert entry.residency() == "device+host"
-    entry.get_prover("device")
-    entry.get_col_prover("device")
+    entry.get_prover()
+    entry.get_col_prover()
     _ = entry.eds
     assert _site("xfer.d2h_bytes", site) - d0 == 4 * k * 90 + 32 + square
 
@@ -511,8 +511,8 @@ def test_prover_on_a_resident_entry_uploads_nothing():
     u0, d0 = _site("xfer.h2d_bytes", site), _site("xfer.d2h_bytes", site)
     up0 = _c("xfer.h2d_calls")
     entry.warm()
-    row = entry.get_prover("device")
-    col = entry.get_col_prover("device")
+    row = entry.get_prover()
+    col = entry.get_col_prover()
     assert _site("xfer.h2d_bytes", site) - u0 == 0
     # each orientation's level stack came down at the prover's site, once
     stack = sum(a.nbytes for lvl in row.levels for a in lvl)
@@ -548,7 +548,7 @@ def test_warmed_chain_answers_the_sample_from_the_built_prover(tmp_path):
             levels = entry._levels_dev
             out = core.sample_many(height, [(0, 0), (1, 1)])
             assert len(out["samples"]) == 2
-            assert entry.get_prover("auto") is core._cache[height].prover
+            assert entry.get_prover() is core._cache[height].prover
             assert entry._levels_dev is levels
             assert _c("da.extend_runs") - c0 == 1
             assert _c("edscache.eds_fetch_started") - s0 == 1
@@ -569,8 +569,8 @@ def test_evicted_entry_frees_its_device_arrays_by_refcount():
     cache = edscache.EdsCache(max_entries=1)
     first = cache.get_or_compute(_ods(k=4, seed=80), "device")
     first.warm()
-    first.get_prover("device")
-    first.get_col_prover("device")
+    first.get_prover()
+    first.get_col_prover()
     refs = [weakref.ref(first._eds_dev), weakref.ref(first._levels_dev[0][0]),
             weakref.ref(first)]
     gc.disable()

@@ -47,7 +47,7 @@ class _Chain:
     """Two copy-less mesh heights behind one SampleCore, and the host
     engine's core over the same squares."""
 
-    def __init__(self):
+    def __init__(self, shard_small):
         from celestia_app_tpu.chain.app import App
         from celestia_app_tpu.das.server import SampleCore
 
@@ -57,22 +57,26 @@ class _Chain:
         self.entries, self.hosts = {}, {}
         for h in HEIGHTS:
             ods = _random_ods(5100 + h)
-            entry = edscache.compute_entry(ods, "mesh")
+            with shard_small():
+                entry = edscache.compute_entry(ods, "device")
             assert entry.chips == 8 and entry.residency() == "device"
             self.entries[h] = entry
             self.hosts[h] = edscache.compute_entry(ods, "host")
             self.core.seed_cache_entry(h, entry)
             self.core_h.seed_cache_entry(h, self.hosts[h])
+            p0 = _counter("mesh.sharded_level_passes")
             for axis in ("row", "col"):
                 # the first dispatch of each orientation, and the host
                 # core's first touch: paid before anything is counted
                 self.core.sample(h, 0, 0, axis=axis)
                 self.core_h.sample(h, 0, 0, axis=axis)
+            # both orientations' level passes ran split over the devices
+            assert _counter("mesh.sharded_level_passes") - p0 == 2
 
 
 @pytest.fixture(scope="module")
-def chain():
-    c = _Chain()
+def chain(shard_small):
+    c = _Chain(shard_small)
     yield c
     c.app.close()
 

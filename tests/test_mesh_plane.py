@@ -1,14 +1,17 @@
-"""Mesh plane (ISSUE 13): sharded production lifecycle, device-resident
-entries, batched produce.
+"""Mesh plane: one engine decision, sharded production, device-resident
+entries.
 
 Runs on the 8-virtual-device CPU mesh (conftest sets
-``XLA_FLAGS=--xla_force_host_platform_device_count=8``). The contract
-under test: the mesh engine is the PRODUCTION dispatch — bit-identical
-to the single-device/host engines at every co-supported size (entries,
-DAH roots, data roots, row+col cell proofs), device-resident until a
-proof/serve path actually needs host bytes (pinned by the
-``edscache.host_crossings`` counter), and the batched produce path
-commits the exact block/app hashes of per-block production.
+``XLA_FLAGS=--xla_force_host_platform_device_count=8``; its
+``shard_small`` fixture lowers the sharding rule's threshold so squares
+of k=2 and up shard as k >= 256 squares do on chips). The contract under
+test: an App takes one of four engine names and keeps one of three; the
+device engine splits a square over the chips exactly when the sharding
+rule holds; the sharded program is bit-identical to the single-device
+and host engines at every co-supported size (entries, DAH roots, data
+roots, row+col cell proofs) and device-resident until a proof/serve path
+actually needs host bytes (pinned by the ``edscache.host_crossings``
+counter).
 """
 
 import base64
@@ -34,6 +37,10 @@ def _counter(name: str) -> int:
     return telemetry.snapshot().get("counters", {}).get(name, 0)
 
 
+def _spans(name: str) -> int:
+    return _counter(f'obs.span_n{{name="{name}"}}')
+
+
 def _assert_proofs_equal(a, b):
     sa, pa = a
     sb, pb = b
@@ -43,18 +50,68 @@ def _assert_proofs_equal(a, b):
 
 
 # ---------------------------------------------------------------------------
+# the engine decision: named once at the App, sharded by what it can see
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("name,kept", [
+    ("host", "host"), ("device", "device"), ("auto", "auto"),
+    ("mesh", "device"),
+])
+def test_app_keeps_one_of_three_engines(name, kept):
+    from celestia_app_tpu.chain.app import App
+
+    assert App(chain_id="engine-names", engine=name).engine == kept
+
+
+@pytest.mark.parametrize("name", ["Device", "gpu", ""])
+def test_app_refuses_an_unknown_engine(name):
+    """A mistyped engine name raises at construction instead of falling
+    through to the host path."""
+    from celestia_app_tpu.chain.app import App
+
+    with pytest.raises(ValueError, match="unknown engine"):
+        App(chain_id="engine-names", engine=name)
+    with pytest.raises(ValueError, match="unknown engine"):
+        edscache.compute_entry(_random_ods(2, 0), name)
+
+
+@pytest.mark.parametrize("k,sharded", [(8, False), (16, True)])
+@pytest.mark.parametrize("engine", ["device", "auto", "mesh"])
+def test_the_sharding_rule_decides_on_k(engine, k, sharded, shard_small):
+    """With the rule's threshold at 16, a square on either side of it
+    takes the single-chip or the sharded program, whichever device
+    engine is named: pinned by the extend's span, the entry's chips and
+    its level passes."""
+    ods = _random_ods(k, 7)
+    m0, d0 = _spans("mesh.extend.run"), _spans("da.extend.run")
+    p0 = _counter("mesh.sharded_level_passes")
+    with shard_small(min_k=16):
+        entry = edscache.compute_entry(ods, engine)
+        entry.warm()
+    assert isinstance(entry, edscache.DeviceEntry)
+    assert _spans("mesh.extend.run") - m0 == int(sharded)
+    assert _spans("da.extend.run") - d0 == int(not sharded)
+    assert entry.chips == (8 if sharded else 1)
+    assert _counter("mesh.sharded_level_passes") - p0 == 2 * sharded
+    assert entry.data_root == edscache.compute_entry(ods, "host").data_root
+
+
+# ---------------------------------------------------------------------------
 # bit-identity: mesh entry == host/single-device entry
 # ---------------------------------------------------------------------------
 
 
 @pytest.mark.parametrize("k", [8, 32])
-def test_mesh_entry_bit_identical_to_host(k):
+def test_mesh_entry_bit_identical_to_host(k, shard_small):
     """Sharded compute_entry == host compute_entry byte for byte:
     EDS, row/col roots, data root, and row+col cell proofs."""
     ods = _random_ods(k, 1000 + k)
     host = edscache.compute_entry(ods, "host")
-    mesh = edscache.compute_entry(ods, "mesh")
-    assert isinstance(mesh, edscache.DeviceEntry)
+    with shard_small():
+        mesh = edscache.compute_entry(ods, "device")
+    assert isinstance(mesh, edscache.DeviceEntry) and mesh.chips == 8
+    p0 = _counter("mesh.sharded_level_passes")
 
     assert mesh.data_root == host.data_root
     assert mesh.dah.row_roots == host.dah.row_roots
@@ -62,8 +119,9 @@ def test_mesh_entry_bit_identical_to_host(k):
     assert mesh.k == host.k == k
     np.testing.assert_array_equal(mesh.eds.squares, host.eds.squares)
 
-    ph, pm = host.get_prover("host"), mesh.get_prover()
-    ch, cm = host.get_col_prover("host"), mesh.get_col_prover()
+    ph, pm = host.get_prover(), mesh.get_prover()
+    ch, cm = host.get_col_prover(), mesh.get_col_prover()
+    assert _counter("mesh.sharded_level_passes") - p0 == 2
     rng = np.random.default_rng(k)
     for _ in range(4):
         r, c = (int(x) for x in rng.integers(0, 2 * k, size=2))
@@ -72,32 +130,24 @@ def test_mesh_entry_bit_identical_to_host(k):
         _assert_proofs_equal(ch.prove_cell(c, r), cm.prove_cell(c, r))
 
 
-def test_mesh_engine_via_auto_routing(monkeypatch):
-    """Under engine="auto", squares at/above CELESTIA_MESH_MIN_K route
-    through the mesh; below it they take the single-device program.
-    Both come back device-resident (one entry class for every
-    device-class engine): the distinction is where the square lives —
-    spread over the mesh, or on one device."""
-    monkeypatch.setenv("CELESTIA_MESH_MIN_K", "16")
-    big = edscache.compute_entry(_random_ods(16, 7), "auto")
-    small = edscache.compute_entry(_random_ods(8, 7), "auto")
-    assert isinstance(big, edscache.DeviceEntry)
-    assert isinstance(small, edscache.DeviceEntry)
-    assert len(big._eds_dev.sharding.device_set) > 1
-    assert len(small._eds_dev.sharding.device_set) == 1
-
-
-def test_mesh_engine_unshardable_square_degrades():
-    """engine="mesh" is device-class for the k=1 empty block (nothing
-    to shard): it must produce the classic entry, not raise — a mesh
-    validator committing an empty height stays alive."""
+def test_mesh_engine_unshardable_square_degrades(shard_small):
+    """The k=1 empty block has nothing to shard, even with the rule's
+    threshold below it: the device engine extends it on one chip, not
+    raise — a validator committing an empty height stays alive."""
     from celestia_app_tpu.da import dah as dah_mod
     from celestia_app_tpu.da import square as square_mod
 
     ods = dah_mod.shares_to_ods(square_mod.empty_square().share_bytes())
-    entry = edscache.compute_entry(ods, "mesh")
+    m0 = _spans("mesh.extend.run")
+    p0 = _counter("mesh.sharded_level_passes")
+    with shard_small(min_k=1):
+        entry = edscache.compute_entry(ods, "mesh")
+        entry.warm()
     host = edscache.compute_entry(ods, "host")
     assert entry.data_root == host.data_root
+    assert entry.chips == 1
+    assert _spans("mesh.extend.run") == m0
+    assert _counter("mesh.sharded_level_passes") == p0
 
 
 # ---------------------------------------------------------------------------
@@ -105,7 +155,7 @@ def test_mesh_engine_unshardable_square_degrades():
 # ---------------------------------------------------------------------------
 
 
-def test_device_residency_and_host_crossings():
+def test_device_residency_and_host_crossings(shard_small):
     """The extend->commit->warm chain never crosses the host boundary,
     and neither does a sample of an entry that has no host copy: its
     cells are cut on the chips and the entry stays "device". A host
@@ -115,16 +165,19 @@ def test_device_residency_and_host_crossings():
     from celestia_app_tpu.das.server import SampleCore
 
     k = 8
-    entry = edscache.compute_entry(_random_ods(k, 42), "mesh")
-    assert entry.residency() == "device"
+    with shard_small():
+        entry = edscache.compute_entry(_random_ods(k, 42), "device")
+    assert entry.residency() == "device" and entry.chips == 8
 
     c0 = _counter("edscache.host_crossings")
+    p0 = _counter("mesh.sharded_level_passes")
     # what the lifecycle reads at Prepare/Process/commit: commitments
     assert len(entry.dah.row_roots) == 2 * k
     assert len(entry.data_root) == 32
     # the warmer's per-scheme hook: device-side level passes only
     entry.warm()
     assert entry.warmed()
+    assert _counter("mesh.sharded_level_passes") - p0 == 2
     assert _counter("edscache.host_crossings") == c0
     assert entry.residency() == "device"
 
@@ -151,7 +204,7 @@ def test_device_residency_and_host_crossings():
     assert _counter("edscache.host_crossings") == after_first
 
 
-def test_device_entry_serves_das_with_crossings_pinned():
+def test_device_entry_serves_das_with_crossings_pinned(shard_small):
     """A seeded device-resident entry serves /das/* with a
     host_crossings delta of exactly 0 from the first sample on — its
     cells are gathered on the chips — and the availability record says
@@ -165,8 +218,11 @@ def test_device_entry_serves_das_with_crossings_pinned():
     app = App(chain_id="mesh-serve")
     app.init_chain({"time_unix": 0})
     core = SampleCore(app)
-    entry = edscache.compute_entry(_random_ods(k, 99), "mesh")
+    with shard_small():
+        entry = edscache.compute_entry(_random_ods(k, 99), "device")
+    p0 = _counter("mesh.sharded_level_passes")
     entry.warm()
+    assert _counter("mesh.sharded_level_passes") - p0 == 2
     core.seed_cache_entry(5, entry)
 
     host = edscache.compute_entry(_random_ods(k, 99), "host")
@@ -204,18 +260,18 @@ def test_device_entry_serves_das_with_crossings_pinned():
 # ---------------------------------------------------------------------------
 
 
-def _seeded_cores(k: int, seed: int, kind: str):
+def _seeded_cores(k: int, seed: int, kind: str, shard_small):
     """(entry under test, its SampleCore, the host engine's SampleCore)
     over one random square at height 7. `kind`: "mesh" (rows split over
     the 8 virtual devices) or "one-chip" (the single-device program's
-    resident square in an entry built without `eds_fetch`, as the
-    batched engine builds it)."""
+    resident square in an entry built without `eds_fetch`)."""
     from celestia_app_tpu.chain.app import App
     from celestia_app_tpu.das.server import SampleCore
 
     ods = _random_ods(k, seed)
     if kind == "mesh":
-        entry = edscache.compute_entry(ods, "mesh")
+        with shard_small():
+            entry = edscache.compute_entry(ods, "device")
         assert entry.chips == 8
     else:
         started = edscache.compute_entry(ods, "device")
@@ -233,7 +289,8 @@ def _seeded_cores(k: int, seed: int, kind: str):
 @pytest.mark.parametrize("axis", ["row", "col"])
 @pytest.mark.parametrize("k", [8, 16, 32])
 @pytest.mark.parametrize("kind", ["mesh", "one-chip"])
-def test_gathered_samples_equal_the_host_engines(kind, k, axis):
+def test_gathered_samples_equal_the_host_engines(kind, k, axis,
+                                                 shard_small):
     """Docs served from an entry with no host copy — its cells and proof
     nodes gathered by one program where the square lives — equal the
     host engine's byte for byte: the corners, a cell of each parity
@@ -241,7 +298,7 @@ def test_gathered_samples_equal_the_host_engines(kind, k, axis):
     and a batch whose out-of-range and withheld cells keep their place
     as error docs. Nothing materializes."""
     w = 2 * k
-    entry, core, core_h = _seeded_cores(k, 3700 + k, kind)
+    entry, core, core_h = _seeded_cores(k, 3700 + k, kind, shard_small)
     quadrants = [(1, 2), (3, k + 1), (k + 2, 5), (k + 3, k + 4)]
     rng = np.random.default_rng(k)
     seventeen = [(int(r), int(c))
@@ -260,6 +317,7 @@ def test_gathered_samples_equal_the_host_engines(kind, k, axis):
     d0 = _counter("das.gather_dispatches")
     n0 = _counter('obs.span_n{name="das.gather"}')
     b0 = _counter('obs.span_n{name="das.build_provers"}')
+    p0 = _counter("mesh.sharded_level_passes")
     want_gathered = 0
     for cells in batches:
         got = core.sample_many(7, cells, axis=axis)
@@ -273,6 +331,8 @@ def test_gathered_samples_equal_the_host_engines(kind, k, axis):
     assert _counter("das.gather_dispatches") - d0 == len(batches)
     assert _counter('obs.span_n{name="das.gather"}') - n0 == len(batches)
     assert _counter('obs.span_n{name="das.build_provers"}') == b0
+    # the gathered orientation's level pass, split where the square is
+    assert _counter("mesh.sharded_level_passes") - p0 == (kind == "mesh")
     assert _counter("edscache.host_crossings") == c0
     assert entry.residency() == "device"
     assert core.availability(7)["residency"] == "device"
@@ -282,7 +342,7 @@ def test_gathered_samples_equal_the_host_engines(kind, k, axis):
 
 @pytest.mark.parametrize("holds", ["started-copy", "landed-copy",
                                    "host-prover", "host-entry"])
-def test_an_entry_with_host_bytes_serves_from_them(holds):
+def test_an_entry_with_host_bytes_serves_from_them(holds, shard_small):
     """The gather is for an entry whose square lives only on the
     chip(s): one whose engine started a host copy (every one-chip
     device engine), whose copy landed, or whose host prover was built,
@@ -296,9 +356,12 @@ def test_an_entry_with_host_bytes_serves_from_them(holds):
     if holds == "host-entry":
         entry = edscache.compute_entry(ods, "host")
     elif holds == "host-prover":
-        entry = edscache.compute_entry(ods, "mesh")
+        with shard_small():
+            entry = edscache.compute_entry(ods, "device")
+        p0 = _counter("mesh.sharded_level_passes")
         entry.get_prover()
         entry.get_col_prover()
+        assert _counter("mesh.sharded_level_passes") - p0 == 2
     else:
         entry = edscache.compute_entry(ods, "device")
         assert entry._eds_fetch is not None
@@ -325,108 +388,16 @@ def test_an_entry_with_host_bytes_serves_from_them(holds):
 
 
 # ---------------------------------------------------------------------------
-# batched produce: same hashes as per-block, extends paid in the batch
-# ---------------------------------------------------------------------------
-
-
-def _funded_pair(chain_id: str, n: int = 4):
-    from celestia_app_tpu.chain.app import App
-    from celestia_app_tpu.chain.crypto import PrivateKey
-    from celestia_app_tpu.chain.node import Node
-    from celestia_app_tpu.client.tx_client import Signer
-
-    privs = [PrivateKey.from_seed(b"mesh-%d" % i) for i in range(n)]
-    addrs = [p.public_key().address() for p in privs]
-    app = App(chain_id=chain_id, engine="auto")
-    app.init_chain({
-        "time_unix": 1_700_000_000.0,
-        "accounts": [{"address": a.hex(), "balance": 10**12}
-                     for a in addrs],
-        "validators": [{"operator": addrs[0].hex(), "power": 10}],
-        # a small gov cap so a handful of txs spans several blocks and
-        # the batch planner actually plans >1 square
-        "gov_max_square_size": 2,
-    })
-    node = Node(app)
-    signer = Signer(chain_id)
-    for i, p in enumerate(privs):
-        signer.add_account(p, number=i)
-    return app, node, signer, addrs
-
-
-def _submit_sends(node, signer, addrs, rounds: int):
-    from celestia_app_tpu.chain.tx import MsgSend
-
-    for _ in range(rounds):
-        for i, a in enumerate(addrs):
-            tx = signer.create_tx(
-                a, [MsgSend(a, addrs[(i + 1) % len(addrs)], 1)],
-                fee=2000, gas_limit=100_000,
-            )
-            signer.accounts[a].sequence += 1
-            node.broadcast_tx(tx.encode())
-
-
-def test_batched_produce_commits_identical_hashes():
-    """produce_blocks_batched == per-block produce_block: identical
-    block hashes and app hashes at every height; the batch pays the
-    extends (one per height, inside the batched dispatch) and the
-    per-block rounds hit the cache."""
-    app_a, node_a, signer_a, addrs_a = _funded_pair("mesh-batch-eq")
-    app_b, node_b, signer_b, addrs_b = _funded_pair("mesh-batch-eq")
-    _submit_sends(node_a, signer_a, addrs_a, rounds=4)
-    _submit_sends(node_b, signer_b, addrs_b, rounds=4)
-
-    d0 = _counter("mesh.batched_dispatches")
-    m0 = _counter("producer.plan_misses")
-    out_a = node_a.produce_blocks_batched(3, t=1_700_000_100.0)
-    assert _counter("mesh.batched_dispatches") > d0
-    assert _counter("producer.plan_misses") == m0, \
-        "every planned square must be hit by its produce round"
-
-    blocks_b = [node_b.produce_block(t=1_700_000_100.0 + i)
-                for i in range(3)]
-    assert len(out_a) == 3
-    for (blk_a, _), (blk_b, _) in zip(out_a, blocks_b):
-        assert blk_a.header.hash() == blk_b.header.hash()
-        assert blk_a.header.data_hash == blk_b.header.data_hash
-        assert blk_a.txs == blk_b.txs
-    assert app_a.last_app_hash == app_b.last_app_hash
-
-
-def test_prewarm_proposals_is_pure_prefetch():
-    """ValidatorNode.prewarm_proposals (the reactor produce_batch knob)
-    warms the cache without changing any consensus bytes."""
-    from celestia_app_tpu.chain import consensus as c
-    from celestia_app_tpu.chain.crypto import PrivateKey
-
-    priv = PrivateKey.from_seed(b"mesh-prewarm")
-    genesis = {
-        "time_unix": 0,
-        "accounts": [{"address":
-                      priv.public_key().address().hex(),
-                      "balance": 10**12}],
-        "validators": [{"operator":
-                        priv.public_key().address().hex(), "power": 1}],
-    }
-    a = c.ValidatorNode("a", priv, genesis, "mesh-prewarm")
-    b = c.ValidatorNode("b", priv, genesis, "mesh-prewarm")
-    a.prewarm_proposals(2)  # empty mempool: plans nothing, must not blow
-    blk_a = a.propose(t=1.0)
-    blk_b = b.propose(t=1.0)
-    assert blk_a.header.hash() == blk_b.header.hash()
-
-
-# ---------------------------------------------------------------------------
 # e2e: a mesh-engine chain through Prepare/Process/commit/serve
 # ---------------------------------------------------------------------------
 
 
-def test_mesh_engine_chain_matches_host_chain():
-    """Two chains over the same txs — engine="mesh" vs engine="host" —
-    commit identical headers, and their served samples are
-    byte-identical. This is the end-to-end PrepareProposal /
-    ProcessProposal / serve pin at a CI-affordable size."""
+def test_mesh_engine_chain_matches_host_chain(shard_small):
+    """Two chains over the same txs — engine="mesh" (kept as "device",
+    its square split over the devices) vs engine="host" — commit
+    identical headers, and their served samples are byte-identical. This
+    is the end-to-end PrepareProposal / ProcessProposal / serve pin at a
+    CI-affordable size."""
     from celestia_app_tpu.chain.app import App
     from celestia_app_tpu.chain.crypto import PrivateKey
     from celestia_app_tpu.chain.node import Node
@@ -449,14 +420,24 @@ def test_mesh_engine_chain_matches_host_chain():
         core = node.attach_das_core(SampleCore(app))
         signer = Signer("mesh-e2e")
         signer.add_account(priv, number=0)
-        tx = signer.create_tx(addr, [MsgSend(addr, addr, 1)],
-                              fee=2000, gas_limit=100_000)
-        node.broadcast_tx(tx.encode())
+        # enough txs that the square is 2x2, so there is a square to split
+        for _ in range(3):
+            tx = signer.create_tx(addr, [MsgSend(addr, addr, 1)],
+                                  fee=2000, gas_limit=100_000)
+            signer.accounts[addr].sequence += 1
+            node.broadcast_tx(tx.encode())
         blk, _ = node.produce_block(t=1_700_000_001.0)
+        assert blk.header.square_size == 2 and len(blk.txs) == 3
         app.da_warmer.wait_idle(30)
         return app, core, blk
 
-    app_m, core_m, blk_m = chain("mesh")
+    p0 = _counter("mesh.sharded_level_passes")
+    with shard_small():
+        app_m, core_m, blk_m = chain("mesh")
+    assert app_m.engine == "device"
+    assert core_m._entry(1).cache_entry.chips == 2
+    # the warmer's two level passes, over the split square
+    assert _counter("mesh.sharded_level_passes") - p0 == 2
     app_h, core_h, blk_h = chain("host")
     assert blk_m.header.hash() == blk_h.header.hash()
     assert app_m.last_app_hash == app_h.last_app_hash
@@ -465,7 +446,7 @@ def test_mesh_engine_chain_matches_host_chain():
         core_h.sample(1, 1, 1, axis="col")
 
 
-def test_mesh_height_serves_a_whole_light_round(tmp_path):
+def test_mesh_height_serves_a_whole_light_round(tmp_path, shard_small):
     """The benchmark's loop at 8x8 on the forced 8-device mesh: one PFB
     block through broadcast_txs -> produce_block on engine="mesh", then a
     light node's round of 16 cells at the new height, racing the prover
@@ -521,7 +502,8 @@ def test_mesh_height_serves_a_whole_light_round(tmp_path):
     errors0 = _counter("edscache.warm_errors")
     runs0 = _counter('obs.span_n{name="mesh.levels.run"}')
     extends0 = _counter('obs.span_n{name="mesh.extend.run"}')
-    app_m, core_m, block, header, rows, cols = height_one("mesh")
+    with shard_small():
+        app_m, core_m, block, header, rows, cols = height_one("mesh")
     try:
         entry = core_m._entry(1).cache_entry
         assert isinstance(entry, edscache.DeviceEntry)
@@ -565,7 +547,7 @@ def test_mesh_height_serves_a_whole_light_round(tmp_path):
 # ---------------------------------------------------------------------------
 
 
-def test_mesh_sharded_ops_bit_identical(monkeypatch):
+def test_mesh_sharded_ops_bit_identical(shard_small):
     """With the mesh active (min_k lowered), the repair sweep's two
     device programs — the fused decode matmul and the batched NMT root
     reduction — run with their batch dimension sharded over the device
@@ -581,12 +563,6 @@ def test_mesh_sharded_ops_bit_identical(monkeypatch):
     slabs = np.stack([eds[i] for i in range(2 * k)])
     idx = list(range(2 * k))
     plain = nmt_ops.eds_axis_roots(slabs, idx, k)
-    monkeypatch.setenv("CELESTIA_MESH_MIN_K", "4")
-    s0 = _counter("mesh.batch_shards")
-    sharded = nmt_ops.eds_axis_roots(slabs, idx, k)
-    assert _counter("mesh.batch_shards") > s0, "batch must have sharded"
-    np.testing.assert_array_equal(plain, sharded)
-
     # whole-columns erasure: one shared pattern, mesh-sharded decode
     present = np.ones((2 * k, 2 * k), dtype=bool)
     present[:, k + 2:2 * k] = False  # k-2 columns lost
@@ -594,9 +570,13 @@ def test_mesh_sharded_ops_bit_identical(monkeypatch):
     garbled[~present] = 0
     row_roots = [bytes(r) for r in entry.dah.row_roots]
     col_roots = [bytes(c) for c in entry.dah.col_roots]
-    fixed = repair_mod.repair_eds(garbled, present, row_roots, col_roots,
-                                  engine="batched")
-    monkeypatch.delenv("CELESTIA_MESH_MIN_K")
+    with shard_small(min_k=4):
+        s0 = _counter("mesh.batch_shards")
+        sharded = nmt_ops.eds_axis_roots(slabs, idx, k)
+        assert _counter("mesh.batch_shards") > s0, "batch must have sharded"
+        np.testing.assert_array_equal(plain, sharded)
+        fixed = repair_mod.repair_eds(garbled, present, row_roots,
+                                      col_roots, engine="batched")
     fixed_scalar = repair_mod.repair_eds(garbled, present, row_roots,
                                          col_roots, engine="scalar")
     np.testing.assert_array_equal(fixed, fixed_scalar)
@@ -700,8 +680,8 @@ def test_mesh_k256_extend_commit_end_to_end():
     well-formed, device-resident."""
     k = 256
     ods = _random_ods(k, 256)
-    entry = edscache.compute_entry(ods, "mesh")
-    assert isinstance(entry, edscache.DeviceEntry)
+    entry = edscache.compute_entry(ods, "device")
+    assert isinstance(entry, edscache.DeviceEntry) and entry.chips == 8
     host = edscache.compute_entry(ods, "host")
     assert entry.data_root == host.data_root
     assert entry.dah.row_roots == host.dah.row_roots
@@ -720,8 +700,8 @@ def test_mesh_k512_extend_commit_repair():
 
     k = 512
     ods = _random_ods(k, 512)
-    entry = edscache.compute_entry(ods, "mesh")
-    assert isinstance(entry, edscache.DeviceEntry)
+    entry = edscache.compute_entry(ods, "device")
+    assert isinstance(entry, edscache.DeviceEntry) and entry.chips == 8
     assert len(entry.dah.row_roots) == 2 * k
     eds = entry.eds.squares
 

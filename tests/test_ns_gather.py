@@ -73,12 +73,21 @@ def _namespaces(ods: np.ndarray) -> list[bytes]:
     return present + absent + [Namespace.v0(b"\x05" * 5).raw, bytes(29)]
 
 
-def _entry(kind: str, ods: np.ndarray, monkeypatch):
+def _mesh_entry(ods: np.ndarray, shard_small):
+    """A device entry with its rows split over CHIPS devices, and both of
+    its level passes run there."""
+    with shard_small(chips=CHIPS):
+        entry = edscache.compute_entry(ods, "device")
+    assert entry.chips == CHIPS
+    passes = _counter("mesh.sharded_level_passes")
+    entry.warm()
+    assert _counter("mesh.sharded_level_passes") - passes == 2
+    return entry
+
+
+def _entry(kind: str, ods: np.ndarray, shard_small):
     if kind == "mesh":
-        monkeypatch.setenv("CELESTIA_MESH_DEVICES", str(CHIPS))
-        entry = edscache.compute_entry(ods, "mesh")
-        assert entry.chips == CHIPS
-        return entry
+        return _mesh_entry(ods, shard_small)
     started = edscache.compute_entry(ods, "device")
     entry = edscache.DeviceEntry(started._eds_dev, started.dah,
                                  started.data_root)
@@ -92,7 +101,7 @@ def _doc(entry, namespace: bytes, nd) -> str:
 
 @pytest.mark.parametrize("k", [8, 16])
 @pytest.mark.parametrize("kind", ["mesh", "one-chip"])
-def test_resident_reads_equal_the_host_reference(kind, k, monkeypatch):
+def test_resident_reads_equal_the_host_reference(kind, k, shard_small):
     """Every namespace of a seeded square — the reserved tx and PFB
     namespaces, ranges that cross a device's rows and that fill whole
     rows, straddling absences and one no row covers — read off an entry
@@ -100,9 +109,9 @@ def test_resident_reads_equal_the_host_reference(kind, k, monkeypatch):
     header; the entry still "device", nothing came down, and a later
     sample still gathers."""
     ods = _ods(k, 4200 + k)
-    entry = _entry(kind, ods, monkeypatch)
+    entry = _entry(kind, ods, shard_small)
     host = edscache.compute_entry(ods, "host")
-    prover = host.get_prover("host")
+    prover = host.get_prover()
     entry.warm()
     c0 = _counter("edscache.host_crossings")
     g0 = _counter("blob.ns_gathers")
@@ -157,7 +166,7 @@ def test_resident_reads_equal_the_host_reference(kind, k, monkeypatch):
 
 @pytest.mark.parametrize("holds", ["host-entry", "started-copy",
                                    "host-prover"])
-def test_entries_with_host_bytes_read_from_them(holds, monkeypatch):
+def test_entries_with_host_bytes_read_from_them(holds, shard_small):
     """An entry that holds host bytes — the host engine's, one whose
     engine started a copy (every one-chip device engine), one whose host
     prover was built — reads from its host prover as before: the same
@@ -169,11 +178,10 @@ def test_entries_with_host_bytes_read_from_them(holds, monkeypatch):
     elif holds == "started-copy":
         entry = edscache.compute_entry(ods, "device")
     else:
-        monkeypatch.setenv("CELESTIA_MESH_DEVICES", str(CHIPS))
-        entry = edscache.compute_entry(ods, "mesh")
+        entry = _mesh_entry(ods, shard_small)
         entry.get_prover()
     host = edscache.compute_entry(ods, "host")
-    prover = host.get_prover("host")
+    prover = host.get_prover()
     g0 = _counter("blob.ns_gathers")
     reader = entry.namespace_reader()
     assert isinstance(reader, nsdev.ProverReader)
@@ -197,7 +205,7 @@ class _Front:
     sidecar, and the host engine's in-process read core over the same
     squares."""
 
-    def __init__(self, k: int):
+    def __init__(self, k: int, shard_small):
         from celestia_app_tpu.chain.app import App
         from celestia_app_tpu.chain.node import Node
         from celestia_app_tpu.das.blob_server import BlobCore, BlobService
@@ -213,7 +221,7 @@ class _Front:
         self.entries, self.spaces = {}, {}
         for h in HEIGHTS:
             ods = _ods(k, 4400 + 10 * k + h)
-            entry = edscache.compute_entry(ods, "mesh")
+            entry = _mesh_entry(ods, shard_small)
             assert entry.residency() == "device"
             self.entries[h] = entry
             self.spaces[h] = _namespaces(ods)
@@ -235,13 +243,10 @@ class _Front:
 
 
 @pytest.fixture(scope="module", params=[8, 16], ids=["k8", "k16"])
-def front(request):
-    mp = pytest.MonkeyPatch()
-    mp.setenv("CELESTIA_MESH_DEVICES", str(CHIPS))
-    f = _Front(request.param)
+def front(request, shard_small):
+    f = _Front(request.param, shard_small)
     yield f
     f.close()
-    mp.undo()
 
 
 def _ask(conn, method: str, path: str, body=None):
@@ -404,7 +409,7 @@ class _Reads:
     """Two heights of one entry kind behind an in-process `BlobCore`, and
     the host reference's `NamespaceData` of every namespace of each."""
 
-    def __init__(self, kind: str):
+    def __init__(self, kind: str, shard_small):
         from celestia_app_tpu.chain.app import App
         from celestia_app_tpu.das.blob_server import BlobCore
         from celestia_app_tpu.das.server import SampleCore
@@ -417,10 +422,13 @@ class _Reads:
         self.roots, self.refs = {}, {}
         for h in HEIGHTS:
             ods = _ods(k, 4600 + 10 * k + h)
-            entry = edscache.compute_entry(ods, engine)
+            if engine == "mesh":
+                entry = _mesh_entry(ods, shard_small)
+            else:
+                entry = edscache.compute_entry(ods, engine)
             assert entry.residency() == engine.replace("mesh", "device")
             self.blob.core.seed_cache_entry(h, entry)
-            prover = edscache.compute_entry(ods, "host").get_prover("host")
+            prover = edscache.compute_entry(ods, "host").get_prover()
             self.roots[h] = entry.data_root
             self.refs[h] = {ns: nsd.get_namespace_data(prover, ns)
                             for ns in _namespaces(ods)}
@@ -442,20 +450,17 @@ class _Reads:
 
 
 @pytest.fixture(scope="module")
-def reads():
-    mp = pytest.MonkeyPatch()
-    mp.setenv("CELESTIA_MESH_DEVICES", str(CHIPS))
+def reads(shard_small):
     made: dict[str, _Reads] = {}
 
     def get(kind: str) -> _Reads:
         if kind not in made:
-            made[kind] = _Reads(kind)
+            made[kind] = _Reads(kind, shard_small)
         return made[kind]
 
     yield get
     for r in made.values():
         r.app.close()
-    mp.undo()
 
 
 MISSING = 99  # a height no core serves: an error member
@@ -514,7 +519,7 @@ def test_pack_chunks_are_the_per_share_encoders_bytes(k):
     of the per-share encoder's docs."""
     ods = _ods(k, 4700 + k)
     entry = edscache.compute_entry(ods, "host")
-    prover = entry.get_prover("host")
+    prover = entry.get_prover()
     _manifest, chunks = blob_packs.build_blob_pack(entry, 5,
                                                    chunk_namespaces=2)
     docs = []

@@ -38,6 +38,7 @@ from celestia_app_tpu.client.follower import (
 )
 from celestia_app_tpu.client.tx_client import Signer
 from celestia_app_tpu.da import dah as dah_mod
+from celestia_app_tpu.da import edscache
 from celestia_app_tpu.da import namespace_data as nsd
 from celestia_app_tpu.da import namespace_device as nsdev
 from celestia_app_tpu.da import proof_device
@@ -105,12 +106,12 @@ def _mk_blobs(rng):
     ]
 
 
-@pytest.mark.parametrize("engine", ("host", "device"))
-def test_batched_matches_host_reference(engine):
+@pytest.mark.parametrize("device", (False, True))
+def test_batched_matches_host_reference(device):
     """THE tentpole pin: one batched dispatch resolves presence, the
     straddling-row absence (successor proof) and the no-covering-row
     absence (no proof) byte-identically to per-query
-    get_namespace_data — on both engines (the device engine degrades to
+    get_namespace_data — on both paths (the device search degrades to
     the host pass when no accelerator runtime is available, counted,
     never raised — identity holds either way)."""
     rng = np.random.default_rng(7)
@@ -123,7 +124,7 @@ def test_batched_matches_host_reference(engine):
         + [blobs[0].namespace.raw]      # duplicate query, order pinned
     )
     c0 = _counters()
-    got = nsdev.get_namespace_data_batched(prover, queries, engine=engine)
+    got = nsdev.get_namespace_data_batched(prover, queries, device=device)
     c1 = _counters()
     assert len(got) == len(queries)
     for q, nd in zip(queries, got):
@@ -135,27 +136,36 @@ def test_batched_matches_host_reference(engine):
     assert [bool(nd.shares) for nd in got] == [
         True, True, True, False, False, True]
     assert got[3].proof is not None and got[4].proof is None
-    if engine == "device":
+    if device:
         # the dispatch either ran on-device or fell back, counted
         assert (_delta(c0, c1, "blob.device_batches")
                 + _delta(c0, c1, "blob.device_fallbacks")) >= 1
 
 
-def test_auto_engine_gates_on_batch_size(monkeypatch):
-    """engine="auto" below CELESTIA_BLOB_MIN_BATCH stays on host (no
-    device dispatch, no fallback) — the gate moves work, never bytes."""
-    monkeypatch.setenv("CELESTIA_BLOB_MIN_BATCH", "64")
+@pytest.mark.parametrize("holds,device_batches", [
+    ("host-entry", 0), ("started-copy", 1)])
+def test_the_entry_says_where_its_search_runs(holds, device_batches):
+    """A namespace read of two queries searches where the entry's engine
+    put it, whatever the batch size: on the host for the host engine's
+    entry (no device batch, no fallback), on the device for a
+    `DeviceEntry` that holds host bytes (one device batch) — the same
+    bytes either way."""
     rng = np.random.default_rng(8)
     blobs = _mk_blobs(rng)
-    _sq, _d, prover, _root = _block(rng, blobs)
+    sq, _d, prover, _root = _block(rng, blobs)
+    ods = dah_mod.shares_to_ods(sq.share_bytes())
+    engine = "host" if holds == "host-entry" else "device"
+    entry = edscache.compute_entry(ods, engine)
+    assert entry.proves_on_host()
+    queries = [blobs[0].namespace.raw, ABSENT.raw]
+    reader = entry.namespace_reader()
     c0 = _counters()
-    got = nsdev.get_namespace_data_batched(
-        prover, [blobs[0].namespace.raw, ABSENT.raw], engine="auto")
+    got = reader.assemble(queries, reader.search(queries))
     c1 = _counters()
-    assert _delta(c0, c1, "blob.device_batches") == 0
+    assert _delta(c0, c1, "blob.device_batches") == device_batches
     assert _delta(c0, c1, "blob.device_fallbacks") == 0
-    assert _nd_canon(got[0]) == _nd_canon(
-        nsd.get_namespace_data(prover, blobs[0].namespace.raw))
+    for q, nd in zip(queries, got):
+        assert _nd_canon(nd) == _nd_canon(nsd.get_namespace_data(prover, q))
 
 
 # ---------------------------------------------------------------------------

@@ -53,25 +53,6 @@ def test_sharded_matches_single_device(k, batch):
         np.testing.assert_array_equal(root_s[b], root1)
 
 
-@pytest.mark.parametrize("k,batch", [(4, 2), (8, 3), (16, 2)])
-def test_batched_pipeline_bit_identical_per_block(k, batch):
-    """jitted_pipeline_batched (the batched producer's program,
-    parallel/mesh_engine.py): one dispatch over B squares equals the
-    single-square pipeline block for block, EDS and roots."""
-    rng = np.random.default_rng(99 + k)
-    ods_batch = np.stack([_random_ods(rng, k) for _ in range(batch)])
-    eds_b, row_b, col_b, roots_b = jax.tree.map(
-        np.asarray, eds_mod.jitted_pipeline_batched(k)(ods_batch)
-    )
-    single = eds_mod.jitted_pipeline(k)
-    for b in range(batch):
-        eds1, row1, col1, root1 = jax.tree.map(np.asarray, single(ods_batch[b]))
-        np.testing.assert_array_equal(eds_b[b], eds1)
-        np.testing.assert_array_equal(row_b[b], row1)
-        np.testing.assert_array_equal(col_b[b], col1)
-        np.testing.assert_array_equal(roots_b[b], root1)
-
-
 def test_mesh_factoring():
     devs = _cpu_devices()
     if len(devs) < 8:
